@@ -84,7 +84,7 @@ SERVING_METRICS = (
 )
 
 # json paths inside the top-level "compute_stitching" section — kernel
-# counts after GEMM/custom-kernel admission into stitched partitions.
+# counts after GEMM admission into stitched partitions.
 # Deterministic (no wall clock): the block must stay collapsed and both
 # plans must actually carry Pallas groups.
 COMPUTE_METRICS = (

@@ -43,7 +43,7 @@ from __future__ import annotations
 # and in CI.  An operator-provided count via XLA_FLAGS is respected.  The
 # modeled/kernel-count metrics of the other sections are device-count
 # independent.
-from repro.launch.hostenv import force_host_devices
+from repro.launch.hostenv import force_host_devices, use_compile_cache
 
 force_host_devices(8)
 
@@ -534,6 +534,7 @@ def sharding(quick: bool) -> dict | None:
     from repro.cache import CompilationService, StitchCache
     from repro.configs import get_reduced
     from repro.core import StitchCompiler
+    from repro.launch.mesh import make_host_mesh
     from repro.models import build_model
     from repro.optim import AdamWConfig
     from repro.train import StitchedTrainStep, init_state
@@ -544,7 +545,7 @@ def sharding(quick: bool) -> dict | None:
         print("# skipped: single-device host "
               "(set --xla_force_host_platform_device_count)")
         return None
-    mesh = jax.make_mesh((n // 2, 2), ("data", "model"))
+    mesh = make_host_mesh(2)
     cfg = get_reduced("qwen3_1_7b")
     model = build_model(cfg)
     opt_cfg = AdamWConfig(warmup_steps=5, total_steps=100)
@@ -631,8 +632,8 @@ def sharding(quick: bool) -> dict | None:
 def compute_stitching(quick: bool) -> dict:
     """Kernel-count evidence for compute-intensive stitching: a transformer
     block (rms -> q/k/v GEMMs -> Pallas flash attention -> output GEMM ->
-    gelu MLP) compiling to ONE stitched kernel, and the serving decode
-    step's plan shrinking with it.  Counts are deterministic — the gate
+    gelu MLP) compiling to one stitched kernel on each side of the flash
+    attention kernel, and the serving decode step's plan.  Counts are deterministic — the gate
     holds them exactly (``lower`` direction + liveness), no wall clock."""
     import jax
     import jax.numpy as jnp
@@ -643,7 +644,7 @@ def compute_stitching(quick: bool) -> dict:
     from repro.models import build_model
     from repro.serve import Engine, ServeConfig
 
-    print("\n# Compute stitching — GEMMs + Pallas attention in one kernel")
+    print("\n# Compute stitching — GEMMs stitched around Pallas attention")
     print("name,us_per_call,derived")
 
     B, S, D, H = 2, 128, 16, 2
@@ -859,6 +860,7 @@ def main() -> None:
                          "kernel counts, modeled step times and cache "
                          "cold/warm compile times")
     args = ap.parse_args(sys.argv[1:])
+    use_compile_cache()
     cost = CostModel(V100 if args.hw == "V100" else TPU_V5E)
 
     t0 = time.time()

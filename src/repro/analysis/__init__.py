@@ -9,7 +9,7 @@ code registry.
 Passes:
   * :func:`verify_graph`   — IR legality (SSA, shapes, dtypes, dead code)
   * :func:`verify_plan`    — fusion-plan legality (cover, cycles, scratch,
-    registry membership); :func:`verify_record` for disk records,
+    no custom kernel in a fused group); :func:`verify_record` for disk records,
     :func:`verify_compiled` for compiled artifacts
   * :func:`check_donation` — donation/aliasing hazards
   * :func:`audit_kv`       — paged-KV refcount conservation over a

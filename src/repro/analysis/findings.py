@@ -30,7 +30,7 @@ RA021     overlapping groups (node owned by more than one group)
 RA022     compute node not covered by any group
 RA023     induced group DAG has a cycle
 RA024     group scratch request exceeds on-chip budget
-RA025     unregistered custom kernel inside a fused group
+RA025     custom kernel inside a fused group
 RA026     recorded pattern-class stats inconsistent [WARN]
 RA027     non-compute node (param/const/tuple) inside a group
 RA028     invalid group kind in a plan record
@@ -81,7 +81,7 @@ CODES: dict[str, str] = {
     "RA022": "uncovered compute node",
     "RA023": "induced group cycle",
     "RA024": "scratch over budget",
-    "RA025": "unregistered custom in fused group",
+    "RA025": "custom kernel in fused group",
     "RA026": "pattern-class stats inconsistent",
     "RA027": "non-compute node in group",
     "RA028": "invalid group kind",

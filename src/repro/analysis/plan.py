@@ -12,7 +12,7 @@ invariants statically:
   ``induced_reaches`` cycle rule, checked over the *whole* plan rather
   than one contraction at a time,
 * multi-member groups fit the on-chip scratch budget (RA024),
-* fused groups only contain CUSTOM kernels the registry knows (RA025),
+* fused groups contain no CUSTOM kernel (RA025),
 * recorded pattern-class stats match a recount (RA026, WARN),
 * horizontal packs are well-formed: member subgraphs disjoint and covering
   (RA060), no data dependence crosses two packed subgraphs (RA061), and
@@ -29,7 +29,6 @@ from typing import Iterable, Sequence
 from repro.core.cost import CostModel
 from repro.core.ir import Graph, OpKind
 from repro.core.pattern import FusionPattern
-from repro.kernels.registry import lookup as _registry_lookup
 
 from .findings import Finding
 
@@ -169,20 +168,21 @@ def verify_plan(
             continue
         if any(m not in g.nodes for m in v.members):
             continue
-        # registered-custom membership: an unregistered CUSTOM cannot live
-        # inside a fused kernel — there is no body to stitch (registry rule;
-        # kernels/stitched.py would only discover this mid-tuning)
+        # a CUSTOM node cannot live inside a fused kernel: there is no body
+        # to stitch, and a Pallas kernel's pallas_call would nest inside the
+        # stitched one, which Mosaic does not lower (kernels/stitched.py
+        # would only discover this mid-tuning)
         for m in sorted(v.members):
             node = g[m]
-            if node.kind is OpKind.CUSTOM and _registry_lookup(node) is None:
+            if node.kind is OpKind.CUSTOM:
                 findings.append(Finding(
                     "RA025", f"custom kernel "
-                             f"{node.attrs.get('kernel', '?')!r} not in "
-                             f"registry", node=m, group=v.index))
+                             f"{node.attrs.get('kernel', '?')!r} inside a "
+                             f"fused group", node=m, group=v.index))
         if scratch_budget is not None and cost is not None \
                 and v.kind in ("pattern", "pallas"):
             p = FusionPattern(g, v.members)
-            req = sum(cost.scratch_request(p).values()) + cost.custom_scratch(p)
+            req = sum(cost.scratch_request(p).values())
             if req > scratch_budget:
                 findings.append(Finding(
                     "RA024", f"scratch request {req} B exceeds budget "

@@ -192,7 +192,7 @@ def config_key(gen_cfg=None) -> str:
     """Stable digest of the pattern-generation knobs a plan was solved under.
 
     Two compiles of the same graph under different :class:`GenConfig`
-    settings (``large_gemm_flops``, ``stitch_custom``, scratch budget, ...)
+    settings (``large_gemm_flops``, ``pack_patterns``, scratch budget, ...)
     legitimately choose different plans; without this component a plan cached
     under one config replays for all of them — the plan-cache staleness bug.
     ``None`` hashes identically to a default ``GenConfig()``, so callers that

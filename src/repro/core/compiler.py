@@ -284,8 +284,8 @@ class StitchCompiler:
         """Run the static verifier on a proposed plan (post-ILP, pre-tune).
 
         ``verify="plans"`` checks the plan invariants (disjointness, induced
-        acyclicity, scratch budget, registry membership); ``verify="full"``
-        additionally runs the IR verifier on the graph.  ERROR findings
+        acyclicity, scratch budget, no custom kernel in a fused group);
+        ``verify="full"`` additionally runs the IR verifier on the graph.  ERROR findings
         raise :class:`repro.analysis.VerificationError` — the compiler
         refuses to tune or execute an illegal plan.  Returns the findings
         summary recorded into :class:`FusionStats`.

@@ -24,10 +24,21 @@ from __future__ import annotations
 import math
 from dataclasses import dataclass, field
 
-from .ir import Graph, OpKind
+from .ir import Graph, OpKind, itemsize
 from .pattern import FusionPattern
 
-__all__ = ["HardwareModel", "V100", "TPU_V5E", "CostModel", "PatternScore"]
+__all__ = ["HardwareModel", "V100", "TPU_V5E", "CostModel", "PatternScore",
+           "vmem_tile_bytes"]
+
+
+def vmem_tile_bytes(shape: tuple[int, ...], item_bytes: int) -> int:
+    """Bytes one block takes in TPU VMEM: its two minor dims pad to the
+    (sublane, 128-lane) tile, with 8 sublanes of 32 bits each."""
+    dims = (1,) * max(0, 2 - len(shape)) + tuple(shape)
+    sub = 8 * max(1, 4 // item_bytes)
+    lead = math.prod(dims[:-2])
+    return (lead * -(-dims[-2] // sub) * sub * -(-dims[-1] // 128) * 128
+            * item_bytes)
 
 
 @dataclass(frozen=True)
@@ -51,6 +62,10 @@ class HardwareModel:
     # unattractive, which is what forces over-wide independent regions to
     # shatter into FFD packs instead of one monolithic kernel.
     reg_budget: int = 2 * 1024 * 1024
+    # TPU kernels hold each input and output block in VMEM, double-buffered
+    # by the Pallas pipeline; those blocks count against onchip_budget
+    # together with the scratch.  GPU kernels stream their operands.
+    block_resident: bool = False
 
     def efficiency(self, nbytes: float) -> float:
         if nbytes <= 0:
@@ -83,10 +98,11 @@ TPU_V5E = HardwareModel(
     hbm_bw=819e9,
     peak_flops=197e12,           # bf16
     launch_latency=2e-6,         # XLA static-schedule dispatch, no driver
-    onchip_budget=16 * 1024 * 1024,  # conservative usable VMEM scratch
+    onchip_budget=16 * 1024 * 1024,  # Mosaic's default scoped VMEM per kernel
     bw_half=1 << 17,
     ici_bw=3 * 2 * 50e9,         # 3 links x 2 directions x 50 GB/s
     reg_budget=2 * 1024 * 1024,  # VREG + low-latency VMEM working set
+    block_resident=True,
 )
 
 
@@ -141,20 +157,24 @@ class CostModel:
                 return desc.flops(node, g)
         return 0.0
 
-    def custom_scratch(self, p: FusionPattern) -> int:
-        """On-chip bytes the pattern's registered custom-kernel bodies bring
-        along (e.g. flash attention's m/l/acc accumulators).  Kept separate
-        from :meth:`scratch_request` because that dict feeds the *template*
-        scratch plan; a custom kernel allocates its own scratch inside its
-        saved body."""
-        from repro.kernels.registry import lookup
-        total = 0
-        for n in p.compute_members:
-            if n.kind is OpKind.CUSTOM and "project" not in n.attrs:
-                desc = lookup(n)
-                if desc is not None:
-                    total += desc.scratch_bytes(n, p.graph)
-        return total
+    def vmem_request(self, p: FusionPattern,
+                     row_block: int | None = None) -> int:
+        """VMEM bytes the emitted kernel's double-buffered input and output
+        blocks take at ``row_block`` (default: the smallest the pattern
+        admits), tile-padded as the chip lays them out.  0 where blocks do
+        not stay resident (GPU) or no Pallas kernel would be emitted (the
+        group then runs as fused jnp and holds no VMEM)."""
+        if not self.hw.block_resident:
+            return 0
+        from repro.kernels.stitched import (StitchInfeasible, analyze_pattern,
+                                            block_bytes)
+        try:
+            ana = analyze_pattern(p)
+        except StitchInfeasible:
+            return 0
+        rb = row_block if row_block in ana.feasible_blocks \
+            else ana.feasible_blocks[0]
+        return 2 * block_bytes(p, ana, rb)
 
     def kernel_time(self, g: Graph, name: str) -> float:
         """K(Op): standalone kernel execution time for one op (roofline max
@@ -261,16 +281,13 @@ class CostModel:
             return 0
         rows = max(counts, key=lambda k: (counts[k], k))
         rb = min(8, rows)
-        # single-block patterns (registered-custom replay; cross-row
-        # accumulators feeding members, e.g. the packed optimizer's global
-        # grad-norm) run as grid==1 composition: whole-array residency is
-        # the scratch plan's domain, and with one block in flight there is
-        # no occupancy to lose — the register gate only prices row-streamed
-        # interleaving width
+        # single-block patterns (cross-row accumulators feeding members,
+        # e.g. the packed optimizer's global grad-norm) run as grid==1
+        # composition: whole-array residency is the scratch plan's domain,
+        # and with one block in flight there is no occupancy to lose — the
+        # register gate only prices row-streamed interleaving width
         members = set(p.members)
         for n in seq:
-            if n.kind is OpKind.CUSTOM and "project" not in n.attrs:
-                return 0
             if n.kind is OpKind.REDUCTION and 0 in tuple(n.attrs.get("axes", ())):
                 src = g[n.operands[0]]
                 if src.shape and src.shape[0] == rows and any(
@@ -306,19 +323,35 @@ class CostModel:
                 live -= dead
         return peak
 
+    def _over_onchip(self, p: FusionPattern, scratch: int) -> str:
+        """Why the kernel cannot fit on chip ("" when it fits): scratch
+        plus resident blocks against the one on-chip budget."""
+        if not self.hw.block_resident:
+            need = scratch
+        else:
+            # a block is at most its whole tensor: when the whole tensors
+            # fit, the row analysis behind vmem_request is not needed
+            g = p.graph
+            whole = 2 * sum(
+                vmem_tile_bytes(g[n].shape or (1,), itemsize(g[n].dtype))
+                for n in list(p.external_inputs) + list(p.external_outputs))
+            need = scratch + whole
+            if need > self.hw.onchip_budget:
+                need = scratch + self.vmem_request(p)
+        if need > self.hw.onchip_budget:
+            return (f"on-chip {need}B (scratch {scratch}B) exceeds budget "
+                    f"{self.hw.onchip_budget}B")
+        return ""
+
     # -- the paper's two scoring paths ---------------------------------------
     def score_model_based(self, p: FusionPattern) -> PatternScore:
         n_kernels = len(p.compute_members)
         if n_kernels < 2:
             return PatternScore(p, -1.0, False, "singleton", 0, 0, 0)
-        req = self.scratch_request(p)
-        total_req = sum(req.values()) + self.custom_scratch(p)
-        if total_req > self.hw.onchip_budget:
-            return PatternScore(
-                p, -1.0, False,
-                f"scratch {total_req}B exceeds budget {self.hw.onchip_budget}B",
-                total_req, 0, 0,
-            )
+        total_req = sum(self.scratch_request(p).values())
+        infeasible = self._over_onchip(p, total_req)
+        if infeasible:
+            return PatternScore(p, -1.0, False, infeasible, total_req, 0, 0)
         reg = self.register_pressure(p)
         if reg > self.reg_budget:
             return PatternScore(
@@ -335,10 +368,10 @@ class CostModel:
         n_kernels = len(p.compute_members)
         if n_kernels < 2:
             return PatternScore(p, -1.0, False, "singleton", 0, 0, 0)
-        req = self.scratch_request(p)
-        total_req = sum(req.values()) + self.custom_scratch(p)
-        if total_req > self.hw.onchip_budget:
-            return PatternScore(p, -1.0, False, "scratch over budget", total_req, 0, 0)
+        total_req = sum(self.scratch_request(p).values())
+        infeasible = self._over_onchip(p, total_req)
+        if infeasible:
+            return PatternScore(p, -1.0, False, infeasible, total_req, 0, 0)
         reg = self.register_pressure(p)
         if reg > self.reg_budget:
             return PatternScore(
@@ -358,8 +391,7 @@ class CostModel:
     # -- dispatch rule (§4.3: model-based for most, execution for complex) ---
     def score(self, p: FusionPattern) -> PatternScore:
         complex_pattern = (p.pattern_class == "gemm"
-                           or len(p.reduce_kinds) > 1
-                           or bool(p.custom_members))
+                           or len(p.reduce_kinds) > 1)
         if complex_pattern:
             return self.score_execution_based(p)
         return self.score_model_based(p)

@@ -60,11 +60,6 @@ class GenConfig:
     max_patterns: int = 4000
     max_pattern_size: int = 64
     max_depth: int = 12
-    # registered Pallas custom kernels (kernels/registry.py) stop partitioning
-    # from `custom_fuse_step` on, so attention/router bodies can live inside a
-    # stitched kernel alongside their surrounding projections
-    stitch_custom: bool = True
-    custom_fuse_step: int = 1
     # on-chip scratch ceiling for candidate partitions; None = hardware budget
     scratch_budget: int | None = None
     # §4.2 independent-op packing: propose horizontal PackPatterns over
@@ -90,16 +85,10 @@ def _is_partition_op(g: Graph, node: OpNode, step: int, cfg: GenConfig) -> bool:
     """Paper's multi-step widening: step 0 partitions on large GEMMs only;
     each later step *removes* a class from the partition set (i.e. allows it
     to fuse).  Order: large gemm | batched-gemm | column reductions | scalar
-    reductions.  GATHER/SCATTER always partition (opaque); CUSTOM partitions
-    unless the kernel is registered stitchable and the step has widened past
-    ``cfg.custom_fuse_step``."""
-    if node.kind is OpKind.CUSTOM:
-        if cfg.stitch_custom and step >= cfg.custom_fuse_step:
-            from repro.kernels.registry import lookup
-            if lookup(node) is not None:
-                return False
-        return True
-    if node.kind in (OpKind.GATHER, OpKind.SCATTER):
+    reductions.  GATHER/SCATTER and CUSTOM always partition (opaque): a
+    custom node that is a Pallas kernel would nest its ``pallas_call`` inside
+    the stitched kernel, which the TPU's Mosaic lowering refuses."""
+    if node.kind in (OpKind.CUSTOM, OpKind.GATHER, OpKind.SCATTER):
         return True
     if node.kind is OpKind.SLICE:
         return False
@@ -166,13 +155,10 @@ def _explore_fusible(g: Graph, name: str, cfg: GenConfig | None = None) -> bool:
         return True
     if cfg is None:
         return False
-    # exploration may also pull in small GEMMs and registered custom kernels —
-    # the same classes the widened substitution steps stop partitioning on
+    # exploration may also pull in small GEMMs — the class the widened
+    # substitution steps stop partitioning on
     if node.kind is OpKind.GEMM:
         return _gemm_flops(g, node) < cfg.large_gemm_flops
-    if node.kind is OpKind.CUSTOM and cfg.stitch_custom:
-        from repro.kernels.registry import lookup
-        return lookup(node) is not None
     return False
 
 
@@ -250,8 +236,6 @@ def _node_sig(g: Graph, n: OpNode) -> tuple:
         extra = (tuple(n.attrs.get("perm", ())),)
     elif n.kind is OpKind.SLICE:
         extra = (tuple(n.attrs.get("starts", ())), tuple(n.attrs.get("limits", ())))
-    elif n.kind is OpKind.CUSTOM:
-        extra = (n.attrs.get("kernel"), n.attrs.get("project"))
     return (n.kind.value, n.attrs.get("op"), n.shape, n.dtype, extra,
             tuple(g[o].shape for o in n.operands),
             tuple(g[o].dtype for o in n.operands))
@@ -371,7 +355,9 @@ def packing_fusion(g: Graph, cfg: GenConfig | None = None,
         reg = cost.register_pressure(p)
         if reg == 0:  # singleton cone: one live row tile
             reg = sum(cost._tile_bytes(g[m]) for m in cone)
-        scr = sum(cost.scratch_request(p).values()) + cost.custom_scratch(p)
+        # on-chip bytes: scratch plus the cone's resident blocks (a pack
+        # holds every member's blocks at once)
+        scr = sum(cost.scratch_request(p).values()) + cost.vmem_request(p)
         return reg, scr
 
     weighted = []

@@ -71,22 +71,15 @@ class FusionPattern:
 
     @cached_property
     def pattern_class(self) -> str:
-        """Paper §6.4: gemm > reduction > elemwise precedence.  Stitchable
-        CUSTOM kernels (flash attention etc.) are compute-bearing, so they
-        classify with the GEMMs."""
+        """Paper §6.4: gemm > reduction > elemwise precedence.  CUSTOM
+        kernels (flash attention etc.) are compute-bearing, so they classify
+        with the GEMMs."""
         kinds = {n.kind for n in self.nodes}
         if kinds & {OpKind.GEMM, OpKind.BATCHED_GEMM, OpKind.CUSTOM}:
             return PatternClass.GEMM
         if OpKind.REDUCTION in kinds:
             return PatternClass.REDUCTION
         return PatternClass.ELEMWISE
-
-    @cached_property
-    def custom_members(self) -> tuple[str, ...]:
-        """CUSTOM member names (registered or not), projections included."""
-        return tuple(
-            n.name for n in self.nodes if n.kind is OpKind.CUSTOM
-        )
 
     @cached_property
     def reduce_kinds(self) -> set[ReduceKind]:

@@ -280,11 +280,11 @@ def trace_to_graph(fn: Callable, *example_args, name: str = "traced",
         extra = {}
         if prim.name == "pallas_call":
             # the kernel-body function name identifies WHICH Pallas kernel
-            # this is; the stitchable-kernel registry keys on it, and making
+            # this is; the kernel registry keys on it, and making
             # it an attr (not just params_sig type-name soup) also makes
             # kernel identity visible to cache signatures
-            nsi = params.get("name_and_src_info")
-            tag = getattr(nsi, "name", None) or params.get("name")
+            body = getattr(params.get("jaxpr"), "debug_info", None)
+            tag = params.get("name") or getattr(body, "func_name", None)
             if tag:
                 extra["kernel"] = str(tag)
         if len(eqn.outvars) == 1:

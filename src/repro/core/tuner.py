@@ -182,9 +182,10 @@ class TemplateTuner:
         req_all = self.cost.scratch_request(p)
         req = {k: v for k, v in req_all.items() if k in set(template.scratch_ops)}
         plan = self._allocator(p.graph).allocate(req)
-        # registered custom-kernel bodies allocate their own scratch inside
-        # the composed kernel; it shares the same on-chip volume
-        if plan.allocated + self.cost.custom_scratch(p) > self.hw.onchip_budget:
+        # the kernel's resident in/out blocks at this template's row block
+        # share the same on-chip volume as its scratch
+        blocks = self.cost.vmem_request(p, grid_row_block(template))
+        if plan.allocated + blocks > self.hw.onchip_budget:
             return None
         return plan
 

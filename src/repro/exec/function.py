@@ -45,7 +45,6 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro import obs
@@ -118,9 +117,9 @@ def shard_wrap(fn: Callable, mesh: Mesh, in_specs, out_specs,
     def dispatch(*args):
         key = refresh_key() if refresh_key is not None else None
         if state["fn"] is None or state["key"] is not key:
-            state["fn"] = jax.jit(shard_map(
+            state["fn"] = jax.jit(jax.shard_map(
                 fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                check_rep=False))
+                check_vma=False))
             state["key"] = key
         return state["fn"](*args)
 
@@ -407,9 +406,9 @@ class StitchedFunction:
 
                 # memoized per artifact: steady state is a jit-cache hit,
                 # an upgrade swap rebuilds once
-                sp.sm_fn = jax.jit(shard_map(
+                sp.sm_fn = jax.jit(jax.shard_map(
                     body, mesh=self.mesh, in_specs=sp.sm_in_specs,
-                    out_specs=sp.sm_out_specs, check_rep=False))
+                    out_specs=sp.sm_out_specs, check_vma=False))
                 sp.sm_for = sp.compiled
             return sp.sm_fn(*dyn)
         env = dict(zip(sp.names, jax.tree_util.tree_leaves((dyn, kwargs))))
@@ -442,10 +441,10 @@ class StitchedFunction:
                 if len(self._jit_sharded) >= 64:   # long-lived servers with
                     self._jit_sharded.clear()      # ever-new extra shapes
                 specs = _resolve(self.in_specs, dyn)
-                fn = None if specs is None else jax.jit(shard_map(
+                fn = None if specs is None else jax.jit(jax.shard_map(
                     self.fn, mesh=self.mesh, in_specs=specs,
                     out_specs=_resolve(self.out_specs, dyn),
-                    check_rep=False))
+                    check_vma=False))
                 self._jit_sharded[key] = fn
             if fn is not None:
                 return fn(*dyn)

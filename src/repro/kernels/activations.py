@@ -10,6 +10,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro import kernels
+
 from .norms import DEFAULT_BLOCK_ROWS, _row_grid
 
 
@@ -23,7 +25,7 @@ def _glu_kernel(g_ref, u_ref, o_ref, *, act: str):
     o_ref[...] = (a * u).astype(o_ref.dtype)
 
 
-def _glu(gate, up, act: str, block_rows: int, interpret: bool):
+def _glu(gate, up, act: str, block_rows: int):
     orig_shape = gate.shape
     d = gate.shape[-1]
     g2, u2 = gate.reshape(-1, d), up.reshape(-1, d)
@@ -38,17 +40,17 @@ def _glu(gate, up, act: str, block_rows: int, interpret: bool):
         ],
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(g2.shape, gate.dtype),
-        interpret=interpret,
+        interpret=kernels.interpret_mode(),
     )(g2, u2)
     return out.reshape(orig_shape)
 
 
-def swiglu(gate, up, *, block_rows: int = DEFAULT_BLOCK_ROWS, interpret: bool = True):
-    return _glu(gate, up, "silu", block_rows, interpret)
+def swiglu(gate, up, *, block_rows: int = DEFAULT_BLOCK_ROWS):
+    return _glu(gate, up, "silu", block_rows)
 
 
-def geglu(gate, up, *, block_rows: int = DEFAULT_BLOCK_ROWS, interpret: bool = True):
-    return _glu(gate, up, "gelu", block_rows, interpret)
+def geglu(gate, up, *, block_rows: int = DEFAULT_BLOCK_ROWS):
+    return _glu(gate, up, "gelu", block_rows)
 
 
 def _sqrelu_kernel(x_ref, o_ref):
@@ -57,7 +59,7 @@ def _sqrelu_kernel(x_ref, o_ref):
     o_ref[...] = (r * r).astype(o_ref.dtype)
 
 
-def squared_relu(x, *, block_rows: int = DEFAULT_BLOCK_ROWS, interpret: bool = True):
+def squared_relu(x, *, block_rows: int = DEFAULT_BLOCK_ROWS):
     orig_shape = x.shape
     d = x.shape[-1]
     x2 = x.reshape(-1, d)
@@ -68,6 +70,6 @@ def squared_relu(x, *, block_rows: int = DEFAULT_BLOCK_ROWS, interpret: bool = T
         in_specs=[pl.BlockSpec((br, d), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(x2.shape, x.dtype),
-        interpret=interpret,
+        interpret=kernels.interpret_mode(),
     )(x2)
     return out.reshape(orig_shape)

@@ -19,6 +19,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import kernels
+
 
 def _xent_kernel(x_ref, lbl_ref, loss_ref, m_scr, l_scr, g_scr, *, nv: int, vb: int):
     iv = pl.program_id(1)
@@ -49,7 +51,7 @@ def _xent_kernel(x_ref, lbl_ref, loss_ref, m_scr, l_scr, g_scr, *, nv: int, vb: 
 
 
 def cross_entropy(logits, labels, *, block_rows: int = 128,
-                  block_vocab: int = 2048, interpret: bool = True):
+                  block_vocab: int = 2048):
     """logits (B, V), labels (B,) -> mean NLL (scalar)."""
     B, V = logits.shape
     br = min(block_rows, B)
@@ -73,6 +75,6 @@ def cross_entropy(logits, labels, *, block_rows: int = 128,
             pltpu.VMEM((br,), jnp.float32),
             pltpu.VMEM((br,), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=kernels.interpret_mode(),
     )(logits, labels.astype(jnp.int32))
     return jnp.mean(per_row)

@@ -9,9 +9,8 @@ softmax, casts); this kernel is the online-softmax flash loop with Lq = 1,
 blocked over kv, GQA via the BlockSpec index maps like
 :mod:`.flash_attention`.
 
-Registered in :mod:`.registry` as ``_decode_attn_kernel`` so the fusion
-planner treats the traced CUSTOM node as a stitchable citizen instead of a
-hard partition boundary.
+Registered in :mod:`.registry` as ``_decode_attn_kernel``, so the cost
+model prices the traced CUSTOM node by its FLOPs.
 """
 
 from __future__ import annotations
@@ -24,13 +23,15 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import kernels
+
 NEG_INF = -1e30
 
 
 def _decode_attn_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref,
                         m_scr, l_scr, acc_scr, *,
                         scale: float, window: int | None, kb: int, nk: int):
-    ik = pl.program_id(2)
+    b, ik = pl.program_id(0), pl.program_id(2)
 
     @pl.when(ik == 0)
     def _init():
@@ -45,31 +46,30 @@ def _decode_attn_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref,
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale  # (1, kb)
 
-    qpos = pos_ref[0, 0]
+    qpos = pos_ref[b]
     kpos = ik * kb + jax.lax.broadcasted_iota(jnp.int32, (1, kb), 1)
     mask = kpos <= qpos
     if window is not None:
         mask = mask & (qpos - kpos < window)
     s = jnp.where(mask, s, NEG_INF)
 
-    m_old = m_scr[...]
-    m_new = jnp.maximum(m_old, jnp.max(s, axis=-1))
-    p = jnp.exp(s - m_new[:, None])
+    m_old = m_scr[...]                                   # (1, 1)
+    m_new = jnp.maximum(m_old, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
     alpha = jnp.exp(m_old - m_new)
-    l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1)
-    acc_scr[...] = acc_scr[...] * alpha[:, None] + jax.lax.dot_general(
+    l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
         p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
     m_scr[...] = m_new
 
     @pl.when(ik == nk - 1)
     def _fin():
         l = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, 0] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
 def decode_attention(q, k, v, positions, *, scale: float | None = None,
-                     window: int | None = None, block_k: int = 128,
-                     interpret: bool = True):
+                     window: int | None = None, block_k: int = 128):
     """q: (B, 1, Hq, Dh); k, v: (B, Smax, Hkv, Dh); positions: (B,) int32
     absolute position of each row's new token -> (B, 1, Hq, Dh).
 
@@ -87,7 +87,7 @@ def decode_attention(q, k, v, positions, *, scale: float | None = None,
         kb -= 1
     nk = Smax // kb
 
-    pos = positions.astype(jnp.int32).reshape(B, 1)
+    pos = positions.astype(jnp.int32).reshape(B)
     qt = q.transpose(0, 2, 1, 3)      # (B, Hq, 1, Dh)
     kt = k.transpose(0, 2, 1, 3)      # (B, Hkv, Smax, Dh)
     vt = v.transpose(0, 2, 1, 3)
@@ -98,7 +98,7 @@ def decode_attention(q, k, v, positions, *, scale: float | None = None,
         ),
         grid=(B, Hq, nk),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda b, h, ik: (b, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),     # all positions
             pl.BlockSpec((1, 1, 1, Dh), lambda b, h, ik: (b, h, 0, 0)),
             pl.BlockSpec((1, 1, kb, Dh),
                          lambda b, h, ik, _g=group: (b, h // _g, ik, 0)),
@@ -108,10 +108,10 @@ def decode_attention(q, k, v, positions, *, scale: float | None = None,
         out_specs=pl.BlockSpec((1, 1, 1, Dh), lambda b, h, ik: (b, h, 0, 0)),
         out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((1,), jnp.float32),
-            pltpu.VMEM((1,), jnp.float32),
+            pltpu.VMEM((1, 1), jnp.float32),
+            pltpu.VMEM((1, 1), jnp.float32),
             pltpu.VMEM((1, Dh), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=kernels.interpret_mode(),
     )(pos, qt, kt, vt)
     return out.transpose(0, 2, 1, 3)

@@ -21,6 +21,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import kernels
+
 NEG_INF = -1e30
 
 
@@ -69,8 +71,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 def flash_attention(q, k, v, *, causal: bool = True, scale: float | None = None,
                     window: int | None = None, q_offset: int = 0,
-                    block_q: int = 128, block_k: int = 128,
-                    interpret: bool = True):
+                    block_q: int = 128, block_k: int = 128):
     """q: (B, Lq, Hq, Dh); k, v: (B, Lkv, Hkv, Dh) -> (B, Lq, Hq, Dh).
 
     ``q_offset``: absolute position of q[0] (for chunked prefill / decode)."""
@@ -111,6 +112,6 @@ def flash_attention(q, k, v, *, causal: bool = True, scale: float | None = None,
             pltpu.VMEM((qb,), jnp.float32),
             pltpu.VMEM((qb, Dh), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=kernels.interpret_mode(),
     )(qt, kt, vt)
     return out.transpose(0, 2, 1, 3)

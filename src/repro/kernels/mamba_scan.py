@@ -19,6 +19,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro import kernels
+
 
 def _mamba_kernel(x_ref, d_ref, A_ref, B_ref, C_ref, D_ref, o_ref, *, L: int):
     A = A_ref[...].astype(jnp.float32)            # (db, N)
@@ -41,8 +43,7 @@ def _mamba_kernel(x_ref, d_ref, A_ref, B_ref, C_ref, D_ref, o_ref, *, L: int):
     jax.lax.fori_loop(0, L, step, h0)
 
 
-def mamba_scan(x, delta, A, B, C, D, *, block_channels: int = 256,
-               interpret: bool = True):
+def mamba_scan(x, delta, A, B, C, D, *, block_channels: int = 256):
     """x, delta: (Bb, L, Dm); A: (Dm, N); B, C: (Bb, L, N); D: (Dm,)."""
     Bb, L, Dm = x.shape
     N = A.shape[1]
@@ -62,6 +63,6 @@ def mamba_scan(x, delta, A, B, C, D, *, block_channels: int = 256,
         ],
         out_specs=pl.BlockSpec((1, L, db), lambda b, c: (b, 0, c)),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-        interpret=interpret,
+        interpret=kernels.interpret_mode(),
     )(x, delta, A, B, C, D)
     return out

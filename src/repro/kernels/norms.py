@@ -18,6 +18,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro import kernels
+
 DEFAULT_BLOCK_ROWS = 256
 
 
@@ -35,8 +37,7 @@ def _rmsnorm_kernel(x_ref, g_ref, o_ref, *, eps: float):
     o_ref[...] = (x * jax.lax.rsqrt(var + eps) * g_ref[...].astype(jnp.float32)).astype(o_ref.dtype)
 
 
-def rmsnorm(x, gamma, eps: float = 1e-6, *, block_rows: int = DEFAULT_BLOCK_ROWS,
-            interpret: bool = True):
+def rmsnorm(x, gamma, eps: float = 1e-6, *, block_rows: int = DEFAULT_BLOCK_ROWS):
     orig_shape = x.shape
     d = x.shape[-1]
     x2 = x.reshape(-1, d)
@@ -50,7 +51,7 @@ def rmsnorm(x, gamma, eps: float = 1e-6, *, block_rows: int = DEFAULT_BLOCK_ROWS
         ],
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(x2.shape, x.dtype),
-        interpret=interpret,
+        interpret=kernels.interpret_mode(),
     )(x2, gamma)
     return out.reshape(orig_shape)
 
@@ -63,7 +64,7 @@ def _rmsnorm_residual_kernel(x_ref, r_ref, g_ref, o_ref, res_ref, *, eps: float)
 
 
 def rmsnorm_residual(x, res, gamma, eps: float = 1e-6, *,
-                     block_rows: int = DEFAULT_BLOCK_ROWS, interpret: bool = True):
+                     block_rows: int = DEFAULT_BLOCK_ROWS):
     orig_shape = x.shape
     d = x.shape[-1]
     x2, r2 = x.reshape(-1, d), res.reshape(-1, d)
@@ -84,7 +85,7 @@ def rmsnorm_residual(x, res, gamma, eps: float = 1e-6, *,
             jax.ShapeDtypeStruct(x2.shape, x.dtype),
             jax.ShapeDtypeStruct(x2.shape, x.dtype),
         ],
-        interpret=interpret,
+        interpret=kernels.interpret_mode(),
     )(x2, r2, gamma)
     return normed.reshape(orig_shape), new_res.reshape(orig_shape)
 
@@ -97,7 +98,7 @@ def _layernorm_kernel(x_ref, g_ref, b_ref, o_ref, *, eps: float):
 
 
 def layernorm(x, gamma, beta, eps: float = 1e-5, *,
-              block_rows: int = DEFAULT_BLOCK_ROWS, interpret: bool = True):
+              block_rows: int = DEFAULT_BLOCK_ROWS):
     orig_shape = x.shape
     d = x.shape[-1]
     x2 = x.reshape(-1, d)
@@ -112,6 +113,6 @@ def layernorm(x, gamma, beta, eps: float = 1e-5, *,
         ],
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(x2.shape, x.dtype),
-        interpret=interpret,
+        interpret=kernels.interpret_mode(),
     )(x2, gamma, beta)
     return out.reshape(orig_shape)

@@ -2,8 +2,8 @@
 
 Models call these; ``KernelMode`` decides the backend:
 
-* ``"pallas"``  — the Pallas kernels (interpret=True on CPU; on TPU this is
-                  where ``interpret=False`` would flip).
+* ``"pallas"``  — the Pallas kernels (interpreted on the CPU, compiled on a
+                  TPU: :func:`repro.kernels.interpret_mode`).
 * ``"ref"``     — the pure-jnp oracles; clean HLO for the multi-pod dry-run
                   and for gradient tracing (several kernels are fwd-only).
 

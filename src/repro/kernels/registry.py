@@ -1,26 +1,18 @@
-"""Registry of *stitchable* custom (Pallas) kernels.
+"""Registry of the repo's own Pallas kernels, keyed by kernel identity.
 
 The tracer lowers every ``pallas_call`` to an opaque ``OpKind.CUSTOM`` node
-carrying an ``eval_fn`` that replays the saved primitive.  By default the
-fusion generator treats CUSTOM as a hard partition boundary — correct for
-arbitrary foreign ops, but it forces e.g. a transformer decode step into
-``gemm | attention | gemm | ...`` islands even though the attention kernel's
-body is perfectly composable with its surrounding projections.
+carrying an ``eval_fn`` that replays the saved primitive, and tags it with
+the kernel-body function name (``attrs["kernel"]``).  Such a node always
+partitions fusion: replaying its ``pallas_call`` inside a stitched kernel
+would nest one Pallas kernel in another, which the TPU's Mosaic lowering
+refuses.  What a registered kernel declares is its compute:
 
-This module is the allow-list that relaxes that: a kernel registered here
-declares the two facts the compiler needs to treat its CUSTOM node as a
-first-class stitching citizen —
+* ``flops`` — an MXU/compute estimate from the operand shapes, so the cost
+  model's roofline sees the kernel as compute-bearing rather than free.
 
-* ``flops``  — an MXU/compute estimate so the cost model's roofline sees
-  the kernel as compute-bearing rather than free;
-* ``scratch_bytes`` — the on-chip (VMEM) footprint its body allocates, so
-  the ILP can reject partitions whose combined scratch would not fit.
-
-The registry is keyed on the Pallas *kernel-body function name* (what
-``pl.pallas_call`` records as ``name_and_src_info``), which the tracer tags
-onto the node as ``attrs["kernel"]``.  Only :mod:`repro.core.ir` is imported
-here — no Pallas, no jax — so ``core.fusiongen -> kernels.registry`` adds no
-import cycles and no accelerator requirements at planning time.
+Only :mod:`repro.core.ir` is imported here — no Pallas, no jax — so
+``core.cost -> kernels.registry`` adds no import cycles and no accelerator
+requirements at planning time.
 """
 
 from __future__ import annotations
@@ -30,36 +22,31 @@ from typing import Callable, Optional
 
 from repro.core.ir import Graph, OpKind, OpNode
 
-__all__ = ["StitchableKernel", "register", "lookup", "registered_names"]
+__all__ = ["RegisteredKernel", "register", "lookup"]
 
 
 @dataclass(frozen=True)
-class StitchableKernel:
+class RegisteredKernel:
     """Compiler-facing descriptor of one registered Pallas kernel.
 
-    ``flops``/``scratch_bytes`` receive the CUSTOM node and its graph and
-    derive estimates from the *operand* shapes (output shapes are unreliable
-    for multi-output kernels, whose base node is shapeless)."""
+    ``flops`` receives the CUSTOM node and its graph and derives the
+    estimate from the *operand* shapes (output shapes are unreliable for
+    multi-output kernels, whose base node is shapeless)."""
 
     name: str
     flops: Callable[[OpNode, Graph], float]
-    scratch_bytes: Callable[[OpNode, Graph], int]
 
 
-_REGISTRY: dict[str, StitchableKernel] = {}
+_REGISTRY: dict[str, RegisteredKernel] = {}
 
 
-def register(desc: StitchableKernel) -> StitchableKernel:
+def register(desc: RegisteredKernel) -> RegisteredKernel:
     """Install (or replace) a descriptor under ``desc.name``."""
     _REGISTRY[desc.name] = desc
     return desc
 
 
-def registered_names() -> tuple[str, ...]:
-    return tuple(sorted(_REGISTRY))
-
-
-def lookup(node: OpNode) -> Optional[StitchableKernel]:
+def lookup(node: OpNode) -> Optional[RegisteredKernel]:
     """Descriptor for a CUSTOM node whose traced kernel tag is registered.
 
     Projection nodes (``attrs["project"]``) resolve through the same tag as
@@ -75,9 +62,7 @@ def lookup(node: OpNode) -> Optional[StitchableKernel]:
 # -- built-in descriptors -----------------------------------------------------
 #
 # The formulas mirror the actual kernel bodies (kernels/flash_attention.py,
-# kernels/router.py): flash keeps a (qb,) m/l pair plus a (qb, Dh) f32
-# accumulator in VMEM per grid step; the router is purely row-blocked with
-# no explicit scratch.
+# kernels/router.py, kernels/decode_attention.py, the VPU kernels).
 
 
 def _flash_flops(node: OpNode, g: Graph) -> float:
@@ -89,15 +74,6 @@ def _flash_flops(node: OpNode, g: Graph) -> float:
     lkv = kv[1]
     # QK^T and PV each cost 2*Lq*Lkv*Dh MACs per (batch, head)
     return 4.0 * b * hq * lq * lkv * dh
-
-
-def _flash_scratch(node: OpNode, g: Graph) -> int:
-    q = g[node.operands[0]].shape
-    if len(q) != 4:
-        return 0
-    _, lq, _, dh = q
-    qb = min(128, lq)                        # default block_q in the kernel
-    return qb * (2 + dh) * 4                 # f32 m + l + (qb, Dh) acc
 
 
 def _router_flops(node: OpNode, g: Graph) -> float:
@@ -124,19 +100,9 @@ def _decode_attn_flops(node: OpNode, g: Graph) -> float:
     return 4.0 * b * hq * smax * dh
 
 
-def _decode_attn_scratch(node: OpNode, g: Graph) -> int:
-    if len(node.operands) < 2:
-        return 0
-    q = g[node.operands[1]].shape
-    if len(q) != 4:
-        return 0
-    dh = q[3]
-    return (2 + dh) * 4                      # f32 m + l + (1, Dh) acc
-
-
 def _vpu_flops(per_elem: float, operand: int = 0):
     """Memory-bound VPU kernels (norms, rope, GLU): a few ops per element of
-    the named operand, no MXU work, no explicit scratch (VREG-only)."""
+    the named operand, no MXU work."""
 
     def flops(node: OpNode, g: Graph) -> float:
         if len(node.operands) <= operand:
@@ -146,13 +112,11 @@ def _vpu_flops(per_elem: float, operand: int = 0):
     return flops
 
 
-register(StitchableKernel("_flash_kernel", _flash_flops, _flash_scratch))
-register(StitchableKernel("_router_kernel", _router_flops, lambda n, g: 0))
-register(StitchableKernel("_decode_attn_kernel", _decode_attn_flops,
-                          _decode_attn_scratch))
-register(StitchableKernel("_rmsnorm_kernel", _vpu_flops(4.0), lambda n, g: 0))
-register(StitchableKernel("_rmsnorm_residual_kernel", _vpu_flops(5.0),
-                          lambda n, g: 0))
-register(StitchableKernel("_layernorm_kernel", _vpu_flops(6.0), lambda n, g: 0))
-register(StitchableKernel("_rope_kernel", _vpu_flops(6.0), lambda n, g: 0))
-register(StitchableKernel("_glu_kernel", _vpu_flops(4.0), lambda n, g: 0))
+register(RegisteredKernel("_flash_kernel", _flash_flops))
+register(RegisteredKernel("_router_kernel", _router_flops))
+register(RegisteredKernel("_decode_attn_kernel", _decode_attn_flops))
+register(RegisteredKernel("_rmsnorm_kernel", _vpu_flops(4.0)))
+register(RegisteredKernel("_rmsnorm_residual_kernel", _vpu_flops(5.0)))
+register(RegisteredKernel("_layernorm_kernel", _vpu_flops(6.0)))
+register(RegisteredKernel("_rope_kernel", _vpu_flops(6.0)))
+register(RegisteredKernel("_glu_kernel", _vpu_flops(4.0)))

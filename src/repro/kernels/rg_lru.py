@@ -15,6 +15,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro import kernels
+
 
 def _rglru_kernel(x_ref, ig_ref, rg_ref, lam_ref, o_ref, *, L: int, c: float):
     lam = jax.nn.softplus(lam_ref[...].astype(jnp.float32))     # (db,)
@@ -35,7 +37,7 @@ def _rglru_kernel(x_ref, ig_ref, rg_ref, lam_ref, o_ref, *, L: int, c: float):
 
 
 def rg_lru(x, input_gate, rec_gate, Lambda, c: float = 8.0, *,
-           block_channels: int = 512, interpret: bool = True):
+           block_channels: int = 512):
     """x, input_gate, rec_gate: (B, L, D); Lambda: (D,)."""
     B, L, D = x.shape
     db = min(block_channels, D)
@@ -52,6 +54,6 @@ def rg_lru(x, input_gate, rec_gate, Lambda, c: float = 8.0, *,
         ],
         out_specs=pl.BlockSpec((1, L, db), lambda b, ch: (b, 0, ch)),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-        interpret=interpret,
+        interpret=kernels.interpret_mode(),
     )(x, input_gate, rec_gate, Lambda)
     return out

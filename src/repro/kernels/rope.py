@@ -15,6 +15,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro import kernels
+
 
 def _rope_kernel(x_ref, pos_ref, o_ref, *, theta: float, half: int):
     x = x_ref[...].astype(jnp.float32)          # (br, H*Dh) flattened heads
@@ -30,8 +32,7 @@ def _rope_kernel(x_ref, pos_ref, o_ref, *, theta: float, half: int):
     o_ref[...] = out.reshape(o_ref.shape).astype(o_ref.dtype)
 
 
-def rope(x, positions, theta: float = 10000.0, *, block_rows: int = 256,
-         interpret: bool = True):
+def rope(x, positions, theta: float = 10000.0, *, block_rows: int = 256):
     """x: (B, L, H, Dh); positions: (B, L).  Returns rotated x."""
     B, L, H, Dh = x.shape
     half = Dh // 2
@@ -50,6 +51,6 @@ def rope(x, positions, theta: float = 10000.0, *, block_rows: int = 256,
         ],
         out_specs=pl.BlockSpec((br, H * Dh), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(x2.shape, x.dtype),
-        interpret=interpret,
+        interpret=kernels.interpret_mode(),
     )(x2, p2)
     return out.reshape(B, L, H, Dh)

@@ -15,6 +15,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro import kernels
+
 
 def _router_kernel(x_ref, w_ref, i_ref, *, k: int, renorm: bool):
     x = x_ref[...].astype(jnp.float32)                    # (br, E)
@@ -41,7 +43,7 @@ def _router_kernel(x_ref, w_ref, i_ref, *, k: int, renorm: bool):
 
 
 def topk_router(logits, k: int, renormalize: bool = True, *,
-                block_rows: int = 1024, interpret: bool = True):
+                block_rows: int = 1024):
     """logits (T, E) -> (weights (T, k), indices (T, k) int32)."""
     T, E = logits.shape
     br = min(block_rows, T)
@@ -59,6 +61,6 @@ def topk_router(logits, k: int, renormalize: bool = True, *,
             jax.ShapeDtypeStruct((T, k), logits.dtype),
             jax.ShapeDtypeStruct((T, k), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=kernels.interpret_mode(),
     )(logits)
     return weights, idx

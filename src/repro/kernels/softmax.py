@@ -12,6 +12,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro import kernels
+
 from .norms import DEFAULT_BLOCK_ROWS, _row_grid
 
 
@@ -34,7 +36,7 @@ def _softmax_masked_kernel(x_ref, m_ref, o_ref, *, scale: float):
 
 
 def softmax(x, scale: float = 1.0, mask=None, *,
-            block_rows: int = DEFAULT_BLOCK_ROWS, interpret: bool = True):
+            block_rows: int = DEFAULT_BLOCK_ROWS):
     orig_shape = x.shape
     d = x.shape[-1]
     x2 = x.reshape(-1, d)
@@ -46,7 +48,7 @@ def softmax(x, scale: float = 1.0, mask=None, *,
             in_specs=[pl.BlockSpec((br, d), lambda i: (i, 0))],
             out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
             out_shape=jax.ShapeDtypeStruct(x2.shape, x.dtype),
-            interpret=interpret,
+            interpret=kernels.interpret_mode(),
         )(x2)
     else:
         m2 = jnp.broadcast_to(mask, orig_shape).reshape(-1, d)
@@ -59,6 +61,6 @@ def softmax(x, scale: float = 1.0, mask=None, *,
             ],
             out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
             out_shape=jax.ShapeDtypeStruct(x2.shape, x.dtype),
-            interpret=interpret,
+            interpret=kernels.interpret_mode(),
         )(x2, m2)
     return out.reshape(orig_shape)

@@ -29,9 +29,11 @@ reduction, GEMM contracting over R).  The paper's layout constraint (§5.3:
 verbatim: a cross-row accumulator may feed other members only when the whole
 row space fits in one block (grid == 1).
 
-Everything is validated in ``interpret=True`` mode on CPU; BlockSpecs are
+The kernel runs in Pallas's interpreter on the CPU and compiles through
+Mosaic on a TPU (:func:`repro.kernels.interpret_mode`).  BlockSpecs are
 written for TPU VMEM tiling (row-block x full minor dims, sublane multiples
-of 8 preferred by the tuner).
+of 8 preferred by the tuner); :func:`block_bytes` is what those blocks hold
+in VMEM, which the cost model counts against the chip's on-chip budget.
 """
 
 from __future__ import annotations
@@ -45,14 +47,17 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from repro import kernels
 from repro.core.codegen import EW_OPS, canonical_dtype, eval_node
+from repro.core.cost import vmem_tile_bytes
 from repro.core.ir import Graph, OpKind, OpNode
 from repro.core.pattern import FusionPattern
 from repro.core.templates import Template
 
-__all__ = ["StitchAnalysis", "analyze_pattern", "build_stitched_callable",
-           "StitchInfeasible"]
+__all__ = ["StitchAnalysis", "analyze_pattern", "block_bytes",
+           "build_stitched_callable", "StitchInfeasible"]
 
 
 class StitchInfeasible(Exception):
@@ -260,25 +265,6 @@ def _analyze_with_rows(p: FusionPattern, rows: int,
                 raise StitchInfeasible(f"gather {name} from row-varying table")
         elif k is OpKind.TUPLE:
             roles[name] = INV
-        elif k is OpKind.CUSTOM:
-            if "project" in node.attrs:
-                # projection of a multi-output custom base: its own shape
-                # decides the role; the base is a shapeless tuple carrier
-                roles[name] = (ROW if node.shape and node.shape[0] == rows
-                               else INV)
-                continue
-            from .registry import lookup
-            if lookup(node) is None:
-                raise StitchInfeasible(f"unregistered custom kernel {name}")
-            if node.attrs.get("multi") and name in p.external_outputs:
-                raise StitchInfeasible(
-                    f"multi-output custom base {name} escapes the pattern")
-            # the saved eval_fn replays the pallas_call at its full traced
-            # shapes; one grid step over the whole row space makes every
-            # blocked shape equal its full shape, so the replay composes
-            single_block = True
-            roles[name] = (ROW if node.shape and node.shape[0] == rows
-                           else INV)
         else:
             raise StitchInfeasible(f"unsupported kind {k} in stitched kernel")
 
@@ -294,6 +280,26 @@ def _block_shape(shape: tuple[int, ...], role: str, rb: int) -> tuple[int, ...]:
     if role == ROW and shape:
         return (rb,) + shape[1:]
     return shape
+
+
+def _io_blocks(p: FusionPattern, ana: StitchAnalysis, rb: int):
+    """(block shape, dtype) of every kernel input then output, as the
+    BlockSpecs of :func:`build_stitched_callable` lay them out: row-role
+    tensors are cut to ``rb`` rows, everything else is resident whole
+    (scalars as ``(1,)``)."""
+    g = p.graph
+    for name in list(p.external_inputs) + list(p.external_outputs):
+        node = g[name]
+        shp = node.shape or (1,)
+        yield (_block_shape(shp, ana.roles[name], rb),
+               canonical_dtype(node.dtype))
+
+
+def block_bytes(p: FusionPattern, ana: StitchAnalysis, rb: int) -> int:
+    """VMEM bytes of one copy of the kernel's input and output blocks at
+    row block ``rb`` (the Pallas pipeline holds two)."""
+    return sum(vmem_tile_bytes(shp, jnp.dtype(dt).itemsize)
+               for shp, dt in _io_blocks(p, ana, rb))
 
 
 def _subst_rows(shape: tuple[int, ...], rows: int, rb: int) -> tuple[int, ...]:
@@ -325,7 +331,6 @@ def build_stitched_callable(
     *,
     row_block: int | None = None,
     scratch_ops: Sequence[str] = (),
-    interpret: bool = True,
 ) -> Callable[..., tuple]:
     """Emit the fused kernel.  Returns ``f(*external_inputs) -> tuple(outputs)``
     (input/output order = ``p.external_inputs`` / ``p.external_outputs``)."""
@@ -352,42 +357,23 @@ def build_stitched_callable(
     member_topo = [n for n in p.nodes if not n.is_source()]
     scratch_set = {s for s in scratch_ops if s in p.members and roles.get(s) == ROW}
 
-    in_specs = []
-    for name in ins:
-        node = g[name]
-        bs = _block_shape(node.shape, roles[name], rb)
+    specs = []
+    for name, (bs, _) in zip(ins + outs, _io_blocks(p, ana, rb)):
         if roles[name] == ROW:
-            in_specs.append(
-                pl.BlockSpec(bs, lambda i, _n=len(bs): (i,) + (0,) * (_n - 1))
-            )
-        else:
-            nd = len(node.shape)
-            in_specs.append(pl.BlockSpec(node.shape or (1,), lambda i, _n=nd: (0,) * max(_n, 1)))
+            specs.append(pl.BlockSpec(
+                bs, lambda i, _n=len(bs): (i,) + (0,) * (_n - 1)))
+        else:  # INV or ACC: the whole tensor every step
+            specs.append(pl.BlockSpec(bs, lambda i, _n=len(bs): (0,) * _n))
+    in_specs, out_specs = specs[:len(ins)], specs[len(ins):]
+    out_shapes = [jax.ShapeDtypeStruct(g[name].shape or (1,),
+                                       canonical_dtype(g[name].dtype))
+                  for name in outs]
 
-    out_specs = []
-    out_shapes = []
-    for name in outs:
-        node = g[name]
-        role = roles[name]
-        shp = node.shape or (1,)
-        if role == ROW:
-            bs = _block_shape(shp, ROW, rb)
-            out_specs.append(pl.BlockSpec(bs, lambda i, _n=len(bs): (i,) + (0,) * (_n - 1)))
-        else:  # INV or ACC: full tensor every step
-            out_specs.append(pl.BlockSpec(shp, lambda i, _n=len(shp): (0,) * _n))
-        out_shapes.append(jax.ShapeDtypeStruct(shp, canonical_dtype(node.dtype)))
-
-    scratch_shapes = []
     scratch_order = sorted(scratch_set)
-    for name in scratch_order:
-        node = g[name]
-        bs = _block_shape(node.shape, ROW, rb)
-        # VMEM scratch for TPU; plain ANY in interpret mode still allocates
-        try:
-            from jax.experimental.pallas import tpu as pltpu
-            scratch_shapes.append(pltpu.VMEM(bs, canonical_dtype(node.dtype)))
-        except Exception:  # pragma: no cover - pltpu always importable in jax>=0.4
-            scratch_shapes.append(jax.ShapeDtypeStruct(bs, canonical_dtype(node.dtype)))
+    scratch_shapes = [
+        pltpu.VMEM(_block_shape(g[name].shape, ROW, rb),
+                   canonical_dtype(g[name].dtype))
+        for name in scratch_order]
 
     n_in, n_out = len(ins), len(outs)
 
@@ -454,7 +440,7 @@ def build_stitched_callable(
         out_specs=out_specs,
         out_shape=out_shapes,
         scratch_shapes=scratch_shapes,
-        interpret=interpret,
+        interpret=kernels.interpret_mode(),
     )
 
     def run(*inputs):

@@ -1,5 +1,6 @@
-"""Pre-jax host-platform device forcing, shared by the launchers, the test
-suite, and the benchmark harness.
+"""Process environment shared by the launchers, the test suite, the
+benchmark harness and the chip smoke: pre-jax host-platform device forcing
+and the placement of JAX's persistent compile cache.
 
 jax locks the device count at first init, so this must run before the first
 jax import anywhere in the process — which is why this module deliberately
@@ -12,8 +13,13 @@ wins; other flags in the variable are preserved (the
 from __future__ import annotations
 
 import os
+from pathlib import Path
 
 FLAG = "--host-devices"
+
+# fixed, at the checkout root: a directory named per run (temporary name,
+# process id, time) would never be found again by the next run
+COMPILE_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
 
 
 def parse_host_devices(argv) -> int | None:
@@ -48,3 +54,15 @@ def force_host_devices(n: int | None = None, argv=None) -> None:
         return
     os.environ["XLA_FLAGS"] = (
         flags + f" --xla_force_host_platform_device_count={int(n)}").strip()
+
+
+def use_compile_cache() -> None:
+    """Turn on JAX's persistent compile cache.  Where
+    ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and no
+    directory is set here; otherwise the cache lives in ``.jax_cache/`` at
+    the checkout root."""
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return
+    import jax
+
+    jax.config.update("jax_compilation_cache_dir", str(COMPILE_CACHE_DIR))

@@ -8,7 +8,13 @@ dry-run wants 512 placeholder devices).
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
+
+
+def _auto_mesh(shape: tuple[int, ...], axes: tuple[str, ...]) -> Mesh:
+    """``jax.make_mesh`` with ``Auto`` axes: the stitched executors place
+    arrays through ``shard_map`` specs, not through sharding-in-types."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -16,7 +22,7 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     axis (gradient reduction crosses DCN on 'pod', ICI on 'data')."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(model_parallel: int = 1) -> Mesh:
@@ -36,5 +42,5 @@ def make_host_mesh(model_parallel: int = 1) -> Mesh:
         raise ValueError(
             f"model_parallel={model_parallel} does not divide the host "
             f"device count ({n} devices); valid values are {divisors}")
-    return jax.make_mesh((n // model_parallel, model_parallel),
-                         ("data", "model"))
+    return _auto_mesh((n // model_parallel, model_parallel),
+                      ("data", "model"))

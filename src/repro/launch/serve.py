@@ -31,10 +31,12 @@ from __future__ import annotations
 import sys
 
 # --host-devices must take effect before the first jax import (jax locks
-# the device count at first init); argparse proper still declares the flag
-from repro.launch.hostenv import force_host_devices
+# the device count at first init); argparse proper still declares the flag.
+# Only when run as the launcher: importing build_engine changes nothing.
+from repro.launch.hostenv import force_host_devices, use_compile_cache
 
-force_host_devices(argv=sys.argv)
+if __name__ == "__main__":
+    force_host_devices(argv=sys.argv)
 
 import argparse
 import json
@@ -149,7 +151,7 @@ def run_static(args, eng, prompts, news):
             "elapsed_s": dt, "tokens_per_sec": total_tokens / max(dt, 1e-9)}
 
 
-def main():
+def make_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")
@@ -206,7 +208,12 @@ def main():
     ap.add_argument("--metrics-json", default=None,
                     help="write the obs metrics-registry snapshot (counters/"
                          "histograms + serve/stitch reports) as JSON at exit")
-    args = ap.parse_args()
+    return ap
+
+
+def main():
+    args = make_parser().parse_args()
+    use_compile_cache()
     if args.max_len is None:
         args.max_len = args.prompt_len + args.new_tokens
 

@@ -29,7 +29,7 @@ import sys
 
 # --host-devices must take effect before the first jax import (jax locks
 # the device count at first init); argparse proper still declares the flag
-from repro.launch.hostenv import force_host_devices
+from repro.launch.hostenv import force_host_devices, use_compile_cache
 
 force_host_devices(argv=sys.argv)
 
@@ -91,6 +91,7 @@ def main():
                     help="write the obs metrics-registry snapshot (plus the "
                          "stitched step report) as JSON at exit")
     args = ap.parse_args()
+    use_compile_cache()
 
     from repro import obs
     if args.trace_out:

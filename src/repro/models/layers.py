@@ -269,7 +269,7 @@ def apply_attention(
         if S == 1 and ops.get_mode() == "pallas" \
                 and not cfg.shard_activations:
             # registered Pallas decode kernel: the whole masked-softmax
-            # attention chain is ONE stitchable CUSTOM node (the position
+            # attention chain is ONE CUSTOM node (the position
             # mask covers length validity, so stale cache rows never
             # contribute — same semantics as the einsum path below)
             out = ops.decode_attention(q, ck, cv, positions[:, 0],

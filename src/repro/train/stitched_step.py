@@ -110,11 +110,8 @@ class StitchedTrainStep:
         self._grad = stitch(self._grad_fn, service=self.service,
                             name="train_grad")
         self._grad.warmup(state.params, batch)
-        try:
-            self._packed = PackedAdamW(self.opt_cfg, state.params,
-                                       rows=self.rows, service=self.service)
-        except Exception:
-            self._packed = None
+        self._packed = PackedAdamW(self.opt_cfg, state.params,
+                                   rows=self.rows, service=self.service)
 
     def _prepare_sharded(self, state: TrainState, batch) -> None:
         from repro.cache.signature import placement_key
@@ -158,15 +155,12 @@ class StitchedTrainStep:
         self._grad.warmup(state.params, batch)
 
         # optimizer: per-shard packed panels over TP-local param slices
-        try:
-            local_params = local_avals(aparams, pspecs, mesh)
-            self._packed = PackedAdamW(
-                self.opt_cfg, local_params, rows=self.rows,
-                service=self.service, external_ssq=True,
-                placement=placement_key(mesh, pspecs))
-        except Exception:
-            self._packed = None
-        if not self._grad.ok or self._packed is None:
+        local_params = local_avals(aparams, pspecs, mesh)
+        self._packed = PackedAdamW(
+            self.opt_cfg, local_params, rows=self.rows,
+            service=self.service, external_ssq=True,
+            placement=placement_key(mesh, pspecs))
+        if not self._grad.ok:
             return
 
         packed = self._packed

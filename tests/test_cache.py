@@ -152,7 +152,7 @@ def test_plans_keyed_by_hardware():
 
 def test_plans_keyed_by_gen_config():
     """A plan solved under one GenConfig must not replay under another —
-    the knobs (large_gemm_flops, stitch_custom, ...) change which patterns
+    the knobs (large_gemm_flops, pack_patterns, ...) change which patterns
     exist, so a stale hit would silently execute the wrong plan."""
     from repro.core.fusiongen import GenConfig
     cache = StitchCache()

@@ -81,12 +81,12 @@ def test_exploratory_no_cycles_and_fusible_kinds():
     for p in pats:
         assert not p.creates_cycle()
         for n in p.nodes:
-            # small GEMMs (below large_gemm_flops) and registered custom
-            # kernels are explorable alongside the classic fusible kinds
+            # small GEMMs (below large_gemm_flops) are explorable alongside
+            # the classic fusible kinds; custom kernels never are
             assert n.kind in (
                 OpKind.ELEMENTWISE, OpKind.BROADCAST, OpKind.RESHAPE,
                 OpKind.TRANSPOSE, OpKind.REDUCTION, OpKind.BATCHED_GEMM,
-                OpKind.GEMM, OpKind.CUSTOM)
+                OpKind.GEMM)
             if n.kind is OpKind.GEMM:
                 from repro.core.fusiongen import _gemm_flops
                 assert _gemm_flops(g, n) < cfg.large_gemm_flops

@@ -181,3 +181,23 @@ def test_mamba_final_state_matches_incremental():
         dBx = (dt[:, t] * x[:, t])[..., None] * B[:, t][:, None, :]
         hinc = dA * hinc + dBx
     np.testing.assert_allclose(np.asarray(h), hinc, rtol=1e-4, atol=1e-5)
+
+
+def test_interpret_mode_follows_platform():
+    """Pallas runs interpreted exactly on the CPU backend."""
+    import jax
+
+    from repro import kernels
+
+    assert kernels.interpret_mode() == (jax.default_backend() == "cpu")
+
+
+def test_block_bytes_pad_to_the_chip_tile():
+    """VMEM blocks pad their two minor dims to (8 sublanes of 32 bits,
+    128 lanes): a (4, 1, 2048) bfloat16 block holds 4 x 16 x 2048 x 2."""
+    from repro.core.cost import vmem_tile_bytes
+
+    assert vmem_tile_bytes((4, 1, 2048), 2) == 4 * 16 * 2048 * 2
+    assert vmem_tile_bytes((8, 2048), 4) == 8 * 2048 * 4
+    assert vmem_tile_bytes((1,), 4) == 8 * 128 * 4
+    assert vmem_tile_bytes((3, 100), 1) == 32 * 128

@@ -20,12 +20,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.cache import CompilationService, StitchCache, placement_key
 from repro.configs import get_reduced
 from repro.core import StitchCompiler
+from repro.launch.mesh import make_host_mesh
 from repro.models import build_model, local_shape
 from repro.optim import AdamWConfig
 from repro.train import StitchedTrainStep, init_state, make_train_step
@@ -41,7 +41,7 @@ UPGRADE_AT = 2           # steps 0-1 on the XLA fallback, 2-3 stitched
 def mesh():
     if len(jax.devices()) < 8:
         pytest.skip("needs the forced 8-device host platform")
-    return jax.make_mesh((4, 2), ("data", "model"))
+    return make_host_mesh(2)
 
 
 @pytest.fixture(scope="module")
@@ -65,7 +65,6 @@ def make_batch(vocab, i):
 # ---------------------------------------------------------------------------
 
 def test_make_host_mesh_validates_divisibility():
-    from repro.launch.mesh import make_host_mesh
     n = len(jax.devices())
     assert n == 8
     mesh = make_host_mesh(2)
@@ -374,7 +373,7 @@ def test_trace_collective_as_custom_partition(mesh):
         return outs[g.outputs[0]]
 
     x = jnp.arange(8 * 4, dtype=jnp.float32).reshape(8, 4)
-    got = shard_map(body, mesh=mesh, in_specs=P(("data", "model")),
-                    out_specs=P(), check_rep=False)(x)
+    got = jax.shard_map(body, mesh=mesh, in_specs=P(("data", "model")),
+                        out_specs=P(), check_vma=False)(x)
     want = np.mean(np.asarray(x) * 2.0 + 1.0, axis=0, keepdims=True)
     np.testing.assert_allclose(np.asarray(got), want, rtol=1e-6)

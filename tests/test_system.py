@@ -182,3 +182,29 @@ def test_zero1_opt_state_specs():
     m_specs = jax.tree.leaves(ospecs.m, is_leaf=lambda x: isinstance(x, P))
     assert any("data" in str(s) for s in m_specs), \
         "no m/v leaf picked up the data axis"
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_placement(monkeypatch, tmp_path, from_env):
+    """The persistent compile cache goes where JAX_COMPILATION_CACHE_DIR
+    says (the code then sets no directory), else to the fixed .jax_cache/
+    at the checkout root."""
+    from pathlib import Path
+
+    from repro.launch.hostenv import COMPILE_CACHE_DIR, use_compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    if from_env:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        use_compile_cache()
+        after = jax.config.jax_compilation_cache_dir
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    if from_env:
+        assert after == before
+    else:
+        assert after == str(COMPILE_CACHE_DIR)
+        assert COMPILE_CACHE_DIR.parent == Path(__file__).resolve().parents[1]
