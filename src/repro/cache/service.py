@@ -292,8 +292,6 @@ class StitchCache:
                 f"failed static verification and was demoted to a miss:\n"
                 f"{format_findings(bad, limit=5)}",
                 RuntimeWarning, stacklevel=4)
-        obs.event("cache.verify_demote", cat="cache", graph=g.name,
-                  bucket=key[1], codes=sorted({f.code for f in bad}))
         return None
 
     def _remember_live(self, g: Graph, compiled: CompiledGraph, compiler,

@@ -146,6 +146,12 @@ class CompiledGraph:
 
     Calling it runs the graph group-by-group (each group = one kernel):
     stitched groups through their Pallas callable, the rest through jnp.
+
+    ``launches`` and ``uploads`` count what the last call dispatched: each
+    Pallas callable call and each ``eval_node`` call is one launch; each
+    constant, and each input that is not already a device array, is one
+    ``jnp.asarray`` upload.  Called under a trace (a ``shard_map`` body),
+    they count the walk that was traced.
     """
 
     def __init__(self, g: Graph, groups: list[_Group], stats: FusionStats):
@@ -153,6 +159,8 @@ class CompiledGraph:
         self.groups = groups
         self.stats = stats
         self._order = self._schedule()
+        self.launches = 0
+        self.uploads = 0
 
     def _schedule(self) -> list[_Group]:
         g = self.graph
@@ -187,14 +195,18 @@ class CompiledGraph:
 
         g = self.graph
         env: dict[str, jax.Array] = {}
+        launches = uploads = 0
         for name, node in g.nodes.items():
             if node.is_source():
+                if not isinstance(inputs.get(name), jax.Array):
+                    uploads += 1
                 env[name] = source_value(node, inputs)
         for grp in self._order:
             if grp.kind == "pallas" and grp.tuned and grp.tuned.callable:
                 p = grp.tuned.pattern
                 args = [env[i] for i in p.external_inputs]
                 outs = grp.tuned.callable(*args)
+                launches += 1
                 for nm, val in zip(p.external_outputs, outs):
                     env[nm] = val
             else:
@@ -203,6 +215,8 @@ class CompiledGraph:
                 for nm in topo:
                     node = g[nm]
                     env[nm] = eval_node(node, [env[o] for o in node.operands], g)
+                    launches += 1
+        self.launches, self.uploads = launches, uploads
         return {o: env[o] for o in g.outputs}
 
 

@@ -224,6 +224,7 @@ class StitchedFunction:
         self.stitched_calls = 0          # served through the compiled artifact
         self.fallback_calls = 0          # drift / trace failure -> jit
         self.jit_calls = 0               # by-design jit ("jit"/"shadow" modes)
+        self._span_name = f"exec.{self.name}"
         # path -> measured-wall-clock Histogram, populated only while the
         # opt-in kernel timer (repro.obs.timer) is enabled
         self._measured: dict[str, obs.Histogram] = {}
@@ -391,7 +392,9 @@ class StitchedFunction:
             self._poll(self._active)
 
     # -- execution -------------------------------------------------------------
-    def _run(self, sp: _Specialization, dyn, kwargs):
+    def _run(self, sp: _Specialization, dyn, kwargs, wsp=obs.NULL_SPAN):
+        """Execute the compiled artifact; ``wsp`` (the ``exec.walk`` span)
+        gets the dispatches the walk issued."""
         if sp.sharded:
             if sp.sm_for is not sp.compiled:
                 compiled, graph = sp.compiled, sp.graph
@@ -410,9 +413,12 @@ class StitchedFunction:
                     body, mesh=self.mesh, in_specs=sp.sm_in_specs,
                     out_specs=sp.sm_out_specs, check_vma=False))
                 sp.sm_for = sp.compiled
+            # the walk was traced into this one jitted program
+            wsp.set(launches=1, uploads=0)
             return sp.sm_fn(*dyn)
         env = dict(zip(sp.names, jax.tree_util.tree_leaves((dyn, kwargs))))
         outs = sp.compiled(env)
+        wsp.set(launches=sp.compiled.launches, uploads=sp.compiled.uploads)
         flat = [outs[o] for o in sp.graph.outputs]
         return jax.tree_util.tree_unflatten(sp.out_tree, flat)
 
@@ -465,21 +471,17 @@ class StitchedFunction:
                             and not leaf.is_deleted()):
                         leaf.delete()
 
-    def _observe(self, path: str, fn, *call_args):
-        """Span + opt-in measured timer around one served call.  Both
-        tracer and timer off (the default) is a two-attribute-read
-        passthrough, so the serving hot path pays nothing unobserved."""
-        if not (obs.tracer.enabled or _ktimer.enabled):
+    def _timed(self, path: str, fn, *call_args):
+        """The opt-in measured timer around one served call; timer off (the
+        default) is a one-attribute-read passthrough."""
+        if not _ktimer.enabled:
             return fn(*call_args)
-        with obs.span(f"exec.{self.name}", cat="exec", path=path):
-            if not _ktimer.enabled:
-                return fn(*call_args)
-            t0 = time.perf_counter()
-            out = fn(*call_args)
-            # bracket device execution, not just async dispatch
-            jax.block_until_ready(out)
-            self._record_measured(path, time.perf_counter() - t0)
-            return out
+        t0 = time.perf_counter()
+        out = fn(*call_args)
+        # bracket device execution, not just async dispatch
+        jax.block_until_ready(out)
+        self._record_measured(path, time.perf_counter() - t0)
+        return out
 
     def _record_measured(self, path: str, measured_s: float) -> None:
         h = self._measured.get(path)
@@ -493,25 +495,39 @@ class StitchedFunction:
         _ktimer.record(self.name, path, measured_s, modeled_s=modeled,
                        placement=sp.placement if sp is not None else "")
 
-    def __call__(self, *args, **kwargs):
+    def _route(self, args, kwargs):
+        """(path, specialization, dynamic args) for one call: the drift
+        check and the upgrade poll, counted per path."""
         statics, dyn = self._split(args)
         if self.mode == "jit":
             self.jit_calls += 1
-            return self._observe("jit", self._jit_call, args, dyn, kwargs)
+            return "jit", None, dyn
         sp = self._get(statics, dyn, kwargs)
         if not sp.ok or sp.in_sig != self._in_sig(dyn, kwargs):
             self.fallback_calls += 1
-            return self._observe("fallback", self._jit_call, args, dyn, kwargs)
+            return "fallback", None, dyn
         if self.mode != "offline":
             self._poll(sp)
         if self.mode == "shadow":
             self.jit_calls += 1
-            return self._observe("jit", self._jit_call, args, dyn, kwargs)
-        out = self._observe("stitched", self._run, sp, dyn, kwargs)
-        self.stitched_calls += 1
-        if self.donate_argnums:
-            self._donate(args, out)
-        return out
+            return "jit", None, dyn
+        return "stitched", sp, dyn
+
+    def __call__(self, *args, **kwargs):
+        # spans: exec.<name> holds exec.check (routing) and, on the
+        # stitched path, exec.walk (the artifact's dispatches)
+        with obs.span(self._span_name, cat="exec") as osp:
+            with obs.span("exec.check", cat="exec"):
+                path, sp, dyn = self._route(args, kwargs)
+            osp.set(path=path)
+            if path != "stitched":
+                return self._timed(path, self._jit_call, args, dyn, kwargs)
+            with obs.span("exec.walk", cat="exec") as wsp:
+                out = self._timed(path, self._run, sp, dyn, kwargs, wsp)
+            self.stitched_calls += 1
+            if self.donate_argnums:
+                self._donate(args, out)
+            return out
 
     # -- introspection ---------------------------------------------------------
     def warmup(self, *args, **kwargs) -> str | None:
@@ -567,7 +583,9 @@ class StitchedFunction:
                 "packed_subgraphs": getattr(s, "packed_subgraphs", 0),
                 "modeled_time": s.modeled_time,
                 "cache_status": s.cache_status,
-                "verify": getattr(s, "verify", None)}
+                "verify": getattr(s, "verify", None),
+                "launches": sp.compiled.launches,
+                "uploads": sp.compiled.uploads}
 
     def land_plans(self, timeout: float | None = None) -> int:
         """Join background compiles and poll EVERY specialization's upgrade

@@ -2,12 +2,15 @@
 
 One subsystem, three pieces (see the per-module docs):
 
-* :mod:`.trace` — structured span tracing with Chrome-trace / Perfetto
-  export.  Every pipeline stage is instrumented: trace → pattern-gen →
-  ILP/greedy → tune, cache hit/miss/replay, background compile
-  start/land/fail, the fallback→stitched upgrade, and per-step serve /
-  train execution (slot occupancy, evictions).  A whole run renders as a
-  timeline in https://ui.perfetto.dev.
+* :mod:`.trace` — structured spans and instant events.  Every pipeline
+  stage is instrumented: trace → pattern-gen → ILP/greedy → tune, cache
+  hit/miss/replay, background compile start/land/fail, the
+  fallback→stitched upgrade, and the served path where the work happens.
+  A span goes to two clocks at once: the in-process buffer
+  (``time.perf_counter``, exported as Chrome-trace JSON for
+  https://ui.perfetto.dev) and, through ``jax.profiler.TraceAnnotation``,
+  the JAX profiler's host plane, where it lines up with the device ops it
+  launched whenever a profiler session records.
 * :mod:`.metrics` — counters / gauges / histograms with the one shared
   percentile summary, a process :class:`~.metrics.MetricsRegistry`, and
   JSON + Prometheus-text export.  Existing report dicts plug in via
@@ -15,6 +18,21 @@ One subsystem, three pieces (see the per-module docs):
 * :mod:`.timer` — opt-in ``block_until_ready``-bracketed measured-kernel
   timing (measured-vs-modeled per plan), feeding the registry, the
   tracer, and ``benchmarks/run.py --json``.
+
+Spans of one served decode step (``Engine.generate_step``), outermost
+first; each per-request span carries the request's ``rid``:
+
+* ``serve.decode`` (``slots``, ``steps``) holding, in order,
+  ``serve.decode.prep`` (page growth, page-table/length/token uploads),
+  ``exec.decode_step`` (``path``: stitched / fallback / jit) with its
+  ``exec.check`` (signature drift check, upgrade poll) and ``exec.walk``
+  (the eager group walk; ``launches`` and ``uploads`` it issued),
+  ``serve.decode.sample`` (argmax, cast, concat), ``serve.decode.readback``
+  (the blocking token copy) and ``serve.decode.commit`` (pool, lengths,
+  last tokens);
+* ``serve.prefill`` (``rid``) holding ``exec.prefill`` > ``exec.check`` /
+  ``exec.walk``, then ``serve.prefill.readback``; ``serve.insert`` and
+  ``serve.release`` (``rid``, ``slot``).
 
 Both the tracer and the timer are **off by default** and their hot-path
 checks are single attribute reads, so instrumentation in per-token code
@@ -30,6 +48,8 @@ costs nothing unobserved.  Typical wiring (what ``launch/train.py`` and
     obs.registry().to_json("metrics.json")
     print(obs.registry().to_prometheus())
 
+To see the spans beside the device, enable tracing while
+``jax.profiler.start_trace`` records; no other switch is needed.
 ``python -m repro.launch.inspect trace.json`` prints the compile timeline
 and the per-plan modeled-vs-measured table offline.
 """

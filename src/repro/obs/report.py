@@ -24,7 +24,14 @@ key                 meaning
 ``specializations`` number of traced (static-arg) specializations
 ``placement``       active mesh+PartitionSpec cache key ("" = single-device)
 ``plan``            active plan stats (mode, n_ops, n_kernels,
-                    pallas_groups, modeled_time, cache_status) or None
+                    pallas_groups, modeled_time, cache_status, launches,
+                    uploads) or None.  ``launches``: dispatches the last
+                    walk of the artifact issued (Pallas callable calls plus
+                    ``eval_node`` calls); ``uploads``: constants and host
+                    values it put on the device with ``jnp.asarray``.
+                    Both are counted as the walk runs
+                    (:class:`repro.core.compiler.CompiledGraph`); 0 before
+                    the first call
 ``error``           this function's trace/compile failure message or None
 ``errors``          *all* per-key background-compile failures recorded by
                     the :class:`repro.cache.CompilationService`
@@ -77,9 +84,9 @@ def validate_exec_report(rep: dict) -> list[str]:
     if not isinstance(rep.get("errors", None), dict):
         problems.append("errors must be a dict (possibly empty)")
     plan = rep.get("plan")
-    if plan is not None and not {"n_kernels", "n_ops",
-                                 "modeled_time"} <= set(plan):
-        problems.append(f"plan missing kernel/op/time stats: {plan!r}")
+    if plan is not None and not {"n_kernels", "n_ops", "modeled_time",
+                                 "launches", "uploads"} <= set(plan):
+        problems.append(f"plan missing kernel/op/time/walk stats: {plan!r}")
     cache = rep.get("cache")
     if cache is not None and not {"total_hits", "total_misses",
                                   "per_placement"} <= set(cache):

@@ -20,11 +20,21 @@ https://ui.perfetto.dev load directly, so one stitched train or serve run
 renders as a timeline: compile stages on the background threads, per-step
 decode spans on the main thread, with hit/miss/upgrade markers in between.
 
+While the tracer is enabled every span also enters a
+``jax.profiler.TraceAnnotation`` of the same name and args (``set`` adds
+its args to both).  When a JAX profiler session is recording, the span
+therefore lands on the xplane's host plane, on the profiler's clock, beside
+the device ops it launched; when none records, the annotation costs one
+native call.  ``jax`` is imported when tracing is first enabled, so a
+process that never enables it imports nothing here.
+
 Overhead contract: a *disabled* tracer's ``span()`` returns a shared
 no-op context manager and ``event()`` returns immediately after one
 attribute check — instrumentation left in hot paths (per-token decode) is
-free when tracing is off.  Timestamps are microseconds since the tracer's
-epoch (``time.perf_counter`` based), the unit Chrome trace expects.
+free when tracing is off.  Arguments are built at the call site either
+way, so hot-path spans pass plain ints and strings.  Buffer timestamps are
+microseconds since the tracer's epoch (``time.perf_counter`` based), the
+unit Chrome trace expects.
 """
 
 from __future__ import annotations
@@ -57,27 +67,34 @@ NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    """One live span; records a ``ph: "X"`` complete event on exit."""
+    """One live span; records a ``ph: "X"`` complete event on exit and
+    brackets a profiler annotation of the same name."""
 
-    __slots__ = ("_tracer", "name", "cat", "args", "_t0")
+    __slots__ = ("_tracer", "name", "cat", "args", "_t0", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str, args: dict):
         self._tracer = tracer
         self.name = name
         self.cat = cat
         self.args = args
+        self._ann = None
 
     def set(self, **args) -> None:
         """Attach/override args discovered while the span is open (e.g. a
         scheduler step's admission/eviction counts, known only at the end)."""
         self.args.update(args)
+        if self._ann is not None:
+            self._ann.set_metadata(**args)
 
     def __enter__(self):
+        self._ann = self._tracer._annotation(self.name, **self.args)
+        self._ann.__enter__()
         self._t0 = self._tracer._now()
         return self
 
     def __exit__(self, *exc):
         t1 = self._tracer._now()
+        self._ann.__exit__(*exc)
         self._tracer._record({
             "ph": "X", "name": self.name, "cat": self.cat or "span",
             "ts": self._t0, "dur": t1 - self._t0,
@@ -98,12 +115,15 @@ class Tracer:
     """
 
     def __init__(self, enabled: bool = False):
-        self.enabled = enabled
+        self.enabled = False
         self.pid = os.getpid()
         self._events: list[dict] = []
         self._thread_names: dict[int, str] = {}
         self._lock = threading.Lock()
         self._epoch = time.perf_counter()
+        self._annotation = None      # jax.profiler.TraceAnnotation, lazily
+        if enabled:
+            self.enable()
 
     # -- clock ----------------------------------------------------------------
     def _now(self) -> float:
@@ -147,6 +167,9 @@ class Tracer:
 
     # -- lifecycle ------------------------------------------------------------
     def enable(self) -> None:
+        if self._annotation is None:
+            from jax.profiler import TraceAnnotation
+            self._annotation = TraceAnnotation
         self.enabled = True
 
     def disable(self) -> None:

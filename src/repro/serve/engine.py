@@ -119,6 +119,7 @@ class Engine:
         # which slots hold a live request
         self._tok = np.zeros((cfg.batch, 1), np.int32)
         self._occupied: set[int] = set()
+        self._rid = [-1] * cfg.batch     # request id per slot, for spans
         self._kv: PagedKV | DenseKV | None = None
         self._prefix = None
         self._exec = self._build_exec()
@@ -252,6 +253,7 @@ class Engine:
         to a slot.  Single-row prompts first consult the prefix cache."""
         from .scheduler import ADMISSION_BUCKET
         extra = dict(extra or {})
+        rid = -1 if rid is None else rid
         toks = np.asarray(tokens, np.int32)
         if toks.ndim == 1:
             toks = toks[None]
@@ -267,21 +269,22 @@ class Engine:
         if cacheable:
             hit = self.prefix_cache.lookup(toks[0, :int(lens[0])])
             if hit is not None:
-                obs.event("serve.prefill", cat="serve",
-                          rid=-1 if rid is None else rid,
+                obs.event("serve.prefill", cat="serve", rid=rid,
                           prompt_len=int(lens[0]), cached=True)
+                hit.rid = rid
                 return hit
         pb = min(ADMISSION_BUCKET.bucket_dim(Pn), self.cfg.max_len)
         padded = np.zeros((B, pb), np.int32)
         padded[:, :Pn] = toks
-        with obs.span("serve.prefill", cat="serve",
-                      rid=-1 if rid is None else rid,
+        with obs.span("serve.prefill", cat="serve", rid=rid,
                       prompt_len=int(lens.max()), bucket=pb, batch=B,
                       cached=False):
             logits, cache = self._prefill_exec(
                 self.params, jnp.asarray(padded), jnp.asarray(lens), extra)
-        first = np.asarray(jnp.argmax(logits, axis=-1)).astype(np.int64)
-        px = Prefix(lengths=lens, first_tokens=first, bucket=pb, kv=cache)
+        with obs.span("serve.prefill.readback", cat="serve", rid=rid):
+            first = np.asarray(jnp.argmax(logits, axis=-1)).astype(np.int64)
+        px = Prefix(lengths=lens, first_tokens=first, bucket=pb, kv=cache,
+                    rid=rid)
         if cacheable:
             self.prefix_cache.register(toks[0, :int(lens[0])], cache,
                                        row=0, first_token=int(first[0]),
@@ -299,11 +302,14 @@ class Engine:
             raise RuntimeError(f"slot {slot} already holds a request "
                                f"(release it first)")
         true_len = int(prefix.lengths[row])
-        if prefix.pages is not None:
-            self.kv.insert_shared(prefix.pages, prefix.tail, true_len, slot)
-        else:
-            self.kv.insert_kv(prefix.kv, row, true_len, slot)
+        with obs.span("serve.insert", cat="serve", rid=prefix.rid, slot=slot):
+            if prefix.pages is not None:
+                self.kv.insert_shared(prefix.pages, prefix.tail, true_len,
+                                      slot)
+            else:
+                self.kv.insert_kv(prefix.kv, row, true_len, slot)
         self._tok[slot, 0] = int(prefix.first_tokens[row])
+        self._rid[slot] = prefix.rid
         self._occupied.add(slot)
 
     # -- stage 3: generate -----------------------------------------------------
@@ -317,30 +323,42 @@ class Engine:
                                "(insert a prefix first)")
         extra = dict(extra or {})
         occ = sorted(self._occupied)
-        if self.paged:
-            for s in occ:
-                self.kv.ensure(s, steps)
-        cache = self.kv.decode_cache()
-        # copy: jnp.asarray may alias the numpy buffer, which is mutated
-        # below while the chunk is still in flight on some backends
-        tok = jnp.asarray(self._tok.copy())
-        toks_dev = []
-        for _ in range(steps):
-            logits, cache = self._decode_dispatch(cache, tok, extra)
-            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None]
-            toks_dev.append(tok)
-        self.kv.absorb(cache)
-        self.kv.advance(occ, steps)
-        out = np.asarray(jnp.concatenate(toks_dev, axis=1))
-        for s in occ:
-            self._tok[s, 0] = int(out[s, -1])
+        with obs.span("serve.decode", cat="serve", slots=len(occ),
+                      steps=steps):
+            with obs.span("serve.decode.prep", cat="serve"):
+                if self.paged:
+                    for s in occ:
+                        self.kv.ensure(s, steps)
+                cache = self.kv.decode_cache()
+                # copy: jnp.asarray may alias the numpy buffer, which is
+                # mutated below while the chunk is still in flight on some
+                # backends
+                tok = jnp.asarray(self._tok.copy())
+            toks_dev = []
+            for _ in range(steps):
+                logits, cache = self._decode_dispatch(cache, tok, extra)
+                with obs.span("serve.decode.sample", cat="serve"):
+                    tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None]
+                    toks_dev.append(tok)
+                    if len(toks_dev) == steps:
+                        toks = jnp.concatenate(toks_dev, axis=1)
+            with obs.span("serve.decode.readback", cat="serve"):
+                out = np.asarray(toks)
+            with obs.span("serve.decode.commit", cat="serve"):
+                self.kv.absorb(cache)
+                self.kv.advance(occ, steps)
+                for s in occ:
+                    self._tok[s, 0] = int(out[s, -1])
         return out
 
     def release(self, slot: int) -> None:
         """Free a finished slot: paged engines return its pages to the
         allocator (decref for prefix-shared ones) immediately."""
-        self.kv.free(slot)
+        with obs.span("serve.release", cat="serve", rid=self._rid[slot],
+                      slot=slot):
+            self.kv.free(slot)
         self._tok[slot, 0] = 0
+        self._rid[slot] = -1
         self._occupied.discard(slot)
         self._debug_audit(f"release(slot={slot})")
 

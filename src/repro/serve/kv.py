@@ -51,6 +51,7 @@ class Prefix:
     pages: list[int] | None = None       # shared full pages (prefix cache)
     tail: tuple | None = None            # (k, v): (L, page_size, H, dh) device
     cached: bool = False                 # True when served from the PrefixCache
+    rid: int = -1                        # request id, for per-request spans
 
     @property
     def batch(self) -> int:

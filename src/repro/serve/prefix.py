@@ -20,7 +20,6 @@ from dataclasses import dataclass
 
 import numpy as np
 
-from repro import obs
 from repro.cache import token_prefix_keys
 
 from .kv import PagedKV, Prefix
@@ -58,12 +57,9 @@ class PrefixCache:
         entry = self._entries.get(key)
         if entry is None:
             self.misses += 1
-            obs.event("serve.prefix.miss", cat="serve", key=key)
             return None
         self._entries.move_to_end(key)
         self.hits += 1
-        obs.event("serve.prefix.hit", cat="serve", key=key,
-                  length=entry.length)
         return Prefix(
             lengths=np.array([entry.length], np.int32),
             first_tokens=np.array([entry.first_token], np.int64),

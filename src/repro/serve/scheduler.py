@@ -235,10 +235,7 @@ class Scheduler:
                 tokens=tokens, queue_depth=m.queue_depth,
                 stitch_status=m.stitch_status)
         ssp.__exit__(None, None, None)
-        # a Perfetto counter track per series: occupancy + queue over time
-        obs.counter_event("serve.slots", cat="serve", active=active,
-                          free=self.cfg.slots - active,
-                          queue_depth=m.queue_depth)
+        # a Perfetto counter track of page-pool occupancy over time
         if self.engine.paged and self.engine._kv is not None:
             alloc = self.engine.kv.allocator
             obs.counter_event("serve.pages", cat="serve", used=alloc.used,

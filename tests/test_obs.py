@@ -374,3 +374,173 @@ def test_serving_latency_summary_keys_always_present():
         assert s[key] == {"p50": 0.0, "p95": 0.0, "p99": 0.0}
     assert s["finish_reasons"] == {}
     assert s["tokens_per_sec"] == 0.0
+
+
+# ---------------------------------------------------------------------------
+# spans on the profiler's clock, and the walk's dispatch counters
+# ---------------------------------------------------------------------------
+
+def _xplane_spans(trace_dir) -> list[tuple[str, int, int, dict]]:
+    """(name, start, end, args) of every program span on the host plane."""
+    from jax.profiler import ProfileData
+
+    (path,) = list(trace_dir.glob("**/*.xplane.pb"))
+    out = []
+    for plane in ProfileData.from_file(str(path)).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                out += [(e.name, int(e.start_ns),
+                         int(e.start_ns + e.duration_ns), dict(e.stats))
+                        for e in line.events
+                        if e.name.startswith(("serve.", "exec."))]
+    return sorted(out, key=lambda s: (s[1], -s[2]))
+
+
+def _inside(outer, inner) -> bool:
+    return outer[1] <= inner[1] and inner[2] <= outer[2]
+
+
+def test_engine_spans_land_on_the_profiler_host_plane(tmp_path):
+    """With tracing enabled while the JAX profiler records, one request's
+    prefill, insert, decode step and release appear on the xplane's host
+    plane, nested as the served path runs them, with their args."""
+    from repro.configs import get_reduced
+    from repro.models import build_model
+    from repro.serve import Engine, ServeConfig
+
+    cfg = get_reduced("qwen3_1_7b")
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    eng = Engine(model, params,
+                 ServeConfig(batch=2, max_len=32, stitch_execute=True),
+                 stitch_service=CompilationService())
+    prompt = np.arange(1, 6, dtype=np.int32)
+    for _ in range(2):                 # trace + land plans, then compile them
+        eng.insert(eng.prefill(prompt), 0)
+        eng.generate_step(1)
+        eng.release(0)
+        assert eng.land_plans() == 0
+    obs.clear_trace()
+    obs.enable_tracing()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        eng.insert(eng.prefill(prompt, rid=7), 1)
+        eng.generate_step(1)
+        eng.release(1)
+    finally:
+        jax.profiler.stop_trace()
+        obs.disable_tracing()
+        obs.clear_trace()
+    spans = _xplane_spans(tmp_path)
+    by = {}
+    for s in spans:
+        by.setdefault(s[0], []).append(s)
+    for name in ("serve.prefill", "serve.prefill.readback", "serve.insert",
+                 "serve.release"):
+        assert by[name][0][3]["rid"] == 7, name
+    assert by["serve.insert"][0][3]["slot"] == 1
+    (decode,) = by["serve.decode"]
+    assert (decode[3]["slots"], decode[3]["steps"]) == (1, 1)
+    children = [s[0] for s in spans if s is not decode and _inside(decode, s)
+                and not any(_inside(o, s) for o in spans
+                            if o is not decode and o is not s
+                            and _inside(decode, o))]
+    assert children == ["serve.decode.prep", "exec.decode_step",
+                        "serve.decode.sample", "serve.decode.readback",
+                        "serve.decode.commit"]
+    (step,) = by["exec.decode_step"]
+    assert step[3]["path"] == "stitched"
+    check, walk = [s for s in spans if s[0] in ("exec.check", "exec.walk")
+                   and _inside(step, s)]
+    assert (check[0], walk[0]) == ("exec.check", "exec.walk")
+    compiled = eng._exec.compiled
+    assert walk[3]["launches"] == compiled.launches > 0
+    assert walk[3]["uploads"] == compiled.uploads
+    (prefill,) = by["exec.prefill"]
+    assert _inside(by["serve.prefill"][0], prefill)
+    assert any(s[0] == "exec.walk" and _inside(prefill, s) for s in spans)
+
+
+def test_disabled_spans_are_the_null_span_and_cheap():
+    obs.disable_tracing()
+    assert obs.span("serve.decode", cat="serve", slots=3, steps=1) \
+        is obs.NULL_SPAN
+    t0 = time.perf_counter()
+    for _ in range(10_000):
+        with obs.span("exec.walk", cat="exec") as s:
+            s.set(launches=1, uploads=0)
+    assert time.perf_counter() - t0 < 0.1
+    assert len(obs.tracer) == 0
+
+
+def test_exec_span_covers_check_and_walk(svc, traced):
+    """``exec.<name>`` spans the whole call: the routing (drift check and
+    upgrade poll) in ``exec.check``, the artifact in ``exec.walk``; a
+    drifted call is served by jit with no walk."""
+    sf = stitch(_small_fn, service=svc, name="spanned_fn")
+    x = jnp.ones((8, 16), jnp.float32)
+    sf(x)
+    obs.clear_trace()
+    sf(x)
+    names = [e["name"] for e in obs.tracer.events()]
+    assert names == ["exec.check", "exec.walk", "exec.spanned_fn"]
+    check, walk, call = obs.tracer.events()
+    assert call["args"] == {"path": "stitched"}
+    assert call["ts"] <= check["ts"] and \
+        walk["ts"] + walk["dur"] <= call["ts"] + call["dur"]
+    plan = sf.report()["plan"]
+    assert walk["args"] == {"launches": plan["launches"],
+                            "uploads": plan["uploads"]}
+    obs.clear_trace()
+    sf(jnp.ones((4, 16), jnp.float32))                 # signature drift
+    assert [e["name"] for e in obs.tracer.events()] == [
+        "exec.check", "exec.spanned_fn"]
+    assert obs.tracer.events()[-1]["args"] == {"path": "fallback"}
+
+
+def test_compiled_graph_counts_launches_and_uploads(monkeypatch):
+    """One call's ``launches`` is its Pallas callable calls plus its
+    ``eval_node`` calls; ``uploads`` its constants plus host inputs."""
+    from repro.core import GraphBuilder, StitchCompiler
+    from repro.core import compiler as compiler_mod
+
+    b = GraphBuilder("walk")
+    x = b.param("x", (64, 256))
+    w = b.param("w", (64, 256))
+    c = b.const("c", (256,))
+    xc = b.ew("add", x, b.bcast(c, (64, 256), (1,)))
+    m = b.reduce("max", xc, axes=(1,))
+    e = b.ew("exp", b.ew("sub", xc, b.bcast(m, (64, 256), (0,))))
+    s = b.reduce("sum", e, axes=(1,))
+    y = b.ew("div", e, b.bcast(s, (64, 256), (0,)))
+    z = b.custom("opaque", (64, 256), "float32", (y,), eval_fn=jnp.tanh)
+    g = b.build(outputs=[b.ew("mul", z, w)])
+    g[c].attrs["value"] = np.ones((256,), np.float32)
+    cg = StitchCompiler(mode="stitch").compile(g)
+    kinds = {grp.kind for grp in cg.groups}
+    assert "pallas" in kinds and kinds - {"pallas"}
+    calls = {"eval_node": 0, "pallas": 0}
+    real_eval = compiler_mod.eval_node
+
+    def counted_eval(*a, **k):
+        calls["eval_node"] += 1
+        return real_eval(*a, **k)
+
+    monkeypatch.setattr(compiler_mod, "eval_node", counted_eval)
+    for grp in cg.groups:
+        if grp.kind == "pallas":
+            real = grp.tuned.callable
+
+            def counted(*a, _real=real):
+                calls["pallas"] += 1
+                return _real(*a)
+
+            monkeypatch.setattr(grp.tuned, "callable", counted)
+    out = cg({"x": np.ones((64, 256), np.float32),         # a host value
+              "w": jnp.ones((64, 256), jnp.float32)})
+    assert calls["pallas"] >= 1 and calls["eval_node"] >= 1
+    assert cg.launches == calls["pallas"] + calls["eval_node"]
+    assert cg.uploads == 2                     # the constant and x
+    np.testing.assert_allclose(
+        np.asarray(out[g.outputs[0]]),
+        np.tanh(np.full((64, 256), 1 / 256, np.float32)), rtol=1e-5)
