@@ -100,6 +100,23 @@ def _decode_attn_flops(node: OpNode, g: Graph) -> float:
     return 4.0 * b * hq * smax * dh
 
 
+def _paged_decode_flops(node: OpNode, g: Graph) -> float:
+    # pallas_call operands: (layer, page_table (B*n,), lengths, q (B, Hq, Dh),
+    # k_new, v_new, pool_k (L, P, page, Hkv, Dh), pool_v) — see
+    # kernels/decode_attention.py; priced as attention over every slot's
+    # whole view (the live length is a runtime value)
+    if len(node.operands) < 7:
+        return 0.0
+    pt = g[node.operands[1]].shape
+    q = g[node.operands[3]].shape
+    pool = g[node.operands[6]].shape
+    if len(pt) != 1 or len(q) != 3 or len(pool) != 5:
+        return 0.0
+    b, hq, dh = q
+    smax = pt[0] // max(b, 1) * pool[2]
+    return 4.0 * b * hq * smax * dh
+
+
 def _vpu_flops(per_elem: float, operand: int = 0):
     """Memory-bound VPU kernels (norms, rope, GLU): a few ops per element of
     the named operand, no MXU work."""
@@ -115,6 +132,7 @@ def _vpu_flops(per_elem: float, operand: int = 0):
 register(RegisteredKernel("_flash_kernel", _flash_flops))
 register(RegisteredKernel("_router_kernel", _router_flops))
 register(RegisteredKernel("_decode_attn_kernel", _decode_attn_flops))
+register(RegisteredKernel("_paged_decode_kernel", _paged_decode_flops))
 register(RegisteredKernel("_rmsnorm_kernel", _vpu_flops(4.0)))
 register(RegisteredKernel("_rmsnorm_residual_kernel", _vpu_flops(5.0)))
 register(RegisteredKernel("_layernorm_kernel", _vpu_flops(6.0)))

@@ -17,6 +17,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import decode_attention as _decode
 from repro.kernels import ops
 from .config import ModelConfig
 
@@ -227,12 +228,9 @@ def apply_attention(
             # Paged serving: the layer's KV lives in a shared page pool
             # (n_pages, page_size, Hkv, dh); ``page_table`` (B, n) maps each
             # slot's logical pages to pool pages.  Scatter the new token
-            # into each slot's current page, then gather the slot-dense
-            # view back out — the gathered view is value-identical to the
-            # dense cache at every unmasked position, so the attention math
-            # below (and the tokens) match the dense path bitwise.  Free
-            # slots' tables point at the reserved sink page 0, so their
-            # ride-along writes never touch a live page.
+            # into each slot's current page.  Free slots' tables point at
+            # the reserved sink page 0, so their ride-along writes never
+            # touch a live page.
             if S != 1:
                 raise NotImplementedError("paged decode is single-token")
             pt = cache["page_table"]                  # (B, n_pages) int32
@@ -240,9 +238,25 @@ def apply_attention(
             phys = jnp.take_along_axis(pt, (length // psz)[:, None],
                                        axis=1)[:, 0]
             off = length % psz
-            pk = cache["k"].at[phys, off].set(k[:, 0].astype(cache["k"].dtype))
-            pv = cache["v"].at[phys, off].set(v[:, 0].astype(cache["v"].dtype))
+            kw = k[:, 0].astype(cache["k"].dtype)
+            vw = v[:, 0].astype(cache["v"].dtype)
+            pk = cache["k"].at[phys, off].set(kw)
+            pv = cache["v"].at[phys, off].set(vw)
             new_cache = {"k": pk, "v": pv, "length": length + S}
+            if "pool" in cache:
+                # the kernel path (see paged_attention_path): read each
+                # slot's live pages of the stacked pool as the step received
+                # it, this token's row folded in from kw/vw
+                pool_k, pool_v, layer = cache["pool"]
+                out = _decode.paged_decode_attention(
+                    q[:, 0], pool_k, pool_v, layer, pt, length, kw, vw,
+                    scale=scale, window=window)
+                out = out.reshape(B, S, Hq * dh) @ p["wo"].astype(dt)
+                return out, new_cache
+            # the gather path: gather the slot-dense view back out — it is
+            # value-identical to the dense cache at every unmasked position,
+            # so the attention math below (and the tokens) match the dense
+            # path bitwise
             n = pt.shape[1]
             flat = pt.reshape(-1)
             ck = jnp.take(pk, flat, axis=0).reshape(B, n * psz, Hkv, dh)
@@ -308,6 +322,18 @@ def apply_attention(
                             positions_q=positions)
     out = out.reshape(B, S, Hq * dh) @ p["wo"].astype(dt)
     return out, new_cache
+
+
+def paged_attention_path(cfg: ModelConfig, pool_shape) -> str:
+    """How the paged decode step attends over a stacked pool (L, P, page,
+    Hkv, dh): ``"kernel"`` reads each slot's live pages through the page
+    table (:func:`repro.kernels.decode_attention.paged_decode_attention`),
+    where Pallas compiles and the shape tiles; ``"gather"`` gathers every
+    slot's whole view and masks it, bitwise equal to the dense layout."""
+    if _decode.paged_kernel_platform() and \
+            _decode.paged_decode_fits(pool_shape, cfg.n_heads):
+        return "kernel"
+    return "gather"
 
 
 def init_kv_cache(cfg: ModelConfig, batch: int, max_len: int, n_layers: int,

@@ -31,6 +31,7 @@ from .layers import (
     init_mlp,
     init_moe,
     init_norm,
+    paged_attention_path,
 )
 
 
@@ -285,13 +286,15 @@ def init_paged_cache(cfg: ModelConfig, num_pages: int, page_size: int) -> Params
 
 
 def _decode_layer(h, xs, cfg: ModelConfig, positions, length,
-                  page_table=None, kv_limit=None):
-    lp, ck, cv = xs
+                  page_table=None, kv_limit=None, pool=None):
+    lp, ck, cv, *layer = xs
     a_in = apply_norm(lp["norm1"], h, cfg)
     layer_cache = {"k": ck, "v": cv, "length": length}
     if page_table is not None:
         layer_cache["page_table"] = page_table
         layer_cache["kv_limit"] = kv_limit
+    if pool is not None:
+        layer_cache["pool"] = (*pool, layer[0])
     attn_out, new_cache = apply_attention(lp["attn"], a_in, cfg, positions,
                                           cache=layer_cache)
     h = h + attn_out
@@ -312,9 +315,11 @@ def decode_step(params: Params, cache: Params, tokens, cfg: ModelConfig,
 
     A cache carrying ``page_table`` selects the paged path: ``k``/``v`` are
     the shared page pool and each slot reads/writes through its page-table
-    row.  ``kv_limit`` (python int) slices the gathered per-slot view back
-    to the engine's max_len so the attention reduction shape — and hence
-    the tokens — match the dense layout bitwise."""
+    row.  On the gather path (:func:`paged_attention_path`) ``kv_limit``
+    (python int) slices the gathered per-slot view back to the engine's
+    max_len so the attention reduction shape — and hence the tokens — match
+    the dense layout bitwise.  On the kernel path each layer reads the
+    whole stacked pool, a loop invariant, at its own layer index."""
     B, S = tokens.shape
     length = cache["length"]
     page_table = cache.get("page_table")
@@ -322,13 +327,19 @@ def decode_step(params: Params, cache: Params, tokens, cfg: ModelConfig,
     positions = base + jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
     h = embed_tokens(params, tokens, cfg, patch_embeds)
 
+    xs = (params["layers"], cache["k"], cache["v"])
+    pool = None
+    if page_table is not None and \
+            paged_attention_path(cfg, cache["k"].shape) == "kernel":
+        pool = (cache["k"], cache["v"])
+        xs += (jnp.arange(cfg.n_layers, dtype=jnp.int32),)
+
     def body(carry, xs):
         return _decode_layer(carry, xs, cfg, positions, length,
-                             page_table=page_table, kv_limit=kv_limit)
+                             page_table=page_table, kv_limit=kv_limit,
+                             pool=pool)
 
-    h, (nk, nv) = scan_or_unroll(body, h,
-                                 (params["layers"], cache["k"], cache["v"]),
-                                 cfg.n_layers, cfg.scan_layers)
+    h, (nk, nv) = scan_or_unroll(body, h, xs, cfg.n_layers, cfg.scan_layers)
     h = apply_norm(params["final_norm"], h, cfg)
     logits = (h[:, -1] @ _head_matrix(params, cfg)).astype(jnp.float32)
     new_cache = {"k": nk, "v": nv, "length": length + S}

@@ -22,9 +22,11 @@ KV lives in :class:`~repro.serve.kv.PagedKV` (fixed-size pages + free-list
 allocator; the default off-mesh) or :class:`~repro.serve.kv.DenseKV` (the
 legacy per-slot rectangle; required under a mesh, where the decode batch is
 ``shard_map``-sharded over DP replicas and a shared page pool is not
-slot-partitionable).  The paged decode gathers each slot's pages into a
-dense view and slices it back to ``max_len``, so paged and dense serving
-are token-for-token identical.
+slot-partitionable).  Where Pallas compiles, the paged decode reads each
+slot's live pages through the page table in one kernel per layer (equal to
+dense serving within float tolerance); elsewhere it gathers each slot's
+pages into a dense view and slices it back to ``max_len``, so paged and
+dense serving are token-for-token identical.
 
 Legacy surfaces remain as thin shims: ``submit``/``step``/``drain``
 delegate to the :class:`~repro.serve.scheduler.Scheduler` (which itself
@@ -48,6 +50,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from repro import obs
 from repro.exec import stitch
 from repro.models.api import Model
+from repro.models.layers import paged_attention_path
 
 from .kv import DenseKV, PagedKV, Prefix
 
@@ -122,6 +125,8 @@ class Engine:
         self._rid = [-1] * cfg.batch     # request id per slot, for spans
         self._kv: PagedKV | DenseKV | None = None
         self._prefix = None
+        # how the traced paged decode step attends: "kernel" | "gather"
+        self._paged_attention: str | None = None
         self._exec = self._build_exec()
         self._prefill_exec = self._build_prefill_exec()
 
@@ -147,6 +152,9 @@ class Engine:
         kvl = self.cfg.max_len if self.paged else None
 
         def decode_step(params, cache, tok, extra):
+            if "page_table" in cache:
+                self._paged_attention = paged_attention_path(
+                    model.cfg, cache["k"].shape)
             return model.decode_step(params, cache, tok, kv_limit=kvl, **extra)
 
         # eligibility covers only (cache, tok, extra): params are fixed for
@@ -323,8 +331,13 @@ class Engine:
                                "(insert a prefix first)")
         extra = dict(extra or {})
         occ = sorted(self._occupied)
+        pages = {}
+        if self.paged:
+            pages = {"kv_live_pages": self.kv.live_pages(occ, steps),
+                     "kv_view_pages": steps * self.kv.slots
+                     * self.kv.pages_per_slot}
         with obs.span("serve.decode", cat="serve", slots=len(occ),
-                      steps=steps):
+                      steps=steps, **pages):
             with obs.span("serve.decode.prep", cat="serve"):
                 if self.paged:
                     for s in occ:
@@ -409,8 +422,12 @@ class Engine:
     def stitch_report(self) -> dict:
         """The decode step's :data:`repro.obs.EXEC_REPORT_SCHEMA` dict —
         upgrade status, plan stats, call counts, cache hit rates, and every
-        background-compile failure (also in pure-jit mode)."""
-        return self._exec.report()
+        background-compile failure (also in pure-jit mode) — plus
+        ``paged_attention``: how the traced paged step attends
+        (``"kernel"`` | ``"gather"``; None before a paged step is traced)."""
+        rep = self._exec.report()
+        rep["paged_attention"] = self._paged_attention
+        return rep
 
     def land_plans(self, timeout: float | None = None) -> int:
         """Join background compiles for decode AND every live prefill

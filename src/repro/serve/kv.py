@@ -14,9 +14,13 @@ chunk.  Eviction frees pages back to the free list instead of abandoning a
 cache hit a page-table splice — shared full pages are refcounted, only the
 partial tail page (where decode writes land) is copied per slot.
 
-Numerics: the decode step gathers each slot's pages into a dense view and
-slices it back to ``max_len`` (see ``kv_limit`` in the model layer), so
-paged serving is token-for-token — bitwise — equal to the dense layout.
+Numerics: on the gather path the decode step gathers each slot's pages
+into a dense view and slices it back to ``max_len`` (see ``kv_limit`` in
+the model layer), so paged serving is token-for-token — bitwise — equal to
+the dense layout.  On the kernel path (where Pallas compiles, see
+:func:`repro.models.layers.paged_attention_path`) the step reads only each
+slot's live pages through the page table, and equals the dense layout
+within float tolerance, not bitwise.
 """
 
 from __future__ import annotations
@@ -169,6 +173,15 @@ class PagedKV:
     def absorb(self, new_cache: dict) -> None:
         """Take the pool back from a decode chunk's output cache."""
         self.pool = {"k": new_cache["k"], "v": new_cache["v"]}
+
+    def live_pages(self, slots, steps: int) -> int:
+        """Pages per layer that ``steps`` decode steps read below the
+        occupied ``slots``' lengths, summed over slots and steps: what the
+        paged kernel copies (``slots * pages_per_slot`` a step is what the
+        gather path reads)."""
+        ps = self.page_size
+        return sum(-(-(int(self.lengths[s]) + i) // ps)
+                   for s in slots for i in range(steps))
 
     def advance(self, slots, steps: int) -> None:
         """Host-side length bookkeeping after a decode chunk."""

@@ -17,6 +17,7 @@ imports this file.
 from __future__ import annotations
 
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -68,8 +69,9 @@ def compiled(monkeypatch, one_chip):
     monkeypatch.setattr(kernels, "interpret_mode", lambda: False)
 
     def compile_(fn, *avals):
-        args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
-                for a in avals]
+        args = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), avals)
         text = jax.jit(fn).lower(*args).compile().as_text()
         assert "tpu_custom_call" in text, "no Mosaic kernel in the program"
         return text
@@ -94,10 +96,10 @@ def _pallas_groups(g):
     return out
 
 
-def _decode_graph():
-    """The paged decode step of full-width qwen3-1.7b, traced at abstract
-    shapes.  Depth is cut to 2 layers: the layers run in one scan, so the
-    graph the planner sees does not grow with depth."""
+def _decode_step():
+    """The paged decode step of full-width qwen3-1.7b and its abstract
+    arguments.  Depth is cut to 2 layers: the layers run in one scan, so
+    the graph the planner sees does not grow with depth."""
     from repro.configs import get_config
     from repro.models import build_model
 
@@ -116,7 +118,12 @@ def _decode_graph():
     def decode_step(params, cache, tok):
         return model.decode_step(params, cache, tok, kv_limit=MAX_LEN)
 
-    g, _ = trace_to_graph(decode_step, params, cache, tok, name="decode")
+    return decode_step, (params, cache, tok)
+
+
+def _decode_graph():
+    fn, args = _decode_step()
+    g, _ = trace_to_graph(fn, *args, name="decode")
     return g
 
 
@@ -135,6 +142,16 @@ def test_stitched_groups_compile(graph, compiled):
         compiled(fn, *avals)
 
 
+def test_paged_decode_step_compiles(compiled):
+    """The whole decode step, the paged kernel inside the layer scan,
+    compiles for the chip; the kernel reads the stacked pool, so the step
+    keeps no gather of the slots' views."""
+    fn, args = _decode_step()
+    text = compiled(fn, *args)          # the kernel is its only Mosaic call
+    page = r"bf16\[\d+,%d,8,128\]\{[^}]*\}" % PAGE
+    assert not re.search(page + r" gather\(", text), "pages still gathered"
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_rmsnorm_compiles(dtype, compiled):
     from repro.kernels.norms import rmsnorm
@@ -149,6 +166,48 @@ def test_flash_attention_compiles(compiled):
     q = jax.ShapeDtypeStruct((1, 128, 16, 128), jnp.bfloat16)
     kv = jax.ShapeDtypeStruct((1, 128, 8, 128), jnp.bfloat16)
     compiled(lambda q, k, v: flash_attention(q, k, v, causal=True), q, kv, kv)
+
+
+def _paged_avals(slots, max_len, page, hkv, dh, layers, group):
+    n = max_len // page
+    pool = jax.ShapeDtypeStruct((layers, slots * n + 1, page, hkv, dh),
+                                jnp.bfloat16)
+    q = jax.ShapeDtypeStruct((slots, hkv * group, dh), jnp.bfloat16)
+    new = jax.ShapeDtypeStruct((slots, hkv, dh), jnp.bfloat16)
+    return (q, pool, pool, jax.ShapeDtypeStruct((), jnp.int32),
+            jax.ShapeDtypeStruct((slots, n), jnp.int32),
+            jax.ShapeDtypeStruct((slots,), jnp.int32), new, new)
+
+
+@pytest.mark.parametrize("shape", [
+    # the chat cells: 12 slots of 2048 positions, page 16, qwen3-1.7b heads
+    dict(slots=12, max_len=2048, page=16, hkv=8, dh=128, layers=28, group=2),
+    # 32 kv heads (phi3-mini's count) at a head size that tiles, page 8
+    dict(slots=8, max_len=2048, page=8, hkv=32, dh=128, layers=32, group=1),
+])
+def test_paged_decode_attention_compiles(shape, compiled):
+    from repro.kernels.decode_attention import (paged_decode_attention,
+                                                paged_decode_fits)
+
+    avals = _paged_avals(**shape)
+    assert paged_decode_fits(avals[1].shape, avals[0].shape[1])
+    compiled(paged_decode_attention, *avals)
+
+
+def test_paged_decode_keeps_gather_at_phi3_widths(compiled):
+    """phi3-mini's head size of 96 is not a whole lane tile, and Mosaic
+    refuses the kernel's page copy there; the paged step keeps the gather
+    path for it, on the chip as elsewhere."""
+    from repro.configs import get_config
+    from repro.kernels.decode_attention import paged_decode_attention
+    from repro.models.layers import paged_attention_path
+
+    cfg = get_config("phi3_mini_3_8b")
+    avals = _paged_avals(slots=8, max_len=2048, page=16, hkv=cfg.n_kv_heads,
+                         dh=cfg.dh, layers=cfg.n_layers, group=1)
+    assert paged_attention_path(cfg, avals[1].shape) == "gather"
+    with pytest.raises(Exception, match="aligned to tiling"):
+        compiled(paged_decode_attention, *avals)
 
 
 def test_decode_attention_compiles(compiled):

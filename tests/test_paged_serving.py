@@ -416,6 +416,77 @@ def test_pallas_decode_attention_collapses_plan(setup):
         assert art.stats.pallas_groups >= 10
 
 
+# -- paged decode attention kernel ---------------------------------------------
+
+def test_paged_kernel_path_matches_dense(setup, monkeypatch):
+    """Where Pallas compiles, the paged step reads each slot's live pages
+    through the page table in one kernel per layer (interpreted here, the
+    platform predicate forced): its decode logits match the dense engine's
+    within float tolerance, its greedy tokens equal them on this seeded
+    setup, and the ``serve.decode`` span counts the pages it reads."""
+    import dataclasses
+
+    from repro import obs
+    from repro.kernels import decode_attention
+
+    cfg, _, _ = setup
+    # kv heads and head size in whole (8, 128) tiles, as the kernel needs
+    cfg = dataclasses.replace(cfg, n_heads=16, n_kv_heads=8, head_dim=128)
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    monkeypatch.setattr(decode_attention, "paged_kernel_platform",
+                        lambda: True)
+    prompts, news = _workload(cfg)
+    prompts, news = prompts[:4], news[:4]
+
+    def engine(paged):
+        return Engine(model, params,
+                      ServeConfig(batch=2, max_len=32, paged=paged,
+                                  page_size=8))
+
+    paged, dense = engine(True), engine(False)
+    logits = []
+    for eng in (paged, dense):
+        for slot in (0, 1):
+            eng.insert(eng.prefill(prompts[slot]), slot)
+        out, _ = eng._decode_dispatch(eng.kv.decode_cache(),
+                                      eng._tok.copy(), {})
+        logits.append(np.asarray(out))
+    assert paged.stitch_report()["paged_attention"] == "kernel"
+    assert dense.stitch_report()["paged_attention"] is None
+    np.testing.assert_allclose(logits[0], logits[1], rtol=2e-2, atol=2e-2)
+
+    lengths = [len(prompts[0]), len(prompts[1])]      # 5 and 12
+    obs.clear_trace()
+    obs.enable_tracing()
+    try:
+        paged.generate_step(1)
+    finally:
+        obs.disable_tracing()
+    (decode,) = [e for e in obs.tracer.events()
+                 if e["name"] == "serve.decode"]
+    obs.clear_trace()
+    assert decode["args"]["kv_live_pages"] == sum(-(-n // 8) for n in lengths)
+    assert decode["args"]["kv_view_pages"] == 2 * 4   # slots x pages_per_slot
+    for eng in (paged, dense):
+        eng.release(0)
+        eng.release(1)
+    assert _drain_tokens(paged, prompts, news) == \
+        _drain_tokens(dense, prompts, news)
+
+
+def test_paged_engine_reports_gather_path(setup):
+    """Under the interpreter the paged step keeps the gather path, and the
+    engine's report says so once the step is traced."""
+    cfg, model, params = setup
+    eng = Engine(model, params, ServeConfig(batch=1, max_len=16,
+                                            page_size=4))
+    assert eng.stitch_report()["paged_attention"] is None
+    eng.insert(eng.prefill(np.arange(1, 6, dtype=np.int32)), 0)
+    eng.generate_step(1)
+    assert eng.stitch_report()["paged_attention"] == "gather"
+
+
 # -- deprecation ---------------------------------------------------------------
 
 def test_legacy_rect_generate_warns_once(setup):
