@@ -2,12 +2,13 @@
 
 Once the window has closed and the program's state is freed, a sample of
 the finished requests, drawn from the seed and always holding the one with
-the most served tokens, is run through the plain reference
-(``reference.decoder``): one forward pass over each prompt with its served
-tokens.  At each served position the reference's float32 logits give the
-gap by which the served token's logit lies below the reference's best
-logit (0 where the served token is the reference's own greedy choice).  The
-number compared is the widest such gap over the sample, in logits.
+the most served tokens, is run through the plain reference of the
+configuration's architecture (``arch.load(cfg).logits_at``): one forward
+pass over each prompt with its served tokens.  At each served position the
+reference's float32 logits give the gap by which the served token's logit
+lies below the reference's best logit (0 where the served token is the
+reference's own greedy choice).  The number compared is the widest such gap
+over the sample, in logits.
 
 Every served token is greedy, so a served token that the reference ranks
 far below its best means the timed path computed something else: a wrong
@@ -19,6 +20,8 @@ gap for the token that it puts first at each of those positions.
 from __future__ import annotations
 
 import numpy as np
+
+import arch
 
 
 def sample(finished: list, seed: int, max_requests: int,
@@ -53,8 +56,7 @@ def widest_gap(weights, cfg: dict, reqs: list, quant: str | None = None
     """(widest gap, positions compared).  With ``quant`` the gap is read for
     the token the lower-precision reference puts first, not the served
     one."""
-    from reference.decoder import logits_at
-
+    logits_at = arch.load(cfg).logits_at
     worst, n = 0.0, 0
     for req in reqs:
         seq, rows, served = _rows(req)

@@ -16,8 +16,19 @@ ones.
 Which metrics a cell reports comes from ``BENCHMARK.json`` at the checkout
 root; each metric is read by ``metrics/<name>.py``.  A cell that is not
 listed there (such as the ``.jit`` variants) names its end-to-end metrics in
-its workload file.  Adding a cell, a configuration, a traffic mix or a
-metric is adding files: nothing here names one.
+its workload file.
+
+Adding a cell, a configuration, a traffic mix or a metric is adding files,
+and editing none: nothing here names one.  A new configuration brings
+``configs/<name>.json`` (the published ``config.json`` with its
+``model_type``, cut as its ``reduced`` says), ``workloads/<cell>.json`` and,
+if new, ``traffic/<mix>.json``; where no ``arch/<model_type>.py`` serves its
+``model_type`` yet, it brings that module and the module's plain reference
+under ``reference/``.  The module is everything the harness knows of an
+architecture: weights layout, the program's model, what traffic it can
+serve, the reference and its control, and the FLOP count (``arch``).  A
+configuration whose ``model_type`` has no module fails before any device
+work, naming the file expected.
 
 Runs only on a TPU: on any other platform, or with fewer chips than the
 cell asks for, it exits with code 3 and prints no result.
@@ -41,14 +52,19 @@ ROOT = HERE.parents[1]
 sys.path.insert(0, str(HERE))
 sys.path.insert(1, str(ROOT / "src"))
 
+import arch  # noqa: E402
+
 EXIT_NO_CHIP = 3
 
 
 def load_cell(name: str) -> tuple[dict, dict, dict]:
-    """(workload, config, traffic mix) for the cell ``name``."""
+    """(workload, config, traffic mix) for the cell ``name``; raises
+    ``arch.MissingArch`` where no module serves the configuration's
+    architecture."""
     workload = json.loads((HERE / "workloads" / f"{name}.json").read_text())
     cfg = json.loads((HERE / "configs" / f"{workload['config']}.json").read_text())
     mix = json.loads((HERE / "traffic" / f"{workload['traffic']}.json").read_text())
+    arch.load(cfg)
     workload["engine"] = dict(workload["engine"])
     workload["engine"]["stitch_cache_dir"] = str(HERE / ".cache" / "stitch")
     return workload, cfg, mix

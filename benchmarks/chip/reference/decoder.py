@@ -33,6 +33,17 @@ import jax.numpy as jnp
 PAD = 256
 
 
+def dims(cfg: dict) -> dict:
+    """The sizes the benchmark reads from a configuration file."""
+    d = cfg["hidden_size"]
+    hq = cfg["num_attention_heads"]
+    return {"D": d, "F": cfg["intermediate_size"], "L": cfg["num_hidden_layers"],
+            "Hq": hq, "Hkv": cfg["num_key_value_heads"],
+            "dh": cfg.get("head_dim") or d // hq, "V": cfg["vocab_size"],
+            "tied": bool(cfg["tie_word_embeddings"]),
+            "qk_norm": bool(cfg.get("qk_norm", False))}
+
+
 def _fp8(w, axis):
     """Round ``w`` to float8 e4m3 with one scale per slice along ``axis``
     (the contraction axis is reduced over)."""
@@ -57,8 +68,6 @@ def _rope(x, pos, theta):
 
 def _hidden(w, tokens, cfg: dict, quant: str | None):
     """tokens (T,) int32 -> final-normed hidden states (T, D) float32."""
-    from weights import dims
-
     s = dims(cfg)
     Hq, Hkv, dh = s["Hq"], s["Hkv"], s["dh"]
     eps = float(cfg["rms_norm_eps"])

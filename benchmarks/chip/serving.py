@@ -23,14 +23,11 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
+import arch
 import loadgen
 
 # seconds at the end of the window that a traced run records
 TRACE_S = 5.0
-
-
-class ProgramFault(RuntimeError):
-    """The program could not run the cell as configured."""
 
 
 @dataclass
@@ -54,44 +51,13 @@ class Window:
     peaks: dict | None = None
 
 
-def model_config(cfg: dict, name: str):
-    """The program's ``ModelConfig`` for a configuration file."""
-    from repro.models.config import ModelConfig
-    from weights import dims
-
-    if cfg["hidden_act"] != "silu":
-        raise ProgramFault(f"hidden_act {cfg['hidden_act']!r}: only SwiGLU")
-    # the program's RMSNorm has no eps argument and fixes it at 1e-6; a
-    # configuration that states another eps (phi3: 1e-5) is compared, with
-    # its own eps, by the reference
-    s = dims(cfg)
-    return ModelConfig(
-        name=name, family="dense", n_layers=s["L"], d_model=s["D"],
-        n_heads=s["Hq"], n_kv_heads=s["Hkv"], d_ff=s["F"], vocab=s["V"],
-        head_dim=s["dh"], act="swiglu", norm="rms", qk_norm=s["qk_norm"],
-        rope_theta=float(cfg["rope_theta"]), tie_embeddings=s["tied"],
-        dtype="bfloat16", param_dtype=cfg["serve_dtype"])
-
-
-def _check_window(cfg: dict, mix: dict, max_len: int) -> None:
-    """A model whose attention has a sliding window runs here only where
-    no position reaches past it, since the program attends to all."""
-    longest = mix["prompt"]["max"] + mix["output"]["max"] - 1
-    if longest > max_len:
-        raise ValueError(f"prompt + output up to {longest} > max_len {max_len}")
-    win = cfg.get("sliding_window")
-    if win and longest > win:
-        raise ValueError(f"contexts up to {longest} positions pass the "
-                         f"sliding window {win}, which the program lacks")
-
-
 def build(workload: dict, cfg: dict, weights):
     """The engine, as ``repro.launch.serve --stitch`` builds it."""
     from repro.launch.serve import build_engine, make_parser
     from repro.models import build_model
 
     e = workload["engine"]
-    mcfg = model_config(cfg, workload["config"])
+    mcfg = arch.load(cfg).program(cfg, workload["config"])
     argv = ["--arch", workload["config"], "--slots", str(e["slots"]),
             "--max-len", str(e["max_len"]), "--page-size", str(e["page_size"])]
     if e["stitch"]:
@@ -128,7 +94,7 @@ def land(eng, reqs: list) -> float:
             eng.release(0)
             lacking = eng.land_plans()
             if lacking:
-                raise ProgramFault(f"{lacking} stitched plans never landed")
+                raise arch.ProgramFault(f"{lacking} stitched plans never landed")
     for b, ns in sorted(pages.items()):
         kv = pxs[b].kv
         for n in sorted(ns):
@@ -149,7 +115,7 @@ def land(eng, reqs: list) -> float:
             bad = {k: v["status"] for k, v in r["plans"].items()
                    if v["status"] != "hit"}
             if bad or r["errors"] or r["service_error"]:
-                raise ProgramFault(f"{which}: plans {bad}, errors "
+                raise arch.ProgramFault(f"{which}: plans {bad}, errors "
                                    f"{r['errors']}, {r['service_error']}")
     return time.perf_counter() - t0
 
@@ -316,7 +282,7 @@ def setup(workload: dict, cfg: dict, mix: dict, seed: int, seconds: float):
 
     import weights as wmod
 
-    _check_window(cfg, mix, workload["engine"]["max_len"])
+    arch.load(cfg).check_traffic(cfg, mix, workload["engine"]["max_len"])
     w = wmod.make(cfg, seed)
     jax.block_until_ready(w)
     eng = build(workload, cfg, w)

@@ -18,7 +18,8 @@ sys.path.insert(1, str(HERE.parents[1] / "src"))
 
 import harness  # noqa: E402
 
-TINY = {"hidden_size": 64, "intermediate_size": 128, "num_hidden_layers": 2,
+TINY = {"model_type": "qwen3",
+        "hidden_size": 64, "intermediate_size": 128, "num_hidden_layers": 2,
         "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
         "vocab_size": 256, "rms_norm_eps": 1e-6, "rope_theta": 1e6,
         "tie_word_embeddings": True, "hidden_act": "silu", "qk_norm": True,

@@ -26,16 +26,17 @@ sys.path.insert(1, str(HERE.parents[1] / "src"))
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
-import serving  # noqa: E402
+import arch  # noqa: E402
 import weights  # noqa: E402
-from reference import decoder  # noqa: E402
 
-QWEN3_LIKE = {"hidden_size": 64, "intermediate_size": 160, "num_hidden_layers": 2,
+QWEN3_LIKE = {"model_type": "qwen3",
+              "hidden_size": 64, "intermediate_size": 160, "num_hidden_layers": 2,
               "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 32,
               "vocab_size": 300, "rms_norm_eps": 1e-6, "rope_theta": 1e6,
               "tie_word_embeddings": True, "hidden_act": "silu", "qk_norm": True,
               "serve_dtype": "float32"}
-PHI3_LIKE = {"hidden_size": 192, "intermediate_size": 256, "num_hidden_layers": 2,
+PHI3_LIKE = {"model_type": "phi3",
+             "hidden_size": 192, "intermediate_size": 256, "num_hidden_layers": 2,
              "num_attention_heads": 2, "num_key_value_heads": 2,
              "vocab_size": 320, "rms_norm_eps": 1e-6, "rope_theta": 1e4,
              "sliding_window": 2047, "tie_word_embeddings": False,
@@ -62,7 +63,7 @@ class _LogitTap:
 def _program(cfg):
     from repro.models import build_model
 
-    mcfg = dataclasses.replace(serving.model_config(cfg, "t"), dtype="float32")
+    mcfg = dataclasses.replace(arch.load(cfg).program(cfg, "t"), dtype="float32")
     return build_model(mcfg)
 
 
@@ -71,6 +72,7 @@ def test_prefill_and_paged_decode_match_reference(name):
     from repro.serve import Engine, ServeConfig
 
     cfg = CONFIGS[name]
+    ref_arch = arch.load(cfg)
     w = weights.make(cfg, 3)
     model = _program(cfg)
     eng = Engine(model, w, ServeConfig(batch=2, max_len=64, page_size=16,
@@ -82,7 +84,7 @@ def test_prefill_and_paged_decode_match_reference(name):
     first = []
     for slot, p in enumerate(prompts):
         px = eng.prefill(p)
-        ref = decoder.logits_at(w, p, [len(p) - 1], cfg)
+        ref = ref_arch.logits_at(w, p, [len(p) - 1], cfg)
         np.testing.assert_allclose(eng._prefill_exec.logits[-1], ref,
                                    rtol=RTOL, atol=ATOL)
         eng.insert(px, slot)
@@ -90,7 +92,7 @@ def test_prefill_and_paged_decode_match_reference(name):
     toks = eng.generate_step(3)
     for slot, p in enumerate(prompts):
         seq = np.concatenate([p, [first[slot]], toks[slot, :2]]).astype(np.int32)
-        ref = decoder.logits_at(w, seq, len(p) + np.arange(3), cfg)
+        ref = ref_arch.logits_at(w, seq, len(p) + np.arange(3), cfg)
         got = np.stack([t[slot] for t in eng._exec.logits])
         np.testing.assert_allclose(got, ref, rtol=RTOL, atol=ATOL)
 
@@ -98,6 +100,7 @@ def test_prefill_and_paged_decode_match_reference(name):
 @pytest.mark.parametrize("name", sorted(CONFIGS))
 def test_loss_and_gradients_match_reference(name):
     cfg = CONFIGS[name]
+    ref_arch = arch.load(cfg)
     w = weights.make(cfg, 5)
     model = _program(cfg)
     rng = np.random.default_rng(1)
@@ -108,7 +111,7 @@ def test_loss_and_gradients_match_reference(name):
         got, g_got = jax.value_and_grad(
             lambda p: model.train_forward(p, batch)[0])(w)
     want, g_want = jax.value_and_grad(
-        lambda p: decoder.loss(p, tokens, labels, cfg))(w)
+        lambda p: ref_arch.loss(p, tokens, labels, cfg))(w)
     np.testing.assert_allclose(got, want, rtol=RTOL)
     for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(g_got),
                             jax.tree_util.tree_leaves(g_want)):
@@ -121,9 +124,10 @@ def test_fp8_control_departs_from_reference():
     """The control reads a different greedy token somewhere and moves the
     logits by far more than float32 rounding."""
     cfg = QWEN3_LIKE
+    ref_arch = arch.load(cfg)
     w = weights.make(cfg, 9)
     seq = np.random.default_rng(2).integers(0, cfg["vocab_size"], 40)
     rows = np.arange(40)
-    ref = np.asarray(decoder.logits_at(w, seq, rows, cfg))
-    low = np.asarray(decoder.logits_at(w, seq, rows, cfg, "fp8"))
+    ref = np.asarray(ref_arch.logits_at(w, seq, rows, cfg))
+    low = np.asarray(ref_arch.logits_at(w, seq, rows, cfg, "fp8"))
     assert np.abs(low - ref).max() > 100 * ATOL
