@@ -144,6 +144,11 @@ def _analyze_with_rows(p: FusionPattern, rows: int,
     roles: dict[str, str] = {}
     acc_init: dict[str, tuple[str, float]] = {}
     for name in p.external_inputs:
+        if (not g[name].shape
+                and jnp.dtype(canonical_dtype(g[name].dtype)).itemsize != 4):
+            # a scalar arrives as a (1,) block, and the chip extracts only
+            # 32-bit scalars from a vector
+            raise StitchInfeasible(f"scalar input {name} of {g[name].dtype}")
         roles[name] = INV if name in force_inv else _role_of_input(g[name], rows)
 
     topo_members = [n.name for n in p.nodes if not n.is_source()]
@@ -269,6 +274,10 @@ def _analyze_with_rows(p: FusionPattern, rows: int,
             raise StitchInfeasible(f"unsupported kind {k} in stitched kernel")
 
     blocks = [b for b in (8, 16, 32, 64, 128, 256, 512, rows) if b <= rows and rows % b == 0]
+    # the chip tiles a block's last dimension by 128, so a rank-1 block of
+    # rows is all of them or a multiple of 128
+    if any(len(g[n].shape) == 1 for n, r in roles.items() if r == ROW):
+        blocks = [b for b in blocks if b == rows or b % 128 == 0]
     if single_block:
         blocks = [rows]
     if not blocks:

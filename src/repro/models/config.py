@@ -20,6 +20,48 @@ class MoEConfig:
     # groups (GShard G); groups align with DP shards so dispatch stays local.
     # 1 = global dispatch (baseline); 0 = auto (min(16, divisors of T)).
     n_groups: int = 1
+    # dropless expert share (expert parallelism): the layer holds experts
+    # [held[0], held[0] + held[1]) of ``n_experts``, routes every token over
+    # all of them and computes its own experts' part for each (token,
+    # expert) pair routed here, with no capacity; None keeps the capacity
+    # dispatch of ``apply_moe``
+    held: tuple[int, int] | None = None
+    routed_scale: float = 1.0     # factor on the routed gates (DeepSeek)
+
+    @property
+    def n_held(self) -> int:
+        return self.held[1] if self.held is not None else self.n_experts
+
+
+@dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rotary frequencies (Peng et al., arXiv:2309.00071), as
+    DeepSeek-V2 and transformers' ``_compute_yarn_parameters`` set them.
+    ``mscale_all_dim`` scales the softmax; the cos/sin factor
+    mscale(mscale) / mscale(mscale_all_dim) is taken to be 1, as every
+    published DeepSeek-V2 sets it."""
+    factor: float
+    original_max: int             # original_max_position_embeddings
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434), without
+    a q low-rank projection: per token the cache holds the normed latent
+    (``kv_rank``) and the rotated key part shared by every head
+    (``qk_rope_dim``)."""
+    kv_rank: int
+    qk_nope_dim: int
+    qk_rope_dim: int
+    v_dim: int
+    yarn: YarnConfig | None = None
+
+    @property
+    def latent_dim(self) -> int:
+        return self.kv_rank + self.qk_rope_dim
 
 
 @dataclass(frozen=True)
@@ -57,6 +99,10 @@ class ModelConfig:
     rope_theta: float = 10000.0
     tie_embeddings: bool = False
     moe: MoEConfig | None = None
+    mla: MLAConfig | None = None  # latent attention in every layer
+    # leading dense-MLP layers (width d_ff) before the MoE stack; DeepSeek's
+    # first_k_dense_replace.  The paged pool counts layers across both.
+    n_dense_layers: int = 0
     ssm: SSMConfig | None = None
     hybrid: HybridConfig | None = None
     # encoder-decoder (seamless): encoder depth; decoder uses n_layers
@@ -100,6 +146,12 @@ class ModelConfig:
         if not self.tie_embeddings:
             total += D * V                         # lm head
         def attn_params() -> int:
+            if self.mla is not None:
+                a = self.mla
+                return (D * Hq * (a.qk_nope_dim + a.qk_rope_dim)
+                        + D * a.latent_dim + a.kv_rank
+                        + a.kv_rank * Hq * (a.qk_nope_dim + a.v_dim)
+                        + Hq * a.v_dim * D)
             p = D * Hq * dh + 2 * D * Hkv * dh + Hq * dh * D
             if self.qkv_bias:
                 p += (Hq + 2 * Hkv) * dh
@@ -129,15 +181,17 @@ class ModelConfig:
             total += D
             return total
         per_layer = attn_params() + 2 * D           # norms
+        nd = self.n_dense_layers
+        total += nd * (per_layer + dense_mlp(F))
         if self.family == "moe" and self.moe:
             m = self.moe
             per_layer += D * m.n_experts            # router
-            per_layer += m.n_experts * (3 * D * m.d_expert)
+            per_layer += m.n_held * (3 * D * m.d_expert)
             if m.n_shared:
                 per_layer += 3 * D * m.d_shared
         else:
             per_layer += dense_mlp(F)
-        total += L * per_layer
+        total += (L - nd) * per_layer
         if self.n_encoder_layers:
             enc_per = attn_params() + dense_mlp(F) + 2 * D
             total += self.n_encoder_layers * (enc_per + attn_params() + D)  # +cross-attn
@@ -150,8 +204,11 @@ class ModelConfig:
             return self.param_count()
         m = self.moe
         full = self.param_count()
-        all_exp = self.n_layers * m.n_experts * 3 * self.d_model * m.d_expert
-        act_exp = self.n_layers * m.top_k * 3 * self.d_model * m.d_expert
+        L = self.n_layers - self.n_dense_layers
+        all_exp = L * m.n_held * 3 * self.d_model * m.d_expert
+        # a share sees its experts' part of the top_k: top_k * held / E
+        act_exp = (L * m.top_k * m.n_held * 3 * self.d_model * m.d_expert
+                   // m.n_experts)
         return full - all_exp + act_exp
 
 

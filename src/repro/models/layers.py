@@ -16,6 +16,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.kernels import decode_attention as _decode
 from repro.kernels import ops
@@ -96,16 +97,36 @@ def init_mlp(key, cfg: ModelConfig, d_ff: int | None = None) -> Params:
     }
 
 
+def init_mla(key, cfg: ModelConfig) -> Params:
+    """Latent attention: ``wq`` (D, H*(nope+rope)), ``wkv_a`` (D, rank +
+    rope) whose latent part is RMS-normed by ``kv_norm_g``, ``wkv_b``
+    (rank, H*(nope+v)) and ``wo`` (H*v, D)."""
+    a, D, H = cfg.mla, cfg.d_model, cfg.n_heads
+    ks = jax.random.split(key, 4)
+    dq = a.qk_nope_dim + a.qk_rope_dim
+    return {
+        "wq": _normal(ks[0], (D, H * dq), 1.0 / math.sqrt(D), cfg.param_dtype),
+        "wkv_a": _normal(ks[1], (D, a.latent_dim), 1.0 / math.sqrt(D),
+                         cfg.param_dtype),
+        "kv_norm_g": jnp.ones((a.kv_rank,), cfg.param_dtype),
+        "wkv_b": _normal(ks[2], (a.kv_rank, H * (a.qk_nope_dim + a.v_dim)),
+                         1.0 / math.sqrt(a.kv_rank), cfg.param_dtype),
+        "wo": _normal(ks[3], (H * a.v_dim, D), 1.0 / math.sqrt(H * a.v_dim),
+                      cfg.param_dtype),
+    }
+
+
 def init_moe(key, cfg: ModelConfig) -> Params:
     m = cfg.moe
     D = cfg.d_model
+    E = m.n_held                  # the experts this layer holds
     ks = jax.random.split(key, 5)
     sc_in, sc_out = 1.0 / math.sqrt(D), 1.0 / math.sqrt(m.d_expert)
     p = {
         "router": _normal(ks[0], (D, m.n_experts), sc_in, cfg.param_dtype),
-        "w_gate": _normal(ks[1], (m.n_experts, D, m.d_expert), sc_in, cfg.param_dtype),
-        "w_up": _normal(ks[2], (m.n_experts, D, m.d_expert), sc_in, cfg.param_dtype),
-        "w_down": _normal(ks[3], (m.n_experts, m.d_expert, D), sc_out, cfg.param_dtype),
+        "w_gate": _normal(ks[1], (E, D, m.d_expert), sc_in, cfg.param_dtype),
+        "w_up": _normal(ks[2], (E, D, m.d_expert), sc_in, cfg.param_dtype),
+        "w_down": _normal(ks[3], (E, m.d_expert, D), sc_out, cfg.param_dtype),
     }
     if m.n_shared:
         p["shared"] = init_mlp(ks[4], cfg, m.d_shared)
@@ -179,8 +200,9 @@ def _chunked_causal_attention(q, k, v, scale, window, use_scan: bool = True,
         return carry, out.astype(qb.dtype)
 
     _, outs = scan_or_unroll(chunk, None, (jnp.arange(nq), qg), nq, use_scan)
-    # (nq, B, qc, Hkv, group, Dh) -> (B, Lq, Hq, Dh)
-    return outs.transpose(1, 0, 2, 3, 4, 5).reshape(B, Lq, Hq, Dh)
+    # (nq, B, qc, Hkv, group, Dv) -> (B, Lq, Hq, Dv); Dv is v's own head
+    # size, which latent attention sets apart from q's and k's
+    return outs.transpose(1, 0, 2, 3, 4, 5).reshape(B, Lq, Hq, v.shape[-1])
 
 
 def apply_attention(
@@ -348,6 +370,189 @@ def init_kv_cache(cfg: ModelConfig, batch: int, max_len: int, n_layers: int,
 
 
 # ---------------------------------------------------------------------------
+# multi-head latent attention (DeepSeek-V2)
+# ---------------------------------------------------------------------------
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def rope_inv_freq(dim: int, theta: float, yarn=None) -> np.ndarray:
+    """Inverse frequencies of a ``dim``-wide rotary part: plain RoPE, or
+    YaRN's blend of interpolated and extrapolated frequencies over a linear
+    ramp between the correction dims of ``beta_fast`` and ``beta_slow``
+    (floored / ceiled), as DeepSeek-V2's modelling code computes them."""
+    pos = theta ** (np.arange(0, dim, 2, dtype=np.float32) / dim)
+    extra = 1.0 / pos
+    if yarn is None:
+        return extra.astype(np.float32)
+    inter = 1.0 / (yarn.factor * pos)
+
+    def corr(rot):
+        return dim * math.log(yarn.original_max / (rot * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    lo = max(math.floor(corr(yarn.beta_fast)), 0)
+    hi = min(math.ceil(corr(yarn.beta_slow)), dim - 1)
+    ramp = (np.arange(dim // 2, dtype=np.float32) - lo) / max(hi - lo, 1e-3)
+    keep = 1.0 - np.clip(ramp, 0.0, 1.0)     # 1: extrapolate (high freq)
+    return (inter * (1.0 - keep) + extra * keep).astype(np.float32)
+
+
+def rope_pairs(x, positions, inv_freq):
+    """Rotary embedding over adjacent pairs ``(x0, x1), (x2, x3), ...`` (the
+    complex-number convention of DeepSeek's code), in float32.
+    x: (B, S, H, d) or (B, S, d); positions (B, S)."""
+    ang = positions.astype(jnp.float32)[..., None] * inv_freq   # (B, S, d/2)
+    if x.ndim == positions.ndim + 2:
+        ang = ang[..., None, :]
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    xp = x.astype(jnp.float32).reshape(*x.shape[:-1], -1, 2)
+    x0, x1 = xp[..., 0], xp[..., 1]
+    out = jnp.stack([x0 * cos - x1 * sin, x0 * sin + x1 * cos], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
+def mla_scale(cfg: ModelConfig) -> float:
+    """Softmax scale: (nope + rope)^-1/2, times YaRN's mscale(all_dim)^2 as
+    DeepSeek's own code sets it (transformers' port leaves the square
+    out)."""
+    a = cfg.mla
+    s = (a.qk_nope_dim + a.qk_rope_dim) ** -0.5
+    if a.yarn is not None:
+        s *= yarn_mscale(a.yarn.factor, a.yarn.mscale_all_dim) ** 2
+    return s
+
+
+def _mla_project(p: Params, x, cfg: ModelConfig, positions):
+    """(q_nope (B,S,H,nope), rotated q_pe (B,S,H,rope), latent row (B,S,
+    rank+rope)): the row is what the cache keeps per token, the normed
+    latent and the rotated key part shared by every head."""
+    a, dt = cfg.mla, cfg.dtype
+    B, S, _ = x.shape
+    inv = rope_inv_freq(a.qk_rope_dim, cfg.rope_theta, a.yarn)
+    q = (x @ p["wq"].astype(dt)).reshape(
+        B, S, cfg.n_heads, a.qk_nope_dim + a.qk_rope_dim)
+    kv = x @ p["wkv_a"].astype(dt)
+    c = ops.rmsnorm(kv[..., :a.kv_rank], p["kv_norm_g"].astype(dt))
+    k_pe = rope_pairs(kv[..., a.kv_rank:], positions, inv)
+    q_pe = rope_pairs(q[..., a.qk_nope_dim:], positions, inv)
+    return q[..., :a.qk_nope_dim], q_pe, jnp.concatenate([c, k_pe], axis=-1)
+
+
+def apply_mla(p: Params, x, cfg: ModelConfig, positions):
+    """Full-sequence causal latent attention in the expanded form: per head
+    [k_nope, v] = latent @ wkv_b, k = [k_nope, k_pe].  x: (B, S, D) ->
+    (out (B, S, D), latent rows (B, S, rank+rope) for the cache)."""
+    a, dt = cfg.mla, cfg.dtype
+    B, S, _ = x.shape
+    H = cfg.n_heads
+    q_nope, q_pe, lat = _mla_project(p, x, cfg, positions)
+    kvb = (lat[..., :a.kv_rank] @ p["wkv_b"].astype(dt)).reshape(
+        B, S, H, a.qk_nope_dim + a.v_dim)
+    k_pe = jnp.broadcast_to(lat[:, :, None, a.kv_rank:],
+                            (B, S, H, a.qk_rope_dim))
+    q = jnp.concatenate([q_nope, q_pe], axis=-1)
+    k = jnp.concatenate([kvb[..., :a.qk_nope_dim], k_pe], axis=-1)
+    v = kvb[..., a.qk_nope_dim:]
+    scale = mla_scale(cfg)
+    if S > _CHUNK_Q and S % _CHUNK_Q == 0:
+        out = _chunked_causal_attention(q, k, v, scale, None,
+                                        use_scan=cfg.scan_layers, cfg=cfg)
+    else:
+        from repro.kernels import ref
+        out = ref.attention(q, k, v, causal=True, scale=scale,
+                            positions_q=positions)
+    return out.reshape(B, S, H * a.v_dim) @ p["wo"].astype(dt), lat
+
+
+# how the latent decode attends (``Engine.stitch_report``): the absorbed
+# form over each slot's gathered latent view; a paged latent kernel would
+# read the pages in place
+MLA_DECODE_PATH = "mla-gather"
+
+
+def mla_decode(p: Params, x, cfg: ModelConfig, positions, cache: Params,
+               layer):
+    """One decode token of latent attention in the absorbed form, reading
+    only cached latent rows: q_lat = q_nope @ W_UK, score = (q_lat . c +
+    q_pe . k_pe) * scale, o = softmax(score) . c @ W_UV @ wo.
+
+    ``cache["lat"]`` is every layer's latent store, read at ``layer`` (a
+    traced index) before this step's rows are written: a page pool (L, P,
+    page * (rank+rope)), each page one row, read through
+    ``cache["page_table"]`` by gathering each slot's pages into a view
+    sliced back to ``kv_limit``, or a dense rectangle (L, B, max_len,
+    rank+rope).  This token's row is folded into the view at the slot's
+    ``length``.  Returns (out (B, 1, D), the row (B, rank+rope) that the
+    step writes)."""
+    a, dt = cfg.mla, cfg.dtype
+    B, S, _ = x.shape
+    H, r = cfg.n_heads, a.kv_rank
+    if S != 1:
+        raise NotImplementedError("latent decode is single-token")
+    q_nope, q_pe, lat = _mla_project(p, x, cfg, positions)
+    row = lat[:, 0]
+    store, length = cache["lat"], cache["length"]
+    if "page_table" in cache:
+        pt = cache["page_table"]
+        c = a.latent_dim
+        n, psz = pt.shape[1], store.shape[2] // c
+        # one gather by (layer, page): slicing the layer out first would
+        # copy its whole pool each layer
+        view = store[layer, pt.reshape(-1)].reshape(B, n * psz, c)
+        lim = cache.get("kv_limit")
+        if lim is not None and lim < n * psz:
+            view = view[:, :lim]
+    else:
+        view = store[layer]
+    view = view.at[jnp.arange(B), length].set(row.astype(view.dtype))
+    wkv_b = p["wkv_b"].astype(dt).reshape(r, H, a.qk_nope_dim + a.v_dim)
+    q_lat = jnp.einsum("bhn,rhn->bhr", q_nope[:, 0], wkv_b[..., :a.qk_nope_dim],
+                       preferred_element_type=jnp.float32)
+    q_full = jnp.concatenate([q_lat.astype(dt), q_pe[:, 0]], axis=-1)
+    score = jnp.einsum("bhc,bkc->bhk", q_full, view.astype(dt),
+                       preferred_element_type=jnp.float32) * mla_scale(cfg)
+    kpos = jnp.arange(view.shape[1])[None, None, :]
+    score = jnp.where(kpos <= positions[:, :, None], score, -1e30)
+    probs = jax.nn.softmax(score, axis=-1)
+    o_lat = jnp.einsum("bhk,bkr->bhr", probs.astype(dt),
+                       view[..., :r].astype(dt),
+                       preferred_element_type=jnp.float32)
+    o = jnp.einsum("bhr,rhv->bhv", o_lat.astype(dt), wkv_b[..., a.qk_nope_dim:],
+                   preferred_element_type=jnp.float32).astype(dt)
+    return o.reshape(B, 1, H * a.v_dim) @ p["wo"].astype(dt), row
+
+
+def write_latent(store, rows, cache: Params):
+    """Write one decode step's latent rows (L, B, rank+rope) of every
+    layer into the store at each slot's ``length``: through the page table
+    into the slot's current page (free slots' tables point at the sink page
+    0), or into the dense rectangle.  A page is one row of the pool, so no
+    dimension of the pool is the odd rank+rope: the chip's default layout
+    of such an array moves that dimension, and every read or write of the
+    pool would then copy it whole."""
+    length = cache["length"]
+    rows = rows.astype(store.dtype)
+    if "page_table" in cache:
+        L, B, c = rows.shape
+        psz = store.shape[2] // c
+        phys = jnp.take_along_axis(cache["page_table"],
+                                   (length // psz)[:, None], axis=1)[:, 0]
+        at = jnp.stack([
+            jnp.broadcast_to(jnp.arange(L, dtype=phys.dtype)[:, None], (L, B)),
+            jnp.broadcast_to(phys, (L, B)),
+            jnp.broadcast_to(length % psz * c, (L, B))], axis=-1)
+        dnums = jax.lax.ScatterDimensionNumbers(
+            update_window_dims=(2,), inserted_window_dims=(0, 1),
+            scatter_dims_to_operand_dims=(0, 1, 2))
+        return jax.lax.scatter(
+            store, at, rows, dnums,
+            mode=jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS)
+    return store.at[:, jnp.arange(rows.shape[1]), length].set(rows)
+
+
+# ---------------------------------------------------------------------------
 # sort-based capacity MoE (dropless up to capacity, GShard semantics)
 # ---------------------------------------------------------------------------
 
@@ -451,6 +656,56 @@ def apply_moe(p: Params, x2d, cfg: ModelConfig):
     aux = E * jnp.sum(frac * jnp.mean(probs, axis=0))
     dropped = n_drop / (T * k)
     return y, {"moe_aux": aux, "moe_drop_frac": dropped.astype(jnp.float32)}
+
+
+def apply_moe_share(p: Params, x2d, cfg: ModelConfig):
+    """Dropless expert share: x2d (T, D) -> (y (T, D), aux, load (2,)).
+
+    The router keeps all ``n_experts`` outputs: a float32 softmax over
+    them, greedy top-k over all (renormalized only with ``renormalize``),
+    times ``routed_scale``.  The layer holds experts ``[e0, e0 + n)``
+    (``moe.held``) and computes, for every (token, expert) pair routed to
+    one of them, that expert's SwiGLU output weighted by its gate: the pairs
+    are sorted by held expert into a grouped matmul
+    (``jax.lax.ragged_dot``) with a static bound of T * k rows, and pairs
+    routed elsewhere sort last and contribute nothing.  No capacity, so no
+    token is dropped and no row depends on another.  The shared experts
+    run on every token.  ``load`` is (pairs routed here, most pairs on one
+    held expert)."""
+    m = cfg.moe
+    dt = cfg.dtype
+    T, D = x2d.shape
+    e0, n = m.held
+    k = m.top_k
+    logits = x2d.astype(jnp.float32) @ p["router"].astype(jnp.float32)
+    probs = jax.nn.softmax(logits, axis=-1)                     # (T, E)
+    gates, idx = jax.lax.top_k(probs, k)
+    if m.renormalize:
+        gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+    gates = gates * m.routed_scale
+    local = idx - e0
+    here = (local >= 0) & (local < n)
+    flat_e = jnp.where(here, local, n).reshape(-1)             # n: elsewhere
+    order = jnp.argsort(flat_e, stable=True)
+    tok = order // k
+    sizes = jnp.zeros((n + 1,), jnp.int32).at[flat_e].add(1)[:n]
+    xs = x2d[tok]                                               # (T*k, D)
+    gate = jax.lax.ragged_dot(xs, p["w_gate"].astype(dt), sizes)
+    up = jax.lax.ragged_dot(xs, p["w_up"].astype(dt), sizes)
+    ys = jax.lax.ragged_dot(ops.swiglu(gate, up), p["w_down"].astype(dt), sizes)
+    # rows past the held pairs are not computed: select, never multiply
+    keep = here.reshape(-1)[order][:, None]
+    contrib = jnp.where(keep, ys.astype(jnp.float32)
+                        * gates.reshape(-1)[order][:, None], 0.0)
+    y = jnp.zeros((T, D), jnp.float32).at[tok].add(contrib)
+    y = y.astype(dt)
+    if m.n_shared:
+        y = y + apply_mlp(p["shared"], x2d, cfg)
+    # load-balance aux over the whole router, as apply_moe reports it
+    counts = jnp.zeros((m.n_experts,), jnp.float32).at[idx.reshape(-1)].add(1.0)
+    aux = m.n_experts * jnp.sum(counts / (T * k) * jnp.mean(probs, axis=0))
+    load = jnp.stack([jnp.sum(sizes), jnp.max(sizes)])
+    return y, {"moe_aux": aux, "moe_drop_frac": jnp.zeros((), jnp.float32)}, load
 
 
 def apply_moe_dense(p: Params, x2d, cfg: ModelConfig):

@@ -23,15 +23,20 @@ from .layers import (
     Params,
     scan_or_unroll,
     apply_attention,
+    apply_mla,
     apply_mlp,
     apply_moe,
     apply_moe_dense,
+    apply_moe_share,
     apply_norm,
     init_attention,
+    init_mla,
     init_mlp,
     init_moe,
     init_norm,
+    mla_decode,
     paged_attention_path,
+    write_latent,
 )
 
 
@@ -39,14 +44,16 @@ from .layers import (
 # init
 # ---------------------------------------------------------------------------
 
-def init_layer(key, cfg: ModelConfig) -> Params:
+def init_layer(key, cfg: ModelConfig, dense: bool = False) -> Params:
+    """One layer; ``dense`` gives a MoE model's leading dense-MLP layer."""
     k1, k2, k3, k4 = jax.random.split(key, 4)
     p = {
         "norm1": init_norm(k1, cfg),
-        "attn": init_attention(k2, cfg),
+        "attn": (init_mla(k2, cfg) if cfg.mla is not None
+                 else init_attention(k2, cfg)),
         "norm2": init_norm(k3, cfg),
     }
-    if cfg.family == "moe":
+    if cfg.family == "moe" and not dense:
         p["mlp"] = init_moe(k4, cfg)
     else:
         p["mlp"] = init_mlp(k4, cfg)
@@ -56,13 +63,18 @@ def init_layer(key, cfg: ModelConfig) -> Params:
 def init_params(cfg: ModelConfig, key) -> Params:
     ke, kl, kh = jax.random.split(key, 3)
     layer_keys = jax.random.split(kl, cfg.n_layers)
-    layers = jax.vmap(lambda k: init_layer(k, cfg))(layer_keys)
+    nd = cfg.n_dense_layers
+    layers = jax.vmap(lambda k: init_layer(k, cfg))(
+        layer_keys[nd:] if nd else layer_keys)
     p = {
         "embed": (jax.random.normal(ke, (cfg.vocab, cfg.d_model), jnp.float32)
                   * 0.02).astype(cfg.param_dtype),
         "layers": layers,
         "final_norm": init_norm(kh, cfg),
     }
+    if nd:
+        p["dense_layers"] = jax.vmap(
+            lambda k: init_layer(k, cfg, dense=True))(layer_keys[:nd])
     if not cfg.tie_embeddings:
         p["lm_head"] = (jax.random.normal(kh, (cfg.d_model, cfg.vocab), jnp.float32)
                         / math.sqrt(cfg.d_model)).astype(cfg.param_dtype)
@@ -129,6 +141,10 @@ def _maybe_remat(fn, cfg: ModelConfig):
 
 def backbone(params: Params, h, cfg: ModelConfig, positions):
     """h: (B, S, D) embeddings -> (B, S, D) final-normed hidden, aux."""
+    if cfg.mla is not None:
+        h, _, auxs = _mla_forward(params, h, cfg, positions)
+        h = apply_norm(params["final_norm"], h, cfg)
+        return h, jax.tree.map(jnp.mean, auxs)
     body = _maybe_remat(
         lambda carry, lp: _layer_fn(carry, lp, cfg, positions), cfg)
     h, auxs = scan_or_unroll(body, h, params["layers"], cfg.n_layers,
@@ -233,6 +249,8 @@ def prefill(params: Params, tokens, cfg: ModelConfig, max_len: int | None = None
     max_len = max_len or S
     positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
     h = embed_tokens(params, tokens, cfg, patch_embeds)
+    if cfg.mla is not None:
+        return _mla_prefill(params, h, cfg, max_len, positions, true_len)
 
     def body(carry, lp):
         x = carry
@@ -252,6 +270,18 @@ def prefill(params: Params, tokens, cfg: ModelConfig, max_len: int | None = None
     body = _maybe_remat(body, cfg)
     h, (ks, vs) = scan_or_unroll(body, h, params["layers"], cfg.n_layers,
                                  cfg.scan_layers)
+    logits, length = _last_logits(params, h, cfg, true_len)
+    if max_len > S:
+        pad = [(0, 0), (0, 0), (0, max_len - S), (0, 0), (0, 0)]
+        ks, vs = jnp.pad(ks, pad), jnp.pad(vs, pad)
+    cache = {"k": ks, "v": vs, "length": length}
+    return logits, cache
+
+
+def _last_logits(params: Params, h, cfg: ModelConfig, true_len):
+    """Final norm, then float32 logits at each row's last position (its
+    true one with ``true_len``) and the cache length."""
+    S = h.shape[1]
     h = apply_norm(params["final_norm"], h, cfg)
     if true_len is None:
         last = h[:, -1]
@@ -260,15 +290,14 @@ def prefill(params: Params, tokens, cfg: ModelConfig, max_len: int | None = None
         tl = jnp.asarray(true_len, jnp.int32)           # (B,)
         last = jnp.take_along_axis(h, (tl - 1)[:, None, None], axis=1)[:, 0]
         length = tl
-    logits = (last @ _head_matrix(params, cfg)).astype(jnp.float32)
-    if max_len > S:
-        pad = [(0, 0), (0, 0), (0, max_len - S), (0, 0), (0, 0)]
-        ks, vs = jnp.pad(ks, pad), jnp.pad(vs, pad)
-    cache = {"k": ks, "v": vs, "length": length}
-    return logits, cache
+    return (last @ _head_matrix(params, cfg)).astype(jnp.float32), length
 
 
 def init_cache(cfg: ModelConfig, batch: int, max_len: int) -> Params:
+    if cfg.mla is not None:
+        return {"lat": jnp.zeros((cfg.n_layers, batch, max_len,
+                                  cfg.mla.latent_dim), cfg.dtype),
+                "length": jnp.zeros((), jnp.int32)}
     shape = (cfg.n_layers, batch, max_len, cfg.n_kv_heads, cfg.dh)
     return {
         "k": jnp.zeros(shape, cfg.dtype),
@@ -280,7 +309,12 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int) -> Params:
 def init_paged_cache(cfg: ModelConfig, num_pages: int, page_size: int) -> Params:
     """KV page pool shared by all slots: per layer, ``num_pages`` fixed-size
     pages.  The serving layer owns the page tables (see
-    :class:`repro.serve.kv.PagedKV`); the decode step only consumes them."""
+    :class:`repro.serve.kv.PagedKV`); the decode step only consumes them.
+    Latent attention keeps one pool ``lat`` of latent rows, each page one
+    row of ``page_size * (rank+rope)`` (see :func:`write_latent`)."""
+    if cfg.mla is not None:
+        return {"lat": jnp.zeros((cfg.n_layers, num_pages,
+                                  page_size * cfg.mla.latent_dim), cfg.dtype)}
     shape = (cfg.n_layers, num_pages, page_size, cfg.n_kv_heads, cfg.dh)
     return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
 
@@ -320,6 +354,8 @@ def decode_step(params: Params, cache: Params, tokens, cfg: ModelConfig,
     max_len so the attention reduction shape — and hence the tokens — match
     the dense layout bitwise.  On the kernel path each layer reads the
     whole stacked pool, a loop invariant, at its own layer index."""
+    if cfg.mla is not None:
+        return _mla_decode_step(params, cache, tokens, cfg, kv_limit)
     B, S = tokens.shape
     length = cache["length"]
     page_table = cache.get("page_table")
@@ -345,4 +381,112 @@ def decode_step(params: Params, cache: Params, tokens, cfg: ModelConfig,
     new_cache = {"k": nk, "v": nv, "length": length + S}
     if page_table is not None:
         new_cache["page_table"] = page_table
+    return logits, new_cache
+
+
+# ---------------------------------------------------------------------------
+# latent attention and leading dense layers (DeepSeek-V2)
+# ---------------------------------------------------------------------------
+
+def _stacks(params: Params, cfg: ModelConfig):
+    """The layer stacks in order, as (params, first layer, count, dense
+    MLP): the leading dense layers, then the rest."""
+    nd = cfg.n_dense_layers
+    first = [(params["dense_layers"], 0, nd, True)] if nd else []
+    return first + [(params["layers"], nd, cfg.n_layers - nd, False)]
+
+
+def _ffn(p: Params, x, cfg: ModelConfig, dense: bool):
+    """A layer's MLP on x (B, S, D): (out, aux, load (2,) or None)."""
+    if dense or cfg.family != "moe":
+        return apply_mlp(p, x, cfg), _empty_aux(), None
+    B, S, D = x.shape
+    if cfg.moe.held is not None:
+        y, aux, load = apply_moe_share(p, x.reshape(B * S, D), cfg)
+    else:
+        (y, aux), load = apply_moe(p, x.reshape(B * S, D), cfg), None
+    return y.reshape(B, S, D), aux, load
+
+
+def _mla_forward(params: Params, h, cfg: ModelConfig, positions):
+    """Every stack over h (B, S, D), full-sequence: (h before the final
+    norm, latent rows (L, B, S, rank+rope), aux per layer)."""
+    lats, auxs = [], []
+    for stack, _, n, dense in _stacks(params, cfg):
+        def body(carry, lp, dense=dense):
+            a_in = apply_norm(lp["norm1"], carry, cfg)
+            attn_out, lat = apply_mla(lp["attn"], a_in, cfg, positions)
+            carry = carry + attn_out
+            m_in = apply_norm(lp["norm2"], carry, cfg)
+            mlp_out, aux, _ = _ffn(lp["mlp"], m_in, cfg, dense)
+            return carry + mlp_out, (lat.astype(cfg.dtype), aux)
+
+        h, (lat, aux) = scan_or_unroll(_maybe_remat(body, cfg), h, stack, n,
+                                       cfg.scan_layers)
+        lats.append(lat)
+        auxs.append(aux)
+    return (h, jnp.concatenate(lats),
+            jax.tree.map(lambda *a: jnp.concatenate(a), *auxs))
+
+
+def _mla_prefill(params: Params, h, cfg: ModelConfig, max_len: int,
+                 positions, true_len):
+    """:func:`prefill` of a latent-attention model: the cache holds the
+    latent rows, ``lat`` (L, B, max_len, rank+rope)."""
+    S = h.shape[1]
+    h, lat, _ = _mla_forward(params, h, cfg, positions)
+    logits, length = _last_logits(params, h, cfg, true_len)
+    if max_len > S:
+        lat = jnp.pad(lat, [(0, 0), (0, 0), (0, max_len - S), (0, 0)])
+    return logits, {"lat": lat, "length": length}
+
+
+def _mla_decode_step(params: Params, cache: Params, tokens, cfg: ModelConfig,
+                     kv_limit=None):
+    """:func:`decode_step` of a latent-attention model.  Each layer reads
+    the latent store as a loop invariant (:func:`mla_decode`, at its own
+    layer index, counted across the stacks) and returns its row; one write
+    after the stacks puts every layer's row in place
+    (:func:`write_latent`).  With a dropless expert share the new cache
+    also carries ``moe_load`` (2,): the (token, expert) pairs routed to
+    held experts, summed over layers, and the most pairs on one held
+    expert in one layer."""
+    B, S = tokens.shape
+    length = cache["length"]
+    lengths = jnp.broadcast_to(length, (B,))
+    positions = lengths[:, None] + jnp.arange(S, dtype=jnp.int32)[None]
+    h = embed_tokens(params, tokens, cfg)
+    view = {"lat": cache["lat"], "length": lengths}
+    if "page_table" in cache:
+        view["page_table"] = cache["page_table"]
+        view["kv_limit"] = kv_limit
+    rows, loads = [], []
+    for stack, first, n, dense in _stacks(params, cfg):
+        def body(carry, xs, dense=dense):
+            lp, layer = xs
+            a_in = apply_norm(lp["norm1"], carry, cfg)
+            attn_out, row = mla_decode(lp["attn"], a_in, cfg, positions,
+                                       view, layer)
+            carry = carry + attn_out
+            m_in = apply_norm(lp["norm2"], carry, cfg)
+            mlp_out, _, load = _ffn(lp["mlp"], m_in, cfg, dense)
+            return carry + mlp_out, (row, load)
+
+        layers = jnp.arange(first, first + n, dtype=jnp.int32)
+        h, (row, load) = scan_or_unroll(body, h, (stack, layers), n,
+                                        cfg.scan_layers)
+        rows.append(row)
+        if load is not None:
+            loads.append(load)
+    h = apply_norm(params["final_norm"], h, cfg)
+    logits = (h[:, -1] @ _head_matrix(params, cfg)).astype(jnp.float32)
+    new_cache = {"lat": write_latent(cache["lat"], jnp.concatenate(rows),
+                                     view),
+                 "length": length + S}
+    if "page_table" in cache:
+        new_cache["page_table"] = cache["page_table"]
+    if loads:
+        load = jnp.concatenate(loads)
+        new_cache["moe_load"] = jnp.stack([jnp.sum(load[:, 0]),
+                                           jnp.max(load[:, 1])])
     return logits, new_cache

@@ -50,7 +50,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from repro import obs
 from repro.exec import stitch
 from repro.models.api import Model
-from repro.models.layers import paged_attention_path
+from repro.models.layers import MLA_DECODE_PATH, paged_attention_path
 
 from .kv import DenseKV, PagedKV, Prefix
 
@@ -127,6 +127,9 @@ class Engine:
         self._prefix = None
         # how the traced paged decode step attends: "kernel" | "gather"
         self._paged_attention: str | None = None
+        # a dropless expert share reads its load back with the tokens
+        moe = model.cfg.moe
+        self._moe_share = moe is not None and moe.held is not None
         self._exec = self._build_exec()
         self._prefill_exec = self._build_prefill_exec()
 
@@ -153,8 +156,9 @@ class Engine:
 
         def decode_step(params, cache, tok, extra):
             if "page_table" in cache:
-                self._paged_attention = paged_attention_path(
-                    model.cfg, cache["k"].shape)
+                self._paged_attention = (
+                    MLA_DECODE_PATH if "lat" in cache else
+                    paged_attention_path(model.cfg, cache["k"].shape))
             return model.decode_step(params, cache, tok, kv_limit=kvl, **extra)
 
         # eligibility covers only (cache, tok, extra): params are fixed for
@@ -333,11 +337,13 @@ class Engine:
         occ = sorted(self._occupied)
         pages = {}
         if self.paged:
-            pages = {"kv_live_pages": self.kv.live_pages(occ, steps),
+            live = ("kv_latent_pages" if self.model.cfg.mla is not None
+                    else "kv_live_pages")
+            pages = {live: self.kv.live_pages(occ, steps),
                      "kv_view_pages": steps * self.kv.slots
                      * self.kv.pages_per_slot}
         with obs.span("serve.decode", cat="serve", slots=len(occ),
-                      steps=steps, **pages):
+                      steps=steps, **pages) as dsp:
             with obs.span("serve.decode.prep", cat="serve"):
                 if self.paged:
                     for s in occ:
@@ -347,16 +353,27 @@ class Engine:
                 # mutated below while the chunk is still in flight on some
                 # backends
                 tok = jnp.asarray(self._tok.copy())
-            toks_dev = []
+            toks_dev, loads = [], []
             for _ in range(steps):
                 logits, cache = self._decode_dispatch(cache, tok, extra)
+                if self._moe_share:
+                    loads.append(cache.pop("moe_load"))
                 with obs.span("serve.decode.sample", cat="serve"):
                     tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None]
                     toks_dev.append(tok)
                     if len(toks_dev) == steps:
                         toks = jnp.concatenate(toks_dev, axis=1)
+                        if loads:
+                            # the expert load rides the tokens' one copy
+                            toks = jnp.concatenate(
+                                [toks.reshape(-1), jnp.stack(loads).reshape(-1)])
             with obs.span("serve.decode.readback", cat="serve"):
                 out = np.asarray(toks)
+            if loads:
+                out, load = (out[:-2 * steps].reshape(-1, steps),
+                             out[-2 * steps:].reshape(steps, 2))
+                dsp.set(moe_pairs_here=int(load[:, 0].sum()),
+                        moe_max_load=int(load[:, 1].max()))
             with obs.span("serve.decode.commit", cat="serve"):
                 self.kv.absorb(cache)
                 self.kv.advance(occ, steps)
@@ -424,9 +441,22 @@ class Engine:
         upgrade status, plan stats, call counts, cache hit rates, and every
         background-compile failure (also in pure-jit mode) — plus
         ``paged_attention``: how the traced paged step attends
-        (``"kernel"`` | ``"gather"``; None before a paged step is traced)."""
+        (``"kernel"`` | ``"gather"``, or ``"mla-gather"`` for latent
+        attention, absorbed over each slot's gathered latent pages; None
+        before a paged step is traced), ``attention`` (the same for latent
+        attention, whatever the cache, else the paged path) and ``moe``
+        (a dropless expert share's ``{"held": [first, end), "experts": E,
+        "dropless": True}``, else None)."""
         rep = self._exec.report()
         rep["paged_attention"] = self._paged_attention
+        cfg = self.model.cfg
+        rep["attention"] = (MLA_DECODE_PATH if cfg.mla is not None
+                            else self._paged_attention)
+        rep["moe"] = None
+        if self._moe_share:
+            e0, n = cfg.moe.held
+            rep["moe"] = {"held": [e0, e0 + n], "experts": cfg.moe.n_experts,
+                          "dropless": True}
         return rep
 
     def land_plans(self, timeout: float | None = None) -> int:

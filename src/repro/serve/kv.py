@@ -14,6 +14,11 @@ chunk.  Eviction frees pages back to the free list instead of abandoning a
 cache hit a page-table splice — shared full pages are refcounted, only the
 partial tail page (where decode writes land) is copied per slot.
 
+Pool leaves: ``k`` and ``v`` (L, num_pages, page_size, Hkv, dh) for
+grouped-query attention; one ``lat`` (L, num_pages, page_size *
+(rank+rope)) of latent rows for latent attention, read by the decode step
+through the same page tables.
+
 Numerics: on the gather path the decode step gathers each slot's pages
 into a dense view and slices it back to ``max_len`` (see ``kv_limit`` in
 the model layer), so paged serving is token-for-token — bitwise — equal to
@@ -53,7 +58,7 @@ class Prefix:
     bucket: int                          # padded prefill length (pow2 bucket)
     kv: dict | None = None               # {"k": (L,B,pb,H,dh), "v": ..., "length"}
     pages: list[int] | None = None       # shared full pages (prefix cache)
-    tail: tuple | None = None            # (k, v): (L, page_size, H, dh) device
+    tail: dict | None = None             # pool leaf -> its (L, page_size, ...) rows
     cached: bool = False                 # True when served from the PrefixCache
     rid: int = -1                        # request id, for per-request spans
 
@@ -166,13 +171,13 @@ class PagedKV:
         Host arrays are COPIED at the boundary: ``jnp.asarray`` may
         zero-copy-alias a numpy buffer (CPU backend), and the host mutates
         ``table``/``lengths`` while the async decode chunk is in flight."""
-        return {"k": self.pool["k"], "v": self.pool["v"],
+        return {**self.pool,
                 "page_table": jnp.asarray(self.table.copy()),
                 "length": jnp.asarray(self.lengths.copy())}
 
     def absorb(self, new_cache: dict) -> None:
         """Take the pool back from a decode chunk's output cache."""
-        self.pool = {"k": new_cache["k"], "v": new_cache["v"]}
+        self.pool = {name: new_cache[name] for name in self.pool}
 
     def live_pages(self, slots, steps: int) -> int:
         """Pages per layer that ``steps`` decode steps read below the
@@ -200,31 +205,46 @@ class PagedKV:
 
     # -- insert / free ---------------------------------------------------------
     def _splice_fn(self, pb: int, n: int) -> Callable:
-        """Jitted pool write: row ``row`` of a (L, B, pb, H, dh) prefill
-        cache -> ``n`` pool pages (one compile per (bucket, page count))."""
+        """Jitted pool write: row ``row`` of a (L, B, pb, ...) prefill
+        cache -> ``n`` pool pages of every pool leaf (one compile per
+        (bucket, page count)).  The pool is donated and written page by
+        page in place: a copy, or the relayout a scatter over the page
+        axis of the latent pool asks for, would need a second pool's worth
+        of device memory."""
         fn = self._splice_fns.get((pb, n))
         if fn is None:
             ps = self.page_size
 
-            def splice(pool_k, pool_v, pk, pv, row, pages):
+            def splice(pool, src, row, pages):
                 need = n * ps
-                out = []
-                for pool, src in ((pool_k, pk), (pool_v, pv)):
+                out = {}
+                for name, leaf in pool.items():
                     sel = jax.lax.dynamic_index_in_dim(
-                        src, row, axis=1, keepdims=False)     # (L, pb, H, dh)
+                        src[name], row, axis=1, keepdims=False)  # (L, pb, ...)
+                    pad = [(0, 0)] * sel.ndim
                     if need > pb:
-                        sel = jnp.pad(sel, [(0, 0), (0, need - pb),
-                                            (0, 0), (0, 0)])
+                        pad[1] = (0, need - pb)
+                        sel = jnp.pad(sel, pad)
                     else:
                         sel = sel[:, :need]
-                    L, _, H, dh = sel.shape
-                    pg = sel.reshape(L, n, ps, H, dh).astype(pool.dtype)
-                    out.append(pool.at[:, pages].set(pg))
-                return out[0], out[1]
+                    pg = sel.reshape(sel.shape[0], n, *leaf.shape[2:]
+                                     ).astype(leaf.dtype)
+                    rest = (0,) * (leaf.ndim - 2)
 
-            fn = jax.jit(splice)
+                    def put(i, acc, pg=pg, rest=rest):
+                        page = jax.lax.dynamic_slice_in_dim(pg, i, 1, axis=1)
+                        return jax.lax.dynamic_update_slice(
+                            acc, page, (0, pages[i], *rest))
+
+                    out[name] = jax.lax.fori_loop(0, n, put, leaf)
+                return out
+
+            fn = jax.jit(splice, donate_argnums=(0,))
             self._splice_fns[(pb, n)] = fn
         return fn
+
+    def _bucket(self, kv: dict) -> int:
+        return kv[next(iter(self.pool))].shape[2]
 
     def insert_kv(self, kv: dict, row: int, true_len: int, slot: int) -> None:
         """Private-page insert: splice one prefill row into freshly
@@ -232,9 +252,8 @@ class PagedKV:
         cache re-home)."""
         n = max(1, math.ceil(true_len / self.page_size))
         pages = self._alloc(n)
-        pb = kv["k"].shape[2]
-        self.pool["k"], self.pool["v"] = self._splice_fn(pb, n)(
-            self.pool["k"], self.pool["v"], kv["k"], kv["v"],
+        self.pool = self._splice_fn(self._bucket(kv), n)(
+            self.pool, {name: kv[name] for name in self.pool},
             jnp.asarray(row, jnp.int32), jnp.asarray(pages, jnp.int32))
         self._set_slot(slot, pages, true_len)
 
@@ -248,13 +267,13 @@ class PagedKV:
         if tail is not None:
             tp = self._alloc(1)[0]
             if self._tail_fn is None:
-                self._tail_fn = jax.jit(
-                    lambda pool_k, pool_v, tk, tv, p: (
-                        pool_k.at[:, p].set(tk.astype(pool_k.dtype)),
-                        pool_v.at[:, p].set(tv.astype(pool_v.dtype))))
-            self.pool["k"], self.pool["v"] = self._tail_fn(
-                self.pool["k"], self.pool["v"], tail[0], tail[1],
-                jnp.asarray(tp, jnp.int32))
+                self._tail_fn = jax.jit(lambda pool, rows, p: {
+                    name: leaf.at[:, p].set(
+                        rows[name].reshape(leaf.shape[:1] + leaf.shape[2:])
+                        .astype(leaf.dtype))
+                    for name, leaf in pool.items()}, donate_argnums=(0,))
+            self.pool = self._tail_fn(self.pool, tail,
+                                      jnp.asarray(tp, jnp.int32))
             owned.append(tp)
         self._set_slot(slot, owned, true_len)
 
@@ -278,28 +297,27 @@ class PagedKV:
     def materialize_prefix(self, kv: dict, row: int, true_len: int):
         """Copy a prefill row into cache-owned pool pages: returns
         (full_pages, tail) where ``full_pages`` covers the ``true_len //
-        page_size`` complete pages and ``tail`` is the partial page's KV as
-        plain device arrays (no pool page held)."""
+        page_size`` complete pages and ``tail`` is the partial page's rows
+        of each pool leaf as plain device arrays (no pool page held)."""
         ps = self.page_size
         n_full = true_len // ps
+        pb = self._bucket(kv)
         pages: list[int] = []
         if n_full:
             pages = self._alloc(n_full)
-            pb = kv["k"].shape[2]
-            self.pool["k"], self.pool["v"] = self._splice_fn(pb, n_full)(
-                self.pool["k"], self.pool["v"], kv["k"], kv["v"],
+            self.pool = self._splice_fn(pb, n_full)(
+                self.pool, {name: kv[name] for name in self.pool},
                 jnp.asarray(row, jnp.int32), jnp.asarray(pages, jnp.int32))
         tail = None
         if true_len % ps:
             lo = n_full * ps
-            pb = kv["k"].shape[2]
             pad = max(0, lo + ps - pb)
-            tk = kv["k"][:, row, lo:lo + ps]
-            tv = kv["v"][:, row, lo:lo + ps]
-            if pad:
-                tk = jnp.pad(tk, [(0, 0), (0, pad), (0, 0), (0, 0)])
-                tv = jnp.pad(tv, [(0, 0), (0, pad), (0, 0), (0, 0)])
-            tail = (tk, tv)
+            tail = {}
+            for name in self.pool:
+                t = kv[name][:, row, lo:lo + ps]
+                if pad:
+                    t = jnp.pad(t, [(0, 0), (0, pad)] + [(0, 0)] * (t.ndim - 2))
+                tail[name] = t
         return pages, tail
 
     def release_pages(self, pages: list[int]) -> None:
@@ -364,7 +382,8 @@ class DenseKV:
         return fn
 
     def insert_kv(self, kv: dict, row: int, true_len: int, slot: int) -> None:
-        self.cache = self._write_fn(kv["k"].shape[2])(
+        pb = next(v for k, v in kv.items() if k != "length").shape[2]
+        self.cache = self._write_fn(pb)(
             self.cache, kv, jnp.asarray(row, jnp.int32),
             jnp.asarray(slot, jnp.int32))
 

@@ -30,7 +30,7 @@ __all__ = ["PrefixCache", "PrefixEntry"]
 @dataclass
 class PrefixEntry:
     pages: list[int]          # cache-owned full pages in the pool
-    tail: tuple | None        # (k, v) partial-page KV, outside the pool
+    tail: dict | None         # pool leaf -> partial-page rows, outside the pool
     length: int
     first_token: int
 

@@ -47,10 +47,13 @@ __all__ = ["SchedulerConfig", "Scheduler", "RAGGED_FAMILIES",
 # families whose decode step supports a per-slot length vector AND whose
 # prefill is pad-invariant under causal masking (SSM/hybrid state mixes pad
 # tokens in, so bucketed admission would change numerics there).  Caveat:
-# moe is pad-invariant only while no expert overflows its capacity —
-# GShard token-choice dispatch couples rows through the shared capacity
-# budget (pad/ride-along tokens can displace real ones on overflow), the
-# same coupling a static moe batch already has.  dense/vlm are exact.
+# moe on the capacity dispatch (``apply_moe``) is pad-invariant only while
+# no expert overflows its capacity — GShard token-choice dispatch couples
+# rows through the shared capacity budget (pad/ride-along tokens can
+# displace real ones on overflow), the same coupling a static moe batch
+# already has.  moe on the dropless expert share (``MoEConfig.held``,
+# ``apply_moe_share``) computes every (token, expert) pair with no
+# capacity, so no row depends on another: exact, as dense/vlm are.
 RAGGED_FAMILIES = ("dense", "moe", "vlm")
 
 # the admission bucket rule, shared with Engine.prefill so the static

@@ -127,15 +127,52 @@ def _decode_graph():
     return g
 
 
+def _deepseek_decode_graph():
+    """The paged latent decode step of DeepSeek-V2-Lite at published
+    widths, one chip's 16 of 64 experts, at 48 slots: its slot vectors of
+    48 rows and its scalar masks are where the chip refused stitched
+    groups (a rank-1 block of 8 rows; a bool scalar input).  Depth is cut
+    to the dense layer and two MoE layers (each stack is one scan)."""
+    from repro.models import build_model
+    from repro.models.config import (MLAConfig, ModelConfig, MoEConfig,
+                                     YarnConfig)
+
+    slots, max_len = 48, 2048
+    cfg = ModelConfig(
+        name="deepseek-v2-lite", family="moe", n_layers=3, d_model=2048,
+        n_heads=16, n_kv_heads=16, d_ff=10944, vocab=102400, head_dim=192,
+        n_dense_layers=1, tie_embeddings=False,
+        mla=MLAConfig(kv_rank=512, qk_nope_dim=128, qk_rope_dim=64,
+                      v_dim=128, yarn=YarnConfig(factor=40.0,
+                                                 original_max=4096,
+                                                 mscale_all_dim=0.707)),
+        moe=MoEConfig(n_experts=64, top_k=6, d_expert=1408, n_shared=2,
+                      d_shared=2816, renormalize=False, held=(0, 16)),
+        dtype="bfloat16", param_dtype="bfloat16")
+    model = build_model(cfg)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    pps = max_len // PAGE
+    cache = dict(jax.eval_shape(
+        lambda: model.init_paged_cache(slots * pps + 1, PAGE)),
+        page_table=jax.ShapeDtypeStruct((slots, pps), jnp.int32),
+        length=jax.ShapeDtypeStruct((slots,), jnp.int32))
+    tok = jax.ShapeDtypeStruct((slots, 1), jnp.int32)
+    g, _ = trace_to_graph(
+        lambda p, c, t: model.decode_step(p, c, t, kv_limit=max_len),
+        params, cache, tok, name="decode")
+    return g
+
+
 @pytest.mark.parametrize("graph", ["softmax_64x256", "mlp_norm_8x2048",
-                                   "qwen3_decode"])
+                                   "qwen3_decode", "deepseek_decode"])
 def test_stitched_groups_compile(graph, compiled):
     """Every Pallas group the planner picks compiles for the chip.  The
     2048x2048 f32 weight of ``mlp_norm`` (32 MiB double-buffered) exceeds
     a kernel's VMEM, so the planner must leave that GEMM outside."""
     g = {"softmax_64x256": lambda: make_softmax_graph(64, 256)[0],
          "mlp_norm_8x2048": lambda: make_mlp_norm_graph(8, 2048),
-         "qwen3_decode": _decode_graph}[graph]()
+         "qwen3_decode": _decode_graph,
+         "deepseek_decode": _deepseek_decode_graph}[graph]()
     groups = _pallas_groups(g)
     assert groups, f"the {graph} plan has no Pallas group"
     for _, fn, avals in groups:
