@@ -62,7 +62,8 @@ def lookup(node: OpNode) -> Optional[RegisteredKernel]:
 # -- built-in descriptors -----------------------------------------------------
 #
 # The formulas mirror the actual kernel bodies (kernels/flash_attention.py,
-# kernels/router.py, kernels/decode_attention.py, the VPU kernels).
+# kernels/router.py, kernels/decode_attention.py,
+# kernels/latent_attention.py, the VPU kernels).
 
 
 def _flash_flops(node: OpNode, g: Graph) -> float:
@@ -117,6 +118,26 @@ def _paged_decode_flops(node: OpNode, g: Graph) -> float:
     return 4.0 * b * hq * smax * dh
 
 
+def _paged_latent_flops(node: OpNode, g: Graph) -> float:
+    # pallas_call operands: (layer, page_table (B*n,), lengths, q_lat (B, H,
+    # rank), q_pe stacked (B, pack*H, lanes), c_new, pe_new, pool_lat (L, P,
+    # page, rank), pool_rope) — see kernels/latent_attention.py; priced as
+    # attention over every slot's whole view, its rope scored once per
+    # lane group
+    if len(node.operands) < 8:
+        return 0.0
+    pt = g[node.operands[1]].shape
+    q = g[node.operands[3]].shape
+    qb = g[node.operands[4]].shape
+    pool = g[node.operands[7]].shape
+    if len(pt) != 1 or len(q) != 3 or len(qb) != 3 or len(pool) != 4:
+        return 0.0
+    b, h, rank = q
+    smax = pt[0] // max(b, 1) * pool[2]
+    # scores against the latent and the packed rope rows, then p . c
+    return 2.0 * b * smax * (2 * h * rank + qb[1] * qb[2])
+
+
 def _vpu_flops(per_elem: float, operand: int = 0):
     """Memory-bound VPU kernels (norms, rope, GLU): a few ops per element of
     the named operand, no MXU work."""
@@ -133,6 +154,7 @@ register(RegisteredKernel("_flash_kernel", _flash_flops))
 register(RegisteredKernel("_router_kernel", _router_flops))
 register(RegisteredKernel("_decode_attn_kernel", _decode_attn_flops))
 register(RegisteredKernel("_paged_decode_kernel", _paged_decode_flops))
+register(RegisteredKernel("_paged_latent_kernel", _paged_latent_flops))
 register(RegisteredKernel("_rmsnorm_kernel", _vpu_flops(4.0)))
 register(RegisteredKernel("_rmsnorm_residual_kernel", _vpu_flops(5.0)))
 register(RegisteredKernel("_layernorm_kernel", _vpu_flops(6.0)))

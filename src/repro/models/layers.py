@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import decode_attention as _decode
+from repro.kernels import latent_attention as _latent
 from repro.kernels import ops
 from .config import ModelConfig
 
@@ -466,10 +467,67 @@ def apply_mla(p: Params, x, cfg: ModelConfig, positions):
     return out.reshape(B, S, H * a.v_dim) @ p["wo"].astype(dt), lat
 
 
-# how the latent decode attends (``Engine.stitch_report``): the absorbed
-# form over each slot's gathered latent view; a paged latent kernel would
-# read the pages in place
-MLA_DECODE_PATH = "mla-gather"
+def mla_decode_path(cfg: ModelConfig, pool) -> str:
+    """How the latent decode step attends over the latent store ``pool``
+    (a cache dict): ``"mla-kernel"`` reads each slot's live pages of the
+    paged pool's ``lat`` and ``rope`` leaves in place
+    (:func:`repro.kernels.latent_attention.paged_latent_decode_attention`),
+    where Pallas compiles and the pages tile; ``"mla-gather"`` gathers every
+    slot's whole view (or reads the dense rectangle) and masks it, bitwise
+    equal to the dense layout."""
+    if "rope" in pool and _decode.paged_kernel_platform() and \
+            _latent.paged_latent_fits(pool["lat"].shape, pool["rope"].shape,
+                                      cfg.n_heads):
+        return "mla-kernel"
+    return "mla-gather"
+
+
+def _latent_view(cache: Params, layer, rope_dim: int):
+    """Every slot's whole latent view (B, n * page, rank+rope) at ``layer``
+    of the paged pool, gathered through the page table: ``lat`` by (layer,
+    page), ``rope`` by (layer, page // pack) and then the page's own lane
+    group.  One gather each: slicing the layer out first would copy its
+    whole pool each layer."""
+    lat, rope, pt = cache["lat"], cache["rope"], cache["page_table"]
+    B, n = pt.shape
+    _, _, psz, lanes = rope.shape
+    pack = lanes // rope_dim
+    flat = pt.reshape(-1)
+    c = lat[layer, flat]                                 # (B*n, page, rank)
+    pe = rope[layer, flat // pack].reshape(B * n, psz, pack, rope_dim)
+    pe = jnp.take_along_axis(pe, (flat % pack)[:, None, None, None],
+                             axis=2)[:, :, 0]
+    return jnp.concatenate([c, pe], axis=-1).reshape(B, n * psz, -1)
+
+
+def latent_gather_attention(q_lat, q_pe, cache: Params, layer, row, *,
+                            scale: float):
+    """The gather path of :func:`mla_decode`: ``o_lat`` (B, H, rank) =
+    softmax(scores) . c over each slot's whole view at ``layer`` -- gathered
+    from the paged pool and sliced back to ``kv_limit``, or the dense
+    rectangle's -- with this step's ``row`` (B, rank+rope) set at the
+    slot's ``length`` and every later position masked.  q_lat (B, H, rank)
+    and q_pe (B, H, rope) are in the compute dtype."""
+    B, _, r = q_lat.shape
+    length = cache["length"]
+    if "page_table" in cache:
+        view = _latent_view(cache, layer, q_pe.shape[-1])
+        lim = cache.get("kv_limit")
+        if lim is not None and lim < view.shape[1]:
+            view = view[:, :lim]
+    else:
+        view = cache["lat"][layer]
+    view = view.at[jnp.arange(B), length].set(row.astype(view.dtype))
+    dt = q_lat.dtype
+    q_full = jnp.concatenate([q_lat, q_pe], axis=-1)
+    score = jnp.einsum("bhc,bkc->bhk", q_full, view.astype(dt),
+                       preferred_element_type=jnp.float32) * scale
+    kpos = jnp.arange(view.shape[1])[None, None, :]
+    score = jnp.where(kpos <= length[:, None, None], score, -1e30)
+    probs = jax.nn.softmax(score, axis=-1)
+    return jnp.einsum("bhk,bkr->bhr", probs.astype(dt),
+                      view[..., :r].astype(dt),
+                      preferred_element_type=jnp.float32)
 
 
 def mla_decode(p: Params, x, cfg: ModelConfig, positions, cache: Params,
@@ -478,14 +536,15 @@ def mla_decode(p: Params, x, cfg: ModelConfig, positions, cache: Params,
     only cached latent rows: q_lat = q_nope @ W_UK, score = (q_lat . c +
     q_pe . k_pe) * scale, o = softmax(score) . c @ W_UV @ wo.
 
-    ``cache["lat"]`` is every layer's latent store, read at ``layer`` (a
-    traced index) before this step's rows are written: a page pool (L, P,
-    page * (rank+rope)), each page one row, read through
-    ``cache["page_table"]`` by gathering each slot's pages into a view
-    sliced back to ``kv_limit``, or a dense rectangle (L, B, max_len,
-    rank+rope).  This token's row is folded into the view at the slot's
-    ``length``.  Returns (out (B, 1, D), the row (B, rank+rope) that the
-    step writes)."""
+    The cache holds every layer's latent store, read at ``layer`` (a traced
+    index) before this step's rows are written: a page pool of two leaves,
+    ``lat`` (L, P, page, rank) and ``rope`` (L, P', page, lanes) (see
+    :func:`repro.models.lm.init_paged_cache`), read through
+    ``cache["page_table"]``; or a dense rectangle ``lat`` (L, B, max_len,
+    rank+rope).  On the kernel path (:func:`mla_decode_path`) each slot's
+    live pages are read in place and the kernel folds this token's row in;
+    otherwise :func:`latent_gather_attention`.  Returns (out (B, 1, D), the
+    row (B, rank+rope) that the step writes)."""
     a, dt = cfg.mla, cfg.dtype
     B, S, _ = x.shape
     H, r = cfg.n_heads, a.kv_rank
@@ -493,63 +552,61 @@ def mla_decode(p: Params, x, cfg: ModelConfig, positions, cache: Params,
         raise NotImplementedError("latent decode is single-token")
     q_nope, q_pe, lat = _mla_project(p, x, cfg, positions)
     row = lat[:, 0]
-    store, length = cache["lat"], cache["length"]
-    if "page_table" in cache:
-        pt = cache["page_table"]
-        c = a.latent_dim
-        n, psz = pt.shape[1], store.shape[2] // c
-        # one gather by (layer, page): slicing the layer out first would
-        # copy its whole pool each layer
-        view = store[layer, pt.reshape(-1)].reshape(B, n * psz, c)
-        lim = cache.get("kv_limit")
-        if lim is not None and lim < n * psz:
-            view = view[:, :lim]
-    else:
-        view = store[layer]
-    view = view.at[jnp.arange(B), length].set(row.astype(view.dtype))
     wkv_b = p["wkv_b"].astype(dt).reshape(r, H, a.qk_nope_dim + a.v_dim)
     q_lat = jnp.einsum("bhn,rhn->bhr", q_nope[:, 0], wkv_b[..., :a.qk_nope_dim],
-                       preferred_element_type=jnp.float32)
-    q_full = jnp.concatenate([q_lat.astype(dt), q_pe[:, 0]], axis=-1)
-    score = jnp.einsum("bhc,bkc->bhk", q_full, view.astype(dt),
-                       preferred_element_type=jnp.float32) * mla_scale(cfg)
-    kpos = jnp.arange(view.shape[1])[None, None, :]
-    score = jnp.where(kpos <= positions[:, :, None], score, -1e30)
-    probs = jax.nn.softmax(score, axis=-1)
-    o_lat = jnp.einsum("bhk,bkr->bhr", probs.astype(dt),
-                       view[..., :r].astype(dt),
-                       preferred_element_type=jnp.float32)
+                       preferred_element_type=jnp.float32).astype(dt)
+    if mla_decode_path(cfg, cache) == "mla-kernel":
+        o_lat = _latent.paged_latent_decode_attention(
+            q_lat, q_pe[:, 0], cache["lat"], cache["rope"], layer,
+            cache["page_table"], cache["length"], row, scale=mla_scale(cfg))
+    else:
+        o_lat = latent_gather_attention(q_lat, q_pe[:, 0], cache, layer, row,
+                                        scale=mla_scale(cfg))
     o = jnp.einsum("bhr,rhv->bhv", o_lat.astype(dt), wkv_b[..., a.qk_nope_dim:],
                    preferred_element_type=jnp.float32).astype(dt)
     return o.reshape(B, 1, H * a.v_dim) @ p["wo"].astype(dt), row
 
 
-def write_latent(store, rows, cache: Params):
+def _scatter_rows(leaf, rows, at):
+    """``leaf[at[..., :-1], at[-1]:at[-1] + w] = rows`` for rows (L, B, w)
+    and (L, B, leaf.ndim) start indices: one scatter of L * B rows."""
+    nd = leaf.ndim
+    dnums = jax.lax.ScatterDimensionNumbers(
+        update_window_dims=(2,), inserted_window_dims=tuple(range(nd - 1)),
+        scatter_dims_to_operand_dims=tuple(range(nd)))
+    return jax.lax.scatter(leaf, at, rows.astype(leaf.dtype), dnums,
+                           mode=jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS)
+
+
+def write_latent(cache: Params, rows) -> Params:
     """Write one decode step's latent rows (L, B, rank+rope) of every
-    layer into the store at each slot's ``length``: through the page table
-    into the slot's current page (free slots' tables point at the sink page
-    0), or into the dense rectangle.  A page is one row of the pool, so no
-    dimension of the pool is the odd rank+rope: the chip's default layout
-    of such an array moves that dimension, and every read or write of the
-    pool would then copy it whole."""
+    layer at each slot's ``length``: through the page table into the
+    slot's current page of the ``lat`` and ``rope`` leaves (free slots'
+    tables point at the sink page 0), or into the dense rectangle.
+    Returns the written leaves."""
     length = cache["length"]
-    rows = rows.astype(store.dtype)
-    if "page_table" in cache:
-        L, B, c = rows.shape
-        psz = store.shape[2] // c
-        phys = jnp.take_along_axis(cache["page_table"],
-                                   (length // psz)[:, None], axis=1)[:, 0]
-        at = jnp.stack([
-            jnp.broadcast_to(jnp.arange(L, dtype=phys.dtype)[:, None], (L, B)),
-            jnp.broadcast_to(phys, (L, B)),
-            jnp.broadcast_to(length % psz * c, (L, B))], axis=-1)
-        dnums = jax.lax.ScatterDimensionNumbers(
-            update_window_dims=(2,), inserted_window_dims=(0, 1),
-            scatter_dims_to_operand_dims=(0, 1, 2))
-        return jax.lax.scatter(
-            store, at, rows, dnums,
-            mode=jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS)
-    return store.at[:, jnp.arange(rows.shape[1]), length].set(rows)
+    if "page_table" not in cache:
+        lat = cache["lat"]
+        return {"lat": lat.at[:, jnp.arange(rows.shape[1]), length].set(
+            rows.astype(lat.dtype))}
+    lat, rope = cache["lat"], cache["rope"]
+    L, B, _ = rows.shape
+    r, psz, lanes = lat.shape[-1], lat.shape[2], rope.shape[-1]
+    w = rows.shape[-1] - r
+    pack = lanes // w
+    phys = jnp.take_along_axis(cache["page_table"],
+                               (length // psz)[:, None], axis=1)[:, 0]
+
+    def at(*idx):
+        return jnp.stack([jnp.broadcast_to(i, (L, B)) for i in idx], axis=-1)
+
+    layer = jnp.arange(L, dtype=phys.dtype)[:, None]
+    off, zero = length % psz, jnp.zeros_like(phys)
+    return {"lat": _scatter_rows(lat, rows[..., :r],
+                                 at(layer, phys, off, zero)),
+            "rope": _scatter_rows(rope, rows[..., r:],
+                                  at(layer, phys // pack, off,
+                                     phys % pack * w))}
 
 
 # ---------------------------------------------------------------------------

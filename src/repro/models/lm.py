@@ -306,15 +306,30 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int) -> Params:
     }
 
 
+def rope_pack(rope_dim: int) -> int:
+    """Pages whose rope parts share one 128-lane row of the latent pool's
+    ``rope`` leaf (1 where ``rope_dim`` does not divide 128)."""
+    return 128 // rope_dim if 128 % rope_dim == 0 else 1
+
+
 def init_paged_cache(cfg: ModelConfig, num_pages: int, page_size: int) -> Params:
     """KV page pool shared by all slots: per layer, ``num_pages`` fixed-size
     pages.  The serving layer owns the page tables (see
     :class:`repro.serve.kv.PagedKV`); the decode step only consumes them.
-    Latent attention keeps one pool ``lat`` of latent rows, each page one
-    row of ``page_size * (rank+rope)`` (see :func:`write_latent`)."""
+    Latent attention keeps its rows as two leaves, so that a page of each
+    is whole (sublane, lane) tiles that one DMA copies and no dimension is
+    the odd rank+rope: ``lat`` (L, P, page, rank) and ``rope`` (L, ceil(P /
+    pack), page, pack * rope), where ``pack`` pages' rope parts lie side by
+    side in a 128-lane row (page p at row p // pack, lanes (p % pack) *
+    rope on).  Together they hold exactly rank+rope values a token."""
     if cfg.mla is not None:
-        return {"lat": jnp.zeros((cfg.n_layers, num_pages,
-                                  page_size * cfg.mla.latent_dim), cfg.dtype)}
+        a = cfg.mla
+        pack = rope_pack(a.qk_rope_dim)
+        return {"lat": jnp.zeros((cfg.n_layers, num_pages, page_size,
+                                  a.kv_rank), cfg.dtype),
+                "rope": jnp.zeros((cfg.n_layers, -(-num_pages // pack),
+                                   page_size, pack * a.qk_rope_dim),
+                                  cfg.dtype)}
     shape = (cfg.n_layers, num_pages, page_size, cfg.n_kv_heads, cfg.dh)
     return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
 
@@ -444,9 +459,9 @@ def _mla_prefill(params: Params, h, cfg: ModelConfig, max_len: int,
 def _mla_decode_step(params: Params, cache: Params, tokens, cfg: ModelConfig,
                      kv_limit=None):
     """:func:`decode_step` of a latent-attention model.  Each layer reads
-    the latent store as a loop invariant (:func:`mla_decode`, at its own
-    layer index, counted across the stacks) and returns its row; one write
-    after the stacks puts every layer's row in place
+    the latent store's leaves as loop invariants (:func:`mla_decode`, at
+    its own layer index, counted across the stacks) and returns its row;
+    one write after the stacks puts every layer's row in place
     (:func:`write_latent`).  With a dropless expert share the new cache
     also carries ``moe_load`` (2,): the (token, expert) pairs routed to
     held experts, summed over layers, and the most pairs on one held
@@ -456,9 +471,10 @@ def _mla_decode_step(params: Params, cache: Params, tokens, cfg: ModelConfig,
     lengths = jnp.broadcast_to(length, (B,))
     positions = lengths[:, None] + jnp.arange(S, dtype=jnp.int32)[None]
     h = embed_tokens(params, tokens, cfg)
-    view = {"lat": cache["lat"], "length": lengths}
+    view = {name: cache[name] for name in ("lat", "rope", "page_table")
+            if name in cache}
+    view["length"] = lengths
     if "page_table" in cache:
-        view["page_table"] = cache["page_table"]
         view["kv_limit"] = kv_limit
     rows, loads = [], []
     for stack, first, n, dense in _stacks(params, cfg):
@@ -480,8 +496,7 @@ def _mla_decode_step(params: Params, cache: Params, tokens, cfg: ModelConfig,
             loads.append(load)
     h = apply_norm(params["final_norm"], h, cfg)
     logits = (h[:, -1] @ _head_matrix(params, cfg)).astype(jnp.float32)
-    new_cache = {"lat": write_latent(cache["lat"], jnp.concatenate(rows),
-                                     view),
+    new_cache = {**write_latent(view, jnp.concatenate(rows)),
                  "length": length + S}
     if "page_table" in cache:
         new_cache["page_table"] = cache["page_table"]

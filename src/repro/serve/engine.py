@@ -50,7 +50,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from repro import obs
 from repro.exec import stitch
 from repro.models.api import Model
-from repro.models.layers import MLA_DECODE_PATH, paged_attention_path
+from repro.models.layers import mla_decode_path, paged_attention_path
 
 from .kv import DenseKV, PagedKV, Prefix
 
@@ -125,7 +125,8 @@ class Engine:
         self._rid = [-1] * cfg.batch     # request id per slot, for spans
         self._kv: PagedKV | DenseKV | None = None
         self._prefix = None
-        # how the traced paged decode step attends: "kernel" | "gather"
+        # how the traced paged decode step attends: "kernel" | "gather",
+        # or "mla-kernel" | "mla-gather" for latent attention
         self._paged_attention: str | None = None
         # a dropless expert share reads its load back with the tokens
         moe = model.cfg.moe
@@ -157,7 +158,7 @@ class Engine:
         def decode_step(params, cache, tok, extra):
             if "page_table" in cache:
                 self._paged_attention = (
-                    MLA_DECODE_PATH if "lat" in cache else
+                    mla_decode_path(model.cfg, cache) if "lat" in cache else
                     paged_attention_path(model.cfg, cache["k"].shape))
             return model.decode_step(params, cache, tok, kv_limit=kvl, **extra)
 
@@ -441,17 +442,18 @@ class Engine:
         upgrade status, plan stats, call counts, cache hit rates, and every
         background-compile failure (also in pure-jit mode) — plus
         ``paged_attention``: how the traced paged step attends
-        (``"kernel"`` | ``"gather"``, or ``"mla-gather"`` for latent
-        attention, absorbed over each slot's gathered latent pages; None
-        before a paged step is traced), ``attention`` (the same for latent
-        attention, whatever the cache, else the paged path) and ``moe``
+        (``"kernel"`` | ``"gather"``, or for latent attention
+        ``"mla-kernel"`` | ``"mla-gather"``, absorbed over each slot's live
+        latent pages read in place or over its gathered view; None before a
+        paged step is traced), ``attention`` (the same, and for latent
+        attention on the dense rectangle ``"mla-gather"``) and ``moe``
         (a dropless expert share's ``{"held": [first, end), "experts": E,
         "dropless": True}``, else None)."""
         rep = self._exec.report()
         rep["paged_attention"] = self._paged_attention
         cfg = self.model.cfg
-        rep["attention"] = (MLA_DECODE_PATH if cfg.mla is not None
-                            else self._paged_attention)
+        rep["attention"] = (self._paged_attention or "mla-gather"
+                            if cfg.mla is not None else self._paged_attention)
         rep["moe"] = None
         if self._moe_share:
             e0, n = cfg.moe.held

@@ -15,15 +15,19 @@ cache hit a page-table splice — shared full pages are refcounted, only the
 partial tail page (where decode writes land) is copied per slot.
 
 Pool leaves: ``k`` and ``v`` (L, num_pages, page_size, Hkv, dh) for
-grouped-query attention; one ``lat`` (L, num_pages, page_size *
-(rank+rope)) of latent rows for latent attention, read by the decode step
-through the same page tables.
+grouped-query attention; for latent attention the rank+rope rows split in
+two, ``lat`` (L, num_pages, page_size, rank) and ``rope``, where ``pack``
+pages' rope parts share a 128-lane row (see
+:func:`repro.models.lm.init_paged_cache`), read by the decode step through
+the same page tables.  A prefill cache's latent rows are split the same
+way where they enter the pool (:func:`_pool_rows`).
 
 Numerics: on the gather path the decode step gathers each slot's pages
 into a dense view and slices it back to ``max_len`` (see ``kv_limit`` in
 the model layer), so paged serving is token-for-token — bitwise — equal to
 the dense layout.  On the kernel path (where Pallas compiles, see
-:func:`repro.models.layers.paged_attention_path`) the step reads only each
+:func:`repro.models.layers.paged_attention_path` and
+:func:`repro.models.layers.mla_decode_path`) the step reads only each
 slot's live pages through the page table, and equals the dense layout
 within float tolerance, not bitwise.
 """
@@ -39,6 +43,30 @@ import jax.numpy as jnp
 import numpy as np
 
 __all__ = ["PageAllocator", "PageExhausted", "PagedKV", "DenseKV", "Prefix"]
+
+
+def _pool_rows(pool: dict, kv: dict) -> dict:
+    """A prefill cache's rows (L, B, S, ...) under each pool leaf's name
+    (its ``length`` is left out): the latent pool keeps a prefill's
+    rank+rope rows ``lat`` as its two leaves ``lat`` and ``rope``."""
+    if "rope" not in pool:
+        return {name: kv[name] for name in pool}
+    r = pool["lat"].shape[-1]
+    return {"lat": kv["lat"][..., :r], "rope": kv["lat"][..., r:]}
+
+
+def _put_page(leaf, rows, page):
+    """Write one page's rows (L, 1, page_size, ..., w) into pool page
+    ``page`` of ``leaf``, in place where ``leaf`` is donated.  Where the
+    rows are narrower than the leaf's last dimension, ``pack`` pages lie
+    side by side along it: page p at row p // pack, from lane (p % pack) *
+    w."""
+    w = rows.shape[-1]
+    pack = leaf.shape[-1] // w
+    start = (0, page) + (0,) * (leaf.ndim - 2)
+    if pack > 1:
+        start = (0, page // pack) + start[2:-1] + (page % pack * w,)
+    return jax.lax.dynamic_update_slice(leaf, rows.astype(leaf.dtype), start)
 
 
 class PageExhausted(RuntimeError):
@@ -218,25 +246,22 @@ class PagedKV:
             def splice(pool, src, row, pages):
                 need = n * ps
                 out = {}
-                for name, leaf in pool.items():
+                for name, rows in _pool_rows(pool, src).items():
                     sel = jax.lax.dynamic_index_in_dim(
-                        src[name], row, axis=1, keepdims=False)  # (L, pb, ...)
+                        rows, row, axis=1, keepdims=False)  # (L, pb, ...)
                     pad = [(0, 0)] * sel.ndim
                     if need > pb:
                         pad[1] = (0, need - pb)
                         sel = jnp.pad(sel, pad)
                     else:
                         sel = sel[:, :need]
-                    pg = sel.reshape(sel.shape[0], n, *leaf.shape[2:]
-                                     ).astype(leaf.dtype)
-                    rest = (0,) * (leaf.ndim - 2)
+                    pg = sel.reshape(sel.shape[0], n, ps, *sel.shape[2:])
 
-                    def put(i, acc, pg=pg, rest=rest):
+                    def put(i, acc, pg=pg):
                         page = jax.lax.dynamic_slice_in_dim(pg, i, 1, axis=1)
-                        return jax.lax.dynamic_update_slice(
-                            acc, page, (0, pages[i], *rest))
+                        return _put_page(acc, page, pages[i])
 
-                    out[name] = jax.lax.fori_loop(0, n, put, leaf)
+                    out[name] = jax.lax.fori_loop(0, n, put, pool[name])
                 return out
 
             fn = jax.jit(splice, donate_argnums=(0,))
@@ -253,8 +278,8 @@ class PagedKV:
         n = max(1, math.ceil(true_len / self.page_size))
         pages = self._alloc(n)
         self.pool = self._splice_fn(self._bucket(kv), n)(
-            self.pool, {name: kv[name] for name in self.pool},
-            jnp.asarray(row, jnp.int32), jnp.asarray(pages, jnp.int32))
+            self.pool, kv, jnp.asarray(row, jnp.int32),
+            jnp.asarray(pages, jnp.int32))
         self._set_slot(slot, pages, true_len)
 
     def insert_shared(self, pages: list[int], tail, true_len: int,
@@ -268,9 +293,7 @@ class PagedKV:
             tp = self._alloc(1)[0]
             if self._tail_fn is None:
                 self._tail_fn = jax.jit(lambda pool, rows, p: {
-                    name: leaf.at[:, p].set(
-                        rows[name].reshape(leaf.shape[:1] + leaf.shape[2:])
-                        .astype(leaf.dtype))
+                    name: _put_page(leaf, rows[name][:, None], p)
                     for name, leaf in pool.items()}, donate_argnums=(0,))
             self.pool = self._tail_fn(self.pool, tail,
                                       jnp.asarray(tp, jnp.int32))
@@ -306,15 +329,15 @@ class PagedKV:
         if n_full:
             pages = self._alloc(n_full)
             self.pool = self._splice_fn(pb, n_full)(
-                self.pool, {name: kv[name] for name in self.pool},
-                jnp.asarray(row, jnp.int32), jnp.asarray(pages, jnp.int32))
+                self.pool, kv, jnp.asarray(row, jnp.int32),
+                jnp.asarray(pages, jnp.int32))
         tail = None
         if true_len % ps:
             lo = n_full * ps
             pad = max(0, lo + ps - pb)
             tail = {}
-            for name in self.pool:
-                t = kv[name][:, row, lo:lo + ps]
+            for name, rows in _pool_rows(self.pool, kv).items():
+                t = rows[:, row, lo:lo + ps]
                 if pad:
                     t = jnp.pad(t, [(0, 0), (0, pad)] + [(0, 0)] * (t.ndim - 2))
                 tail[name] = t

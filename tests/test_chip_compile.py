@@ -127,19 +127,16 @@ def _decode_graph():
     return g
 
 
-def _deepseek_decode_graph():
-    """The paged latent decode step of DeepSeek-V2-Lite at published
-    widths, one chip's 16 of 64 experts, at 48 slots: its slot vectors of
-    48 rows and its scalar masks are where the chip refused stitched
-    groups (a rank-1 block of 8 rows; a bool scalar input).  Depth is cut
-    to the dense layer and two MoE layers (each stack is one scan)."""
-    from repro.models import build_model
+DS_SLOTS, DS_MAX_LEN = 48, 2048       # the DeepSeek cell's serving shape
+
+
+def _deepseek_cfg(n_layers: int = 3):
+    """DeepSeek-V2-Lite at published widths, one chip's 16 of 64 experts."""
     from repro.models.config import (MLAConfig, ModelConfig, MoEConfig,
                                      YarnConfig)
 
-    slots, max_len = 48, 2048
-    cfg = ModelConfig(
-        name="deepseek-v2-lite", family="moe", n_layers=3, d_model=2048,
+    return ModelConfig(
+        name="deepseek-v2-lite", family="moe", n_layers=n_layers, d_model=2048,
         n_heads=16, n_kv_heads=16, d_ff=10944, vocab=102400, head_dim=192,
         n_dense_layers=1, tie_embeddings=False,
         mla=MLAConfig(kv_rank=512, qk_nope_dim=128, qk_rope_dim=64,
@@ -149,17 +146,39 @@ def _deepseek_decode_graph():
         moe=MoEConfig(n_experts=64, top_k=6, d_expert=1408, n_shared=2,
                       d_shared=2816, renormalize=False, held=(0, 16)),
         dtype="bfloat16", param_dtype="bfloat16")
-    model = build_model(cfg)
+
+
+def _deepseek_pool(model):
+    return jax.eval_shape(lambda: model.init_paged_cache(
+        DS_SLOTS * (DS_MAX_LEN // PAGE) + 1, PAGE))
+
+
+def _deepseek_decode_step():
+    """The paged latent decode step of DeepSeek-V2-Lite at 48 slots and its
+    abstract arguments.  Depth is cut to the dense layer and two MoE layers
+    (each stack is one scan)."""
+    from repro.models import build_model
+
+    model = build_model(_deepseek_cfg())
     params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
-    pps = max_len // PAGE
-    cache = dict(jax.eval_shape(
-        lambda: model.init_paged_cache(slots * pps + 1, PAGE)),
-        page_table=jax.ShapeDtypeStruct((slots, pps), jnp.int32),
-        length=jax.ShapeDtypeStruct((slots,), jnp.int32))
-    tok = jax.ShapeDtypeStruct((slots, 1), jnp.int32)
-    g, _ = trace_to_graph(
-        lambda p, c, t: model.decode_step(p, c, t, kv_limit=max_len),
-        params, cache, tok, name="decode")
+    pps = DS_MAX_LEN // PAGE
+    cache = dict(_deepseek_pool(model),
+                 page_table=jax.ShapeDtypeStruct((DS_SLOTS, pps), jnp.int32),
+                 length=jax.ShapeDtypeStruct((DS_SLOTS,), jnp.int32))
+    tok = jax.ShapeDtypeStruct((DS_SLOTS, 1), jnp.int32)
+
+    def decode_step(params, cache, tok):
+        return model.decode_step(params, cache, tok, kv_limit=DS_MAX_LEN)
+
+    return decode_step, (params, cache, tok)
+
+
+def _deepseek_decode_graph():
+    """The DeepSeek decode step's graph: its slot vectors of 48 rows and
+    its scalar masks are where the chip refused stitched groups (a rank-1
+    block of 8 rows; a bool scalar input)."""
+    fn, args = _deepseek_decode_step()
+    g, _ = trace_to_graph(fn, *args, name="decode")
     return g
 
 
@@ -187,6 +206,53 @@ def test_paged_decode_step_compiles(compiled):
     text = compiled(fn, *args)          # the kernel is its only Mosaic call
     page = r"bf16\[\d+,%d,8,128\]\{[^}]*\}" % PAGE
     assert not re.search(page + r" gather\(", text), "pages still gathered"
+
+
+def test_latent_decode_step_compiles(compiled):
+    """The whole DeepSeek decode step compiles with the paged latent kernel
+    inside each layer scan, and keeps no gather of the latent pages."""
+    from repro.models.layers import mla_decode_path
+
+    fn, args = _deepseek_decode_step()
+    assert mla_decode_path(_deepseek_cfg(), args[1]) == "mla-kernel"
+    text = compiled(fn, *args)
+    # the kernel's o_lat (slots, heads, rank) is a Mosaic call's output
+    assert re.search(r"bf16\[%d,16,512\][^\n]*tpu_custom_call" % DS_SLOTS,
+                     text), "no paged latent kernel in the step"
+    page = r"bf16\[\d+,%d,(512|128)\]\{[^}]*\}" % PAGE
+    assert not re.search(page + r" gather\(", text), "pages still gathered"
+
+
+def test_paged_latent_decode_attention_compiles(compiled):
+    """The kernel at the DeepSeek cell's shapes: 48 slots of 2048
+    positions, page 16, 16 heads, rank 512 + rope 64, 27 layers."""
+    from repro.kernels.latent_attention import paged_latent_decode_attention
+
+    n = DS_MAX_LEN // PAGE
+    P = DS_SLOTS * n + 1
+    bf16 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+    compiled(lambda *a: paged_latent_decode_attention(*a, scale=0.1147),
+             bf16(DS_SLOTS, 16, 512), bf16(DS_SLOTS, 16, 64),
+             bf16(27, P, PAGE, 512), bf16(27, -(-P // 2), PAGE, 128), i32(),
+             i32(DS_SLOTS, n), i32(DS_SLOTS), bf16(DS_SLOTS, 576))
+
+
+def test_latent_pool_bytes_per_token(one_chip):
+    """On the chip the paged latent pool holds 576 bf16 values a token and
+    layer, 31,104 B a token over 27 layers, unpadded: as device arguments
+    its two leaves take that many bytes per pool position, within 0.1%
+    (the rope leaf rounds the page count up to whole pairs)."""
+    from repro.models import build_model
+
+    pool = _deepseek_pool(build_model(_deepseek_cfg(27)))
+    args = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip), pool)
+    mem = jax.jit(lambda pool: {k: v[0, 0, 0, 0] for k, v in pool.items()}
+                  ).lower(args).compile().memory_analysis()
+    positions = pool["lat"].shape[1] * PAGE
+    assert mem.argument_size_in_bytes / positions == pytest.approx(
+        31104, rel=1e-3)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])

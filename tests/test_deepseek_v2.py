@@ -18,6 +18,8 @@ absorbed against expanded decode) agree to 1e-5.
 from __future__ import annotations
 
 import dataclasses
+import hashlib
+import json
 import sys
 from pathlib import Path
 
@@ -34,11 +36,13 @@ import arch  # noqa: E402
 import weights  # noqa: E402
 from reference import deepseek_v2 as ref  # noqa: E402
 
-from repro import obs  # noqa: E402
+from repro import kernels, obs  # noqa: E402
 from repro.cache import CompilationService, StitchCache  # noqa: E402
+from repro.configs import get_reduced  # noqa: E402
 from repro.models import build_model  # noqa: E402
 from repro.models import layers, lm  # noqa: E402
 from repro.serve import Engine, ServeConfig  # noqa: E402
+from repro.serve.kv import PagedKV  # noqa: E402
 
 YARN = {"type": "yarn", "factor": 40, "original_max_position_embeddings": 64,
         "beta_fast": 32, "beta_slow": 1, "mscale": 0.707,
@@ -376,3 +380,101 @@ def test_loss_and_gradients_match_reference():
         scale = float(jnp.max(jnp.abs(b)))
         np.testing.assert_allclose(a, b, rtol=0, atol=1e-3 * scale,
                                    err_msg=jax.tree_util.keystr(path))
+
+
+def test_latent_decode_path_follows_platform_and_pool(monkeypatch):
+    """The paged latent kernel where Pallas compiles and the published
+    widths tile its pages; the gather where Pallas interprets (the CPU),
+    where the pages do not tile (the small config), and over the dense
+    rectangle."""
+    pub = json.loads((CHIP / "configs" / "deepseek-v2-lite-ep4.json")
+                     .read_text())
+    big = arch.load(pub).program(pub, "t")
+    small = _program(TINY).cfg
+    pool = lambda cfg, page: jax.eval_shape(
+        lambda: lm.init_paged_cache(cfg, 6145, page))
+    dense = jax.eval_shape(lambda: lm.init_cache(big, 48, 2048))
+    assert {k: v.shape for k, v in pool(big, 16).items()} == {
+        "lat": (27, 6145, 16, 512), "rope": (27, 3073, 16, 128)}
+    assert layers.mla_decode_path(big, pool(big, 16)) == "mla-gather"
+    monkeypatch.setattr(kernels, "interpret_mode", lambda: False)
+    assert layers.mla_decode_path(big, pool(big, 16)) == "mla-kernel"
+    assert layers.mla_decode_path(big, dense) == "mla-gather"
+    assert layers.mla_decode_path(small, pool(small, 8)) == "mla-gather"
+
+
+def _slot_rows(kv: PagedKV, slot: int, n: int, rope: int) -> np.ndarray:
+    """The first ``n`` latent rows (L, n, rank+rope) of ``slot``, read back
+    from the pool's two leaves through the page table."""
+    lat, pe = np.asarray(kv.pool["lat"]), np.asarray(kv.pool["rope"])
+    pack, ps = pe.shape[-1] // rope, kv.page_size
+    out = []
+    for t in range(n):
+        p, o = int(kv.table[slot, t // ps]), t % ps
+        g = p % pack * rope
+        out.append(np.concatenate([lat[:, p, o], pe[:, p // pack, o, g:g + rope]],
+                                  axis=-1))
+    return np.stack(out, axis=1)
+
+
+def test_insert_and_prefix_tail_round_trip_the_latent_leaves():
+    """A prefill's latent rows, spliced into private pages, or into shared
+    full pages plus a copied tail page, read back from ``lat`` and the
+    packed ``rope`` leaf as they were written; the other slot's pages are
+    left as they were."""
+    cfg = TINY
+    model = _program(cfg)
+    w = weights.make(cfg, 10)
+    rope = cfg["qk_rope_head_dim"]
+    kv = PagedKV(model, 3, 64, 8)
+    toks = np.random.default_rng(14).integers(0, 256, (2, 21)).astype(np.int32)
+    _, pre = model.prefill(w, jnp.asarray(toks), max_len=32)
+    kv.allocator.alloc(5)                  # slot pages start mid-row
+    kv.insert_kv(pre, 0, 21, 0)
+    want0 = np.asarray(pre["lat"][:, 0, :21])
+    np.testing.assert_array_equal(_slot_rows(kv, 0, 21, rope), want0)
+    pages, tail = kv.materialize_prefix(pre, 1, 21)
+    assert len(pages) == 2
+    assert {k: v.shape for k, v in tail.items()} == {
+        "lat": (4, 8, 32), "rope": (4, 8, rope)}
+    kv.insert_shared(pages, tail, 21, 2)
+    np.testing.assert_array_equal(_slot_rows(kv, 2, 21, rope),
+                                  np.asarray(pre["lat"][:, 1, :21]))
+    np.testing.assert_array_equal(_slot_rows(kv, 0, 21, rope), want0)
+
+
+# sha256 (first 16 hex digits) of the lowered text of the dense family's
+# paged decode, prefill and training steps at reduced size, as the program
+# lowered them before the paged latent kernel: latent attention must leave
+# the grouped-query path alone.  A change meant for the dense family
+# updates these pins.
+DENSE_HLO = {
+    "qwen3_1_7b": {"decode": "3d6cb545b80b8fc6", "prefill": "79aeb514e6bfdab0",
+                   "train": "f6d4f02270ece95c"},
+    "qwen2_moe_a2_7b": {"decode": "e9b40a2d1a2ca9f3",
+                        "prefill": "7c1dec06fdf47ef2",
+                        "train": "10031b4b1ecfa103"},
+}
+
+
+@pytest.mark.parametrize("name", sorted(DENSE_HLO))
+def test_dense_family_lowering_unchanged(name):
+    model = build_model(get_reduced(name))
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    B, S, L, page = 2, 16, 32, 8
+    tok = jax.ShapeDtypeStruct((B, S), jnp.int32)
+    cache = dict(jax.eval_shape(
+        lambda: model.init_paged_cache(B * L // page + 1, page)),
+        page_table=jax.ShapeDtypeStruct((B, L // page), jnp.int32),
+        length=jax.ShapeDtypeStruct((B,), jnp.int32))
+    steps = {
+        "decode": (lambda p, c, t: model.decode_step(p, c, t, kv_limit=L),
+                   (params, cache, jax.ShapeDtypeStruct((B, 1), jnp.int32))),
+        "prefill": (lambda p, t, n: model.prefill(p, t, max_len=L, true_len=n),
+                    (params, tok, jax.ShapeDtypeStruct((B,), jnp.int32))),
+        "train": (jax.grad(lambda p, b: model.train_forward(p, b)[0]),
+                  (params, {"tokens": tok, "labels": tok})),
+    }
+    got = {k: hashlib.sha256(jax.jit(f).lower(*a).as_text().encode())
+           .hexdigest()[:16] for k, (f, a) in steps.items()}
+    assert got == DENSE_HLO[name]
