@@ -267,7 +267,7 @@ def apply_attention(
             pv = cache["v"].at[phys, off].set(vw)
             new_cache = {"k": pk, "v": pv, "length": length + S}
             if "pool" in cache:
-                # the kernel path (see paged_attention_path): read each
+                # the kernel path (see attention_path): read each
                 # slot's live pages of the stacked pool as the step received
                 # it, this token's row folded in from kw/vw
                 pool_k, pool_v, layer = cache["pool"]
@@ -345,29 +345,6 @@ def apply_attention(
                             positions_q=positions)
     out = out.reshape(B, S, Hq * dh) @ p["wo"].astype(dt)
     return out, new_cache
-
-
-def paged_attention_path(cfg: ModelConfig, pool_shape) -> str:
-    """How the paged decode step attends over a stacked pool (L, P, page,
-    Hkv, dh): ``"kernel"`` reads each slot's live pages through the page
-    table (:func:`repro.kernels.decode_attention.paged_decode_attention`),
-    where Pallas compiles and the shape tiles; ``"gather"`` gathers every
-    slot's whole view and masks it, bitwise equal to the dense layout."""
-    if _decode.paged_kernel_platform() and \
-            _decode.paged_decode_fits(pool_shape, cfg.n_heads):
-        return "kernel"
-    return "gather"
-
-
-def init_kv_cache(cfg: ModelConfig, batch: int, max_len: int, n_layers: int,
-                  dtype=None):
-    dtype = dtype or cfg.dtype
-    shape = (n_layers, batch, max_len, cfg.n_kv_heads, cfg.dh)
-    return {
-        "k": jnp.zeros(shape, dtype),
-        "v": jnp.zeros(shape, dtype),
-        "length": jnp.zeros((), jnp.int32),
-    }
 
 
 # ---------------------------------------------------------------------------
@@ -467,21 +444,6 @@ def apply_mla(p: Params, x, cfg: ModelConfig, positions):
     return out.reshape(B, S, H * a.v_dim) @ p["wo"].astype(dt), lat
 
 
-def mla_decode_path(cfg: ModelConfig, pool) -> str:
-    """How the latent decode step attends over the latent store ``pool``
-    (a cache dict): ``"mla-kernel"`` reads each slot's live pages of the
-    paged pool's ``lat`` and ``rope`` leaves in place
-    (:func:`repro.kernels.latent_attention.paged_latent_decode_attention`),
-    where Pallas compiles and the pages tile; ``"mla-gather"`` gathers every
-    slot's whole view (or reads the dense rectangle) and masks it, bitwise
-    equal to the dense layout."""
-    if "rope" in pool and _decode.paged_kernel_platform() and \
-            _latent.paged_latent_fits(pool["lat"].shape, pool["rope"].shape,
-                                      cfg.n_heads):
-        return "mla-kernel"
-    return "mla-gather"
-
-
 def _latent_view(cache: Params, layer, rope_dim: int):
     """Every slot's whole latent view (B, n * page, rank+rope) at ``layer``
     of the paged pool, gathered through the page table: ``lat`` by (layer,
@@ -539,9 +501,9 @@ def mla_decode(p: Params, x, cfg: ModelConfig, positions, cache: Params,
     The cache holds every layer's latent store, read at ``layer`` (a traced
     index) before this step's rows are written: a page pool of two leaves,
     ``lat`` (L, P, page, rank) and ``rope`` (L, P', page, lanes) (see
-    :func:`repro.models.lm.init_paged_cache`), read through
+    :meth:`LatentAttention.init_paged_cache`), read through
     ``cache["page_table"]``; or a dense rectangle ``lat`` (L, B, max_len,
-    rank+rope).  On the kernel path (:func:`mla_decode_path`) each slot's
+    rank+rope).  On the kernel path (:func:`attention_path`) each slot's
     live pages are read in place and the kernel folds this token's row in;
     otherwise :func:`latent_gather_attention`.  Returns (out (B, 1, D), the
     row (B, rank+rope) that the step writes)."""
@@ -555,7 +517,7 @@ def mla_decode(p: Params, x, cfg: ModelConfig, positions, cache: Params,
     wkv_b = p["wkv_b"].astype(dt).reshape(r, H, a.qk_nope_dim + a.v_dim)
     q_lat = jnp.einsum("bhn,rhn->bhr", q_nope[:, 0], wkv_b[..., :a.qk_nope_dim],
                        preferred_element_type=jnp.float32).astype(dt)
-    if mla_decode_path(cfg, cache) == "mla-kernel":
+    if attention_path(cfg, cache) == "mla-kernel":
         o_lat = _latent.paged_latent_decode_attention(
             q_lat, q_pe[:, 0], cache["lat"], cache["rope"], layer,
             cache["page_table"], cache["length"], row, scale=mla_scale(cfg))
@@ -607,6 +569,174 @@ def write_latent(cache: Params, rows) -> Params:
             "rope": _scatter_rows(rope, rows[..., r:],
                                   at(layer, phys // pack, off,
                                      phys % pack * w))}
+
+
+# ---------------------------------------------------------------------------
+# attention kinds: what repro.models.lm's one layer walk leaves to the kind —
+# the attention, what a decode layer reads from xs and returns in ys, and
+# how the cache is made and written
+# ---------------------------------------------------------------------------
+
+def rope_pack(rope_dim: int) -> int:
+    """Pages whose rope parts share one 128-lane row of the latent pool's
+    ``rope`` leaf (1 where ``rope_dim`` does not divide 128)."""
+    return 128 // rope_dim if 128 % rope_dim == 0 else 1
+
+
+class GroupedQueryAttention:
+    """:func:`apply_attention`, caching each layer's post-RoPE ``k`` and
+    ``v``: (L, B, max_len, Hkv, dh), or pages (L, P, page, Hkv, dh).  A
+    decode layer takes its layer of both from xs, writes its token there
+    and returns them in ys: the scan restacks the cache."""
+
+    live_pages = "kv_live_pages"          # the serve.decode span's count
+    init = staticmethod(init_attention)
+
+    def forward(self, p, x, cfg, positions, rows: bool):
+        """Full sequence: (out, ``{"k", "v"}`` where ``rows``, else None)."""
+        return apply_attention(p, x, cfg, positions, return_kv=rows)
+
+    def seq_ys(self, rows, aux):
+        """A full-sequence layer's ys: its rows in prefill, aux in train."""
+        return (rows, None) if rows is not None else (None, aux)
+
+    def init_cache(self, cfg, batch: int, max_len: int) -> Params:
+        # the rectangle is the pool's layout: a page of max_len a slot
+        return {**self.init_paged_cache(cfg, batch, max_len),
+                "length": jnp.zeros((), jnp.int32)}
+
+    def init_paged_cache(self, cfg, num_pages: int, page_size: int) -> Params:
+        shape = (cfg.n_layers, num_pages, page_size, cfg.n_kv_heads, cfg.dh)
+        return {"k": jnp.zeros(shape, cfg.dtype),
+                "v": jnp.zeros(shape, cfg.dtype)}
+
+    def path(self, cfg, cache) -> str | None:
+        """``"kernel"`` reads each slot's live pages through the page table
+        (:func:`repro.kernels.decode_attention.paged_decode_attention`),
+        where Pallas compiles and the pool tiles; ``"gather"`` gathers every
+        slot's whole view and masks it, bitwise equal to the dense layout;
+        None over the dense rectangle."""
+        if cache is None or "page_table" not in cache:
+            return None
+        if _decode.paged_kernel_platform() and \
+                _decode.paged_decode_fits(cache["k"].shape, cfg.n_heads):
+            return "kernel"
+        return "gather"
+
+    def positions(self, cache: Params, B: int, S: int):
+        """(positions (B, S), length): a scalar ``length`` is the lock-step
+        batch, a (B,) one the ragged batch."""
+        length = cache["length"]
+        base = length[:, None] if jnp.ndim(length) else length
+        return base + jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32),
+                                       (B, S)), length
+
+    def decode_xs(self, cfg, view: Params, first: int, n: int):
+        """Layers [first, first + n) of k and v; on the kernel path also
+        their indices, at which each reads the stacked pool, a loop
+        invariant."""
+        xs = (view["k"][first:first + n], view["v"][first:first + n])
+        if self.path(cfg, view) == "kernel":
+            xs += (jnp.arange(first, first + n, dtype=jnp.int32),)
+        return xs
+
+    def decode(self, p, x, cfg, positions, view: Params, xs):
+        """One layer's token: (out, its written ``{"k", "v"}``)."""
+        ck, cv, *layer = xs
+        cache = {**view, "k": ck, "v": cv}
+        if layer:
+            cache["pool"] = (view["k"], view["v"], layer[0])
+        out, new = apply_attention(p, x, cfg, positions, cache=cache)
+        return out, {"k": new["k"], "v": new["v"]}
+
+    def write(self, view: Params, rows) -> Params:
+        return rows                       # each layer wrote its own
+
+
+class LatentAttention:
+    """:func:`apply_mla` and :func:`mla_decode` (DeepSeek-V2), caching each
+    token's rank+rope row: ``lat`` (L, B, max_len, rank+rope), or pages
+    (:meth:`init_paged_cache`).  A decode layer reads the whole cache, a
+    loop invariant, at its layer index from xs and returns its row in ys;
+    one write after the walk puts the rows in place."""
+
+    live_pages = "kv_latent_pages"
+    init = staticmethod(init_mla)
+
+    def forward(self, p, x, cfg, positions, rows: bool):
+        """Full sequence: (out, ``{"lat"}``), kept or not."""
+        out, lat = apply_mla(p, x, cfg, positions)
+        return out, {"lat": lat}
+
+    def seq_ys(self, rows, aux):
+        """Both, in train and prefill alike (jit drops the unused one)."""
+        return rows, aux
+
+    def init_cache(self, cfg, batch: int, max_len: int) -> Params:
+        return {"lat": jnp.zeros((cfg.n_layers, batch, max_len,
+                                  cfg.mla.latent_dim), cfg.dtype),
+                "length": jnp.zeros((), jnp.int32)}
+
+    def init_paged_cache(self, cfg, num_pages: int, page_size: int) -> Params:
+        """Two leaves, so that a page of each is whole (sublane, lane)
+        tiles that one DMA copies and no dimension is the odd rank+rope:
+        ``lat`` (L, P, page, rank) and ``rope`` (L, ceil(P / pack), page,
+        pack * rope), where ``pack`` pages' rope parts lie side by side in a
+        128-lane row (page p at row p // pack, lanes (p % pack) * rope on).
+        Together they hold exactly rank+rope values a token."""
+        a = cfg.mla
+        pack = rope_pack(a.qk_rope_dim)
+        return {"lat": jnp.zeros((cfg.n_layers, num_pages, page_size,
+                                  a.kv_rank), cfg.dtype),
+                "rope": jnp.zeros((cfg.n_layers, -(-num_pages // pack),
+                                   page_size, pack * a.qk_rope_dim),
+                                  cfg.dtype)}
+
+    def path(self, cfg, cache) -> str:
+        """``"mla-kernel"`` reads each slot's live pages of the pool's
+        ``lat`` and ``rope`` leaves in place
+        (:func:`repro.kernels.latent_attention.paged_latent_decode_attention`),
+        where Pallas compiles and the pages tile; ``"mla-gather"`` gathers
+        every slot's whole view (or reads the dense rectangle) and masks
+        it, bitwise equal to the dense layout."""
+        if cache is not None and "rope" in cache and \
+                _decode.paged_kernel_platform() and \
+                _latent.paged_latent_fits(cache["lat"].shape,
+                                          cache["rope"].shape, cfg.n_heads):
+            return "mla-kernel"
+        return "mla-gather"
+
+    def positions(self, cache: Params, B: int, S: int):
+        """(positions (B, S), length), one length per slot."""
+        lengths = jnp.broadcast_to(cache["length"], (B,))
+        return lengths[:, None] + jnp.arange(S, dtype=jnp.int32)[None], lengths
+
+    def decode_xs(self, cfg, view: Params, first: int, n: int):
+        return (jnp.arange(first, first + n, dtype=jnp.int32),)
+
+    def decode(self, p, x, cfg, positions, view: Params, xs):
+        """One layer's token: (out, its row (B, rank+rope))."""
+        return mla_decode(p, x, cfg, positions, view, xs[0])
+
+    def write(self, view: Params, rows) -> Params:
+        return write_latent(view, rows)
+
+
+_KINDS = (GroupedQueryAttention(), LatentAttention())
+
+
+def attention_kind(cfg: ModelConfig):
+    """The decoder's attention kind: latent where ``cfg.mla`` is set, else
+    grouped-query."""
+    return _KINDS[cfg.mla is not None]
+
+
+def attention_path(cfg: ModelConfig, cache: Params | None = None):
+    """How the decode step attends over ``cache`` (None: the dense
+    rectangle), as the platform and the pool's shapes decide:
+    ``"kernel"`` | ``"gather"`` (grouped-query over a page pool, None over
+    the rectangle) or ``"mla-kernel"`` | ``"mla-gather"`` (latent)."""
+    return attention_kind(cfg).path(cfg, cache)
 
 
 # ---------------------------------------------------------------------------

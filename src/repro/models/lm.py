@@ -1,42 +1,39 @@
 """Decoder-only transformer LM — dense, MoE, and VLM-backbone families.
 
 Layers are scanned (``jax.lax.scan`` over stacked parameters) so HLO size is
-O(1) in depth; remat policy comes from the config.  The same forward serves:
+O(1) in depth; remat policy comes from the config.  One layer body
+(:func:`_block`) and one walk over the layer stacks (:func:`_walk`) serve:
 
 * ``train_forward``  — full-sequence causal, returns mean-token CE loss;
 * ``prefill``        — full-sequence causal, fills a KV cache;
 * ``decode_step``    — single-token step against a static-shape KV cache.
+
+What differs between grouped-query and latent attention (the attention,
+what a decode layer reads from xs and returns in ys, how the cache is made
+and written) sits behind one seam, :func:`repro.models.layers.attention_kind`.
 """
 
 from __future__ import annotations
 
 import functools
 import math
-from typing import Any
 
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import ops
 from .config import ModelConfig
 from .layers import (
     Params,
     scan_or_unroll,
-    apply_attention,
-    apply_mla,
     apply_mlp,
     apply_moe,
     apply_moe_dense,
     apply_moe_share,
     apply_norm,
-    init_attention,
-    init_mla,
+    attention_kind,
     init_mlp,
     init_moe,
     init_norm,
-    mla_decode,
-    paged_attention_path,
-    write_latent,
 )
 
 
@@ -47,17 +44,12 @@ from .layers import (
 def init_layer(key, cfg: ModelConfig, dense: bool = False) -> Params:
     """One layer; ``dense`` gives a MoE model's leading dense-MLP layer."""
     k1, k2, k3, k4 = jax.random.split(key, 4)
-    p = {
+    return {
         "norm1": init_norm(k1, cfg),
-        "attn": (init_mla(k2, cfg) if cfg.mla is not None
-                 else init_attention(k2, cfg)),
+        "attn": attention_kind(cfg).init(k2, cfg),
         "norm2": init_norm(k3, cfg),
+        "mlp": init_moe(k4, cfg) if _routed(cfg, dense) else init_mlp(k4, cfg),
     }
-    if cfg.family == "moe" and not dense:
-        p["mlp"] = init_moe(k4, cfg)
-    else:
-        p["mlp"] = init_mlp(k4, cfg)
-    return p
 
 
 def init_params(cfg: ModelConfig, key) -> Params:
@@ -82,7 +74,7 @@ def init_params(cfg: ModelConfig, key) -> Params:
 
 
 # ---------------------------------------------------------------------------
-# forward
+# the layer body and the walk over the stacks
 # ---------------------------------------------------------------------------
 
 def _empty_aux():
@@ -90,22 +82,42 @@ def _empty_aux():
             "moe_drop_frac": jnp.zeros((), jnp.float32)}
 
 
-def _layer_fn(h, lp, cfg: ModelConfig, positions):
-    if cfg.shard_activations:
-        from .sharding import hint_rows
-        h = hint_rows(h)
+def _routed(cfg: ModelConfig, dense: bool = False) -> bool:
+    """Whether a layer's FFN routes tokens to experts: a MoE model's, past
+    its leading dense layers."""
+    return cfg.family == "moe" and not dense
+
+
+def _ffn(p: Params, x, cfg: ModelConfig, dense: bool = False,
+         soft: bool = False):
+    """A layer's FFN on x (B, S, D): (out, aux, load (2,) or None).  The
+    MLP of a dense model or layer; else the experts: the dropless share
+    (``moe.held``), or with ``soft`` every expert on every token
+    (:func:`apply_moe_dense`, whose independent per-expert chains the
+    horizontal packer bins), or the capacity dispatch."""
+    if not _routed(cfg, dense):
+        return apply_mlp(p, x, cfg), _empty_aux(), None
+    B, S, D = x.shape
+    x2 = x.reshape(B * S, D)
+    load = None
+    if cfg.moe.held is not None:
+        y, aux, load = apply_moe_share(p, x2, cfg)
+    elif soft:
+        y, aux = apply_moe_dense(p, x2, cfg), _empty_aux()
+    else:
+        y, aux = apply_moe(p, x2, cfg)
+    return y.reshape(B, S, D), aux, load
+
+
+def _block(h, lp: Params, cfg: ModelConfig, attend, ffn):
+    """One layer: norm, ``attend(p, x) -> (out, y)``, residual, norm,
+    ``ffn(p, x) -> (out, aux, load)``, residual: (h, y, aux, load)."""
     a_in = apply_norm(lp["norm1"], h, cfg)
-    attn_out, _ = apply_attention(lp["attn"], a_in, cfg, positions)
+    attn_out, y = attend(lp["attn"], a_in)
     h = h + attn_out
     m_in = apply_norm(lp["norm2"], h, cfg)
-    if cfg.family == "moe":
-        B, S, D = m_in.shape
-        y2d, aux = apply_moe(lp["mlp"], m_in.reshape(B * S, D), cfg)
-        mlp_out = y2d.reshape(B, S, D)
-    else:
-        mlp_out = apply_mlp(lp["mlp"], m_in, cfg)
-        aux = _empty_aux()
-    return h + mlp_out, aux
+    mlp_out, aux, load = ffn(lp["mlp"], m_in)
+    return h + mlp_out, y, aux, load
 
 
 def block_forward(lp: Params, x, cfg: ModelConfig):
@@ -116,18 +128,13 @@ def block_forward(lp: Params, x, cfg: ModelConfig):
     sort-based capacity dispatch is gather/scatter-partitioned anyway, while
     the dense form exposes E independent per-expert chains for the
     horizontal packer."""
-    B, S, D = x.shape
+    B, S, _ = x.shape
     positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
-    a_in = apply_norm(lp["norm1"], x, cfg)
-    attn_out, _ = apply_attention(lp["attn"], a_in, cfg, positions)
-    x = x + attn_out
-    m_in = apply_norm(lp["norm2"], x, cfg)
-    if cfg.family == "moe":
-        y2d = apply_moe_dense(lp["mlp"], m_in.reshape(B * S, D), cfg)
-        mlp_out = y2d.reshape(B, S, D)
-    else:
-        mlp_out = apply_mlp(lp["mlp"], m_in, cfg)
-    return x + mlp_out
+    attend = functools.partial(attention_kind(cfg).forward, cfg=cfg,
+                               positions=positions, rows=False)
+    x, *_ = _block(x, lp, cfg, attend,
+                   functools.partial(_ffn, cfg=cfg, soft=True))
+    return x
 
 
 def _maybe_remat(fn, cfg: ModelConfig):
@@ -139,19 +146,60 @@ def _maybe_remat(fn, cfg: ModelConfig):
     return fn
 
 
+def _stacks(params: Params, cfg: ModelConfig):
+    """The layer stacks in order, as (params, first layer, count, dense
+    MLP): the leading dense layers, then the rest."""
+    nd = cfg.n_dense_layers
+    first = [(params["dense_layers"], 0, nd, True)] if nd else []
+    return first + [(params["layers"], nd, cfg.n_layers - nd, False)]
+
+
+def _walk(params: Params, h, cfg: ModelConfig, layer, xs=lambda first, n: (),
+          remat: bool = False):
+    """Scan ``layer(h, (lp, *x), dense) -> (h, y)`` over each stack in
+    turn, ``x`` from ``xs(first, n)``: (h, [each stack's ys])."""
+    ys = []
+    for stack, first, n, dense in _stacks(params, cfg):
+        body = functools.partial(layer, dense=dense)
+        if remat:
+            body = _maybe_remat(body, cfg)
+        h, y = scan_or_unroll(body, h, (stack, *xs(first, n)), n,
+                              cfg.scan_layers)
+        ys.append(y)
+    return h, ys
+
+
+def _cat(ys):                   # the stacks' ys, joined along the layers
+    return jax.tree.map(lambda *a: jnp.concatenate(a), *ys)
+
+
+def _sequence(params: Params, h, cfg: ModelConfig, positions, train: bool):
+    """Every layer over h (B, S, D), causal: (h before the final norm,
+    (cache rows {leaf: (L, B, S, ...)}, aux per layer)), either None where
+    the attention kind does not keep it.  Only training hints shardings."""
+    kind = attention_kind(cfg)
+    attend = functools.partial(kind.forward, cfg=cfg, positions=positions,
+                               rows=not train)
+
+    def layer(h, xs, dense):
+        (lp,) = xs
+        if train and cfg.shard_activations:
+            from .sharding import hint_rows
+            h = hint_rows(h)
+        h, rows, aux, _ = _block(h, lp, cfg, attend,
+                                 functools.partial(_ffn, cfg=cfg, dense=dense))
+        rows = jax.tree.map(lambda r: r.astype(cfg.dtype), rows)
+        return h, kind.seq_ys(rows, aux)
+
+    h, ys = _walk(params, h, cfg, layer, remat=True)
+    return h, _cat(ys)
+
+
 def backbone(params: Params, h, cfg: ModelConfig, positions):
     """h: (B, S, D) embeddings -> (B, S, D) final-normed hidden, aux."""
-    if cfg.mla is not None:
-        h, _, auxs = _mla_forward(params, h, cfg, positions)
-        h = apply_norm(params["final_norm"], h, cfg)
-        return h, jax.tree.map(jnp.mean, auxs)
-    body = _maybe_remat(
-        lambda carry, lp: _layer_fn(carry, lp, cfg, positions), cfg)
-    h, auxs = scan_or_unroll(body, h, params["layers"], cfg.n_layers,
-                             cfg.scan_layers)
+    h, (_, auxs) = _sequence(params, h, cfg, positions, train=True)
     h = apply_norm(params["final_norm"], h, cfg)
-    aux = jax.tree.map(jnp.mean, auxs)
-    return h, aux
+    return h, jax.tree.map(jnp.mean, auxs)
 
 
 def embed_tokens(params: Params, tokens, cfg: ModelConfig, patch_embeds=None):
@@ -225,7 +273,7 @@ def train_forward(params: Params, batch: dict, cfg: ModelConfig,
     h = embed_tokens(params, tokens, cfg, batch.get("patch_embeds"))
     h, aux = backbone(params, h, cfg, positions)
     loss = lm_loss(params, h, labels, cfg)
-    if cfg.family == "moe":
+    if _routed(cfg):
         loss = loss + aux_coef * aux["moe_aux"]
     return loss, aux
 
@@ -249,33 +297,13 @@ def prefill(params: Params, tokens, cfg: ModelConfig, max_len: int | None = None
     max_len = max_len or S
     positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
     h = embed_tokens(params, tokens, cfg, patch_embeds)
-    if cfg.mla is not None:
-        return _mla_prefill(params, h, cfg, max_len, positions, true_len)
-
-    def body(carry, lp):
-        x = carry
-        a_in = apply_norm(lp["norm1"], x, cfg)
-        attn_out, kv = apply_attention(lp["attn"], a_in, cfg, positions,
-                                       return_kv=True)
-        x = x + attn_out
-        m_in = apply_norm(lp["norm2"], x, cfg)
-        if cfg.family == "moe":
-            Bq, Sq, D = m_in.shape
-            y2d, _ = apply_moe(lp["mlp"], m_in.reshape(Bq * Sq, D), cfg)
-            mlp_out = y2d.reshape(Bq, Sq, D)
-        else:
-            mlp_out = apply_mlp(lp["mlp"], m_in, cfg)
-        return x + mlp_out, (kv["k"].astype(cfg.dtype), kv["v"].astype(cfg.dtype))
-
-    body = _maybe_remat(body, cfg)
-    h, (ks, vs) = scan_or_unroll(body, h, params["layers"], cfg.n_layers,
-                                 cfg.scan_layers)
+    h, (rows, _) = _sequence(params, h, cfg, positions, train=False)
     logits, length = _last_logits(params, h, cfg, true_len)
     if max_len > S:
-        pad = [(0, 0), (0, 0), (0, max_len - S), (0, 0), (0, 0)]
-        ks, vs = jnp.pad(ks, pad), jnp.pad(vs, pad)
-    cache = {"k": ks, "v": vs, "length": length}
-    return logits, cache
+        rows = jax.tree.map(lambda r: jnp.pad(
+            r, [(0, 0), (0, 0), (0, max_len - S)] + [(0, 0)] * (r.ndim - 3)),
+            rows)
+    return logits, {**rows, "length": length}
 
 
 def _last_logits(params: Params, h, cfg: ModelConfig, true_len):
@@ -294,67 +322,15 @@ def _last_logits(params: Params, h, cfg: ModelConfig, true_len):
 
 
 def init_cache(cfg: ModelConfig, batch: int, max_len: int) -> Params:
-    if cfg.mla is not None:
-        return {"lat": jnp.zeros((cfg.n_layers, batch, max_len,
-                                  cfg.mla.latent_dim), cfg.dtype),
-                "length": jnp.zeros((), jnp.int32)}
-    shape = (cfg.n_layers, batch, max_len, cfg.n_kv_heads, cfg.dh)
-    return {
-        "k": jnp.zeros(shape, cfg.dtype),
-        "v": jnp.zeros(shape, cfg.dtype),
-        "length": jnp.zeros((), jnp.int32),
-    }
-
-
-def rope_pack(rope_dim: int) -> int:
-    """Pages whose rope parts share one 128-lane row of the latent pool's
-    ``rope`` leaf (1 where ``rope_dim`` does not divide 128)."""
-    return 128 // rope_dim if 128 % rope_dim == 0 else 1
+    return attention_kind(cfg).init_cache(cfg, batch, max_len)
 
 
 def init_paged_cache(cfg: ModelConfig, num_pages: int, page_size: int) -> Params:
     """KV page pool shared by all slots: per layer, ``num_pages`` fixed-size
-    pages.  The serving layer owns the page tables (see
-    :class:`repro.serve.kv.PagedKV`); the decode step only consumes them.
-    Latent attention keeps its rows as two leaves, so that a page of each
-    is whole (sublane, lane) tiles that one DMA copies and no dimension is
-    the odd rank+rope: ``lat`` (L, P, page, rank) and ``rope`` (L, ceil(P /
-    pack), page, pack * rope), where ``pack`` pages' rope parts lie side by
-    side in a 128-lane row (page p at row p // pack, lanes (p % pack) *
-    rope on).  Together they hold exactly rank+rope values a token."""
-    if cfg.mla is not None:
-        a = cfg.mla
-        pack = rope_pack(a.qk_rope_dim)
-        return {"lat": jnp.zeros((cfg.n_layers, num_pages, page_size,
-                                  a.kv_rank), cfg.dtype),
-                "rope": jnp.zeros((cfg.n_layers, -(-num_pages // pack),
-                                   page_size, pack * a.qk_rope_dim),
-                                  cfg.dtype)}
-    shape = (cfg.n_layers, num_pages, page_size, cfg.n_kv_heads, cfg.dh)
-    return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
-
-
-def _decode_layer(h, xs, cfg: ModelConfig, positions, length,
-                  page_table=None, kv_limit=None, pool=None):
-    lp, ck, cv, *layer = xs
-    a_in = apply_norm(lp["norm1"], h, cfg)
-    layer_cache = {"k": ck, "v": cv, "length": length}
-    if page_table is not None:
-        layer_cache["page_table"] = page_table
-        layer_cache["kv_limit"] = kv_limit
-    if pool is not None:
-        layer_cache["pool"] = (*pool, layer[0])
-    attn_out, new_cache = apply_attention(lp["attn"], a_in, cfg, positions,
-                                          cache=layer_cache)
-    h = h + attn_out
-    m_in = apply_norm(lp["norm2"], h, cfg)
-    if cfg.family == "moe":
-        B, S, D = m_in.shape
-        y2d, _ = apply_moe(lp["mlp"], m_in.reshape(B * S, D), cfg)
-        mlp_out = y2d.reshape(B, S, D)
-    else:
-        mlp_out = apply_mlp(lp["mlp"], m_in, cfg)
-    return h + mlp_out, (new_cache["k"], new_cache["v"])
+    pages, in the attention kind's leaves.  The serving layer owns the page
+    tables (see :class:`repro.serve.kv.PagedKV`); the decode step only
+    consumes them."""
+    return attention_kind(cfg).init_paged_cache(cfg, num_pages, page_size)
 
 
 def decode_step(params: Params, cache: Params, tokens, cfg: ModelConfig,
@@ -362,144 +338,39 @@ def decode_step(params: Params, cache: Params, tokens, cfg: ModelConfig,
     """tokens: (B, S_new) — S_new=1 for pure decode; larger for prefill.
     Returns (logits_last, new_cache).
 
-    A cache carrying ``page_table`` selects the paged path: ``k``/``v`` are
+    A cache carrying ``page_table`` selects the paged path: its leaves are
     the shared page pool and each slot reads/writes through its page-table
-    row.  On the gather path (:func:`paged_attention_path`) ``kv_limit``
-    (python int) slices the gathered per-slot view back to the engine's
-    max_len so the attention reduction shape — and hence the tokens — match
-    the dense layout bitwise.  On the kernel path each layer reads the
-    whole stacked pool, a loop invariant, at its own layer index."""
-    if cfg.mla is not None:
-        return _mla_decode_step(params, cache, tokens, cfg, kv_limit)
+    row.  On the gather path (:func:`repro.models.layers.attention_path`)
+    ``kv_limit`` (python int) slices the gathered per-slot view back to the
+    engine's max_len so the attention reduction shape — and hence the
+    tokens — match the dense layout bitwise.  The attention kind decides
+    what each layer reads and returns and how the cache is written after
+    the walk.  With a dropless expert share the new cache also carries
+    ``moe_load`` (2,): the (token, expert) pairs routed to held experts,
+    summed over layers, and the most pairs on one held expert in one
+    layer."""
+    kind = attention_kind(cfg)
     B, S = tokens.shape
-    length = cache["length"]
-    page_table = cache.get("page_table")
-    base = length[:, None] if jnp.ndim(length) else length   # ragged: (B,) offsets
-    positions = base + jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
+    positions, length = kind.positions(cache, B, S)
+    view = {**cache, "length": length, "kv_limit": kv_limit}
     h = embed_tokens(params, tokens, cfg, patch_embeds)
 
-    xs = (params["layers"], cache["k"], cache["v"])
-    pool = None
-    if page_table is not None and \
-            paged_attention_path(cfg, cache["k"].shape) == "kernel":
-        pool = (cache["k"], cache["v"])
-        xs += (jnp.arange(cfg.n_layers, dtype=jnp.int32),)
+    def layer(h, xs, dense):
+        lp, *at = xs
+        h, row, _, load = _block(
+            h, lp, cfg, lambda p, x: kind.decode(p, x, cfg, positions, view, at),
+            functools.partial(_ffn, cfg=cfg, dense=dense))
+        return h, (row, load)
 
-    def body(carry, xs):
-        return _decode_layer(carry, xs, cfg, positions, length,
-                             page_table=page_table, kv_limit=kv_limit,
-                             pool=pool)
-
-    h, (nk, nv) = scan_or_unroll(body, h, xs, cfg.n_layers, cfg.scan_layers)
+    h, ys = _walk(params, h, cfg, layer,
+                  functools.partial(kind.decode_xs, cfg, view))
     h = apply_norm(params["final_norm"], h, cfg)
     logits = (h[:, -1] @ _head_matrix(params, cfg)).astype(jnp.float32)
-    new_cache = {"k": nk, "v": nv, "length": length + S}
-    if page_table is not None:
-        new_cache["page_table"] = page_table
-    return logits, new_cache
-
-
-# ---------------------------------------------------------------------------
-# latent attention and leading dense layers (DeepSeek-V2)
-# ---------------------------------------------------------------------------
-
-def _stacks(params: Params, cfg: ModelConfig):
-    """The layer stacks in order, as (params, first layer, count, dense
-    MLP): the leading dense layers, then the rest."""
-    nd = cfg.n_dense_layers
-    first = [(params["dense_layers"], 0, nd, True)] if nd else []
-    return first + [(params["layers"], nd, cfg.n_layers - nd, False)]
-
-
-def _ffn(p: Params, x, cfg: ModelConfig, dense: bool):
-    """A layer's MLP on x (B, S, D): (out, aux, load (2,) or None)."""
-    if dense or cfg.family != "moe":
-        return apply_mlp(p, x, cfg), _empty_aux(), None
-    B, S, D = x.shape
-    if cfg.moe.held is not None:
-        y, aux, load = apply_moe_share(p, x.reshape(B * S, D), cfg)
-    else:
-        (y, aux), load = apply_moe(p, x.reshape(B * S, D), cfg), None
-    return y.reshape(B, S, D), aux, load
-
-
-def _mla_forward(params: Params, h, cfg: ModelConfig, positions):
-    """Every stack over h (B, S, D), full-sequence: (h before the final
-    norm, latent rows (L, B, S, rank+rope), aux per layer)."""
-    lats, auxs = [], []
-    for stack, _, n, dense in _stacks(params, cfg):
-        def body(carry, lp, dense=dense):
-            a_in = apply_norm(lp["norm1"], carry, cfg)
-            attn_out, lat = apply_mla(lp["attn"], a_in, cfg, positions)
-            carry = carry + attn_out
-            m_in = apply_norm(lp["norm2"], carry, cfg)
-            mlp_out, aux, _ = _ffn(lp["mlp"], m_in, cfg, dense)
-            return carry + mlp_out, (lat.astype(cfg.dtype), aux)
-
-        h, (lat, aux) = scan_or_unroll(_maybe_remat(body, cfg), h, stack, n,
-                                       cfg.scan_layers)
-        lats.append(lat)
-        auxs.append(aux)
-    return (h, jnp.concatenate(lats),
-            jax.tree.map(lambda *a: jnp.concatenate(a), *auxs))
-
-
-def _mla_prefill(params: Params, h, cfg: ModelConfig, max_len: int,
-                 positions, true_len):
-    """:func:`prefill` of a latent-attention model: the cache holds the
-    latent rows, ``lat`` (L, B, max_len, rank+rope)."""
-    S = h.shape[1]
-    h, lat, _ = _mla_forward(params, h, cfg, positions)
-    logits, length = _last_logits(params, h, cfg, true_len)
-    if max_len > S:
-        lat = jnp.pad(lat, [(0, 0), (0, 0), (0, max_len - S), (0, 0)])
-    return logits, {"lat": lat, "length": length}
-
-
-def _mla_decode_step(params: Params, cache: Params, tokens, cfg: ModelConfig,
-                     kv_limit=None):
-    """:func:`decode_step` of a latent-attention model.  Each layer reads
-    the latent store's leaves as loop invariants (:func:`mla_decode`, at
-    its own layer index, counted across the stacks) and returns its row;
-    one write after the stacks puts every layer's row in place
-    (:func:`write_latent`).  With a dropless expert share the new cache
-    also carries ``moe_load`` (2,): the (token, expert) pairs routed to
-    held experts, summed over layers, and the most pairs on one held
-    expert in one layer."""
-    B, S = tokens.shape
-    length = cache["length"]
-    lengths = jnp.broadcast_to(length, (B,))
-    positions = lengths[:, None] + jnp.arange(S, dtype=jnp.int32)[None]
-    h = embed_tokens(params, tokens, cfg)
-    view = {name: cache[name] for name in ("lat", "rope", "page_table")
-            if name in cache}
-    view["length"] = lengths
-    if "page_table" in cache:
-        view["kv_limit"] = kv_limit
-    rows, loads = [], []
-    for stack, first, n, dense in _stacks(params, cfg):
-        def body(carry, xs, dense=dense):
-            lp, layer = xs
-            a_in = apply_norm(lp["norm1"], carry, cfg)
-            attn_out, row = mla_decode(lp["attn"], a_in, cfg, positions,
-                                       view, layer)
-            carry = carry + attn_out
-            m_in = apply_norm(lp["norm2"], carry, cfg)
-            mlp_out, _, load = _ffn(lp["mlp"], m_in, cfg, dense)
-            return carry + mlp_out, (row, load)
-
-        layers = jnp.arange(first, first + n, dtype=jnp.int32)
-        h, (row, load) = scan_or_unroll(body, h, (stack, layers), n,
-                                        cfg.scan_layers)
-        rows.append(row)
-        if load is not None:
-            loads.append(load)
-    h = apply_norm(params["final_norm"], h, cfg)
-    logits = (h[:, -1] @ _head_matrix(params, cfg)).astype(jnp.float32)
-    new_cache = {**write_latent(view, jnp.concatenate(rows)),
-                 "length": length + S}
+    new_cache = {**kind.write(view, _cat([row for row, _ in ys])),
+                 "length": cache["length"] + S}
     if "page_table" in cache:
         new_cache["page_table"] = cache["page_table"]
+    loads = [load for _, load in ys if load is not None]
     if loads:
         load = jnp.concatenate(loads)
         new_cache["moe_load"] = jnp.stack([jnp.sum(load[:, 0]),

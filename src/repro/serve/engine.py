@@ -50,7 +50,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from repro import obs
 from repro.exec import stitch
 from repro.models.api import Model
-from repro.models.layers import mla_decode_path, paged_attention_path
+from repro.models.layers import attention_kind, attention_path
 
 from .kv import DenseKV, PagedKV, Prefix
 
@@ -157,9 +157,7 @@ class Engine:
 
         def decode_step(params, cache, tok, extra):
             if "page_table" in cache:
-                self._paged_attention = (
-                    mla_decode_path(model.cfg, cache) if "lat" in cache else
-                    paged_attention_path(model.cfg, cache["k"].shape))
+                self._paged_attention = attention_path(model.cfg, cache)
             return model.decode_step(params, cache, tok, kv_limit=kvl, **extra)
 
         # eligibility covers only (cache, tok, extra): params are fixed for
@@ -338,8 +336,7 @@ class Engine:
         occ = sorted(self._occupied)
         pages = {}
         if self.paged:
-            live = ("kv_latent_pages" if self.model.cfg.mla is not None
-                    else "kv_live_pages")
+            live = attention_kind(self.model.cfg).live_pages
             pages = {live: self.kv.live_pages(occ, steps),
                      "kv_view_pages": steps * self.kv.slots
                      * self.kv.pages_per_slot}
@@ -442,18 +439,15 @@ class Engine:
         upgrade status, plan stats, call counts, cache hit rates, and every
         background-compile failure (also in pure-jit mode) — plus
         ``paged_attention``: how the traced paged step attends
-        (``"kernel"`` | ``"gather"``, or for latent attention
-        ``"mla-kernel"`` | ``"mla-gather"``, absorbed over each slot's live
-        latent pages read in place or over its gathered view; None before a
-        paged step is traced), ``attention`` (the same, and for latent
-        attention on the dense rectangle ``"mla-gather"``) and ``moe``
-        (a dropless expert share's ``{"held": [first, end), "experts": E,
-        "dropless": True}``, else None)."""
+        (:func:`repro.models.layers.attention_path`; None before a paged
+        step is traced), ``attention`` (the same, else how the dense
+        rectangle is read) and ``moe`` (a dropless expert share's
+        ``{"held": [first, end), "experts": E, "dropless": True}``, else
+        None)."""
         rep = self._exec.report()
         rep["paged_attention"] = self._paged_attention
         cfg = self.model.cfg
-        rep["attention"] = (self._paged_attention or "mla-gather"
-                            if cfg.mla is not None else self._paged_attention)
+        rep["attention"] = self._paged_attention or attention_path(cfg)
         rep["moe"] = None
         if self._moe_share:
             e0, n = cfg.moe.held

@@ -18,16 +18,15 @@ Pool leaves: ``k`` and ``v`` (L, num_pages, page_size, Hkv, dh) for
 grouped-query attention; for latent attention the rank+rope rows split in
 two, ``lat`` (L, num_pages, page_size, rank) and ``rope``, where ``pack``
 pages' rope parts share a 128-lane row (see
-:func:`repro.models.lm.init_paged_cache`), read by the decode step through
-the same page tables.  A prefill cache's latent rows are split the same
-way where they enter the pool (:func:`_pool_rows`).
+:meth:`repro.models.layers.LatentAttention.init_paged_cache`), read by the
+decode step through the same page tables.  A prefill cache's latent rows
+are split the same way where they enter the pool (:func:`_pool_rows`).
 
 Numerics: on the gather path the decode step gathers each slot's pages
 into a dense view and slices it back to ``max_len`` (see ``kv_limit`` in
 the model layer), so paged serving is token-for-token — bitwise — equal to
 the dense layout.  On the kernel path (where Pallas compiles, see
-:func:`repro.models.layers.paged_attention_path` and
-:func:`repro.models.layers.mla_decode_path`) the step reads only each
+:func:`repro.models.layers.attention_path`) the step reads only each
 slot's live pages through the page table, and equals the dense layout
 within float tolerance, not bitwise.
 """

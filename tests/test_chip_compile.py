@@ -211,10 +211,10 @@ def test_paged_decode_step_compiles(compiled):
 def test_latent_decode_step_compiles(compiled):
     """The whole DeepSeek decode step compiles with the paged latent kernel
     inside each layer scan, and keeps no gather of the latent pages."""
-    from repro.models.layers import mla_decode_path
+    from repro.models.layers import attention_path
 
     fn, args = _deepseek_decode_step()
-    assert mla_decode_path(_deepseek_cfg(), args[1]) == "mla-kernel"
+    assert attention_path(_deepseek_cfg(), args[1]) == "mla-kernel"
     text = compiled(fn, *args)
     # the kernel's o_lat (slots, heads, rank) is a Mosaic call's output
     assert re.search(r"bf16\[%d,16,512\][^\n]*tpu_custom_call" % DS_SLOTS,
@@ -303,12 +303,13 @@ def test_paged_decode_keeps_gather_at_phi3_widths(compiled):
     path for it, on the chip as elsewhere."""
     from repro.configs import get_config
     from repro.kernels.decode_attention import paged_decode_attention
-    from repro.models.layers import paged_attention_path
+    from repro.models.layers import attention_path
 
     cfg = get_config("phi3_mini_3_8b")
     avals = _paged_avals(slots=8, max_len=2048, page=16, hkv=cfg.n_kv_heads,
                          dh=cfg.dh, layers=cfg.n_layers, group=1)
-    assert paged_attention_path(cfg, avals[1].shape) == "gather"
+    pool = {"k": avals[1], "v": avals[2], "page_table": avals[4]}
+    assert attention_path(cfg, pool) == "gather"
     with pytest.raises(Exception, match="aligned to tiling"):
         compiled(paged_decode_attention, *avals)
 

@@ -396,11 +396,11 @@ def test_latent_decode_path_follows_platform_and_pool(monkeypatch):
     dense = jax.eval_shape(lambda: lm.init_cache(big, 48, 2048))
     assert {k: v.shape for k, v in pool(big, 16).items()} == {
         "lat": (27, 6145, 16, 512), "rope": (27, 3073, 16, 128)}
-    assert layers.mla_decode_path(big, pool(big, 16)) == "mla-gather"
+    assert layers.attention_path(big, pool(big, 16)) == "mla-gather"
     monkeypatch.setattr(kernels, "interpret_mode", lambda: False)
-    assert layers.mla_decode_path(big, pool(big, 16)) == "mla-kernel"
-    assert layers.mla_decode_path(big, dense) == "mla-gather"
-    assert layers.mla_decode_path(small, pool(small, 8)) == "mla-gather"
+    assert layers.attention_path(big, pool(big, 16)) == "mla-kernel"
+    assert layers.attention_path(big, dense) == "mla-gather"
+    assert layers.attention_path(small, pool(small, 8)) == "mla-gather"
 
 
 def _slot_rows(kv: PagedKV, slot: int, n: int, rope: int) -> np.ndarray:

@@ -81,6 +81,31 @@ def test_prefill_equals_decode_loop(arch):
                                rtol=2e-4, atol=2e-4)
 
 
+def test_gqa_leading_dense_layers():
+    """A grouped-query MoE model's leading dense layer runs on every walk:
+    prefill equals the token-by-token decode loop, its cache holds every
+    layer, and the dense layer's params move the logits."""
+    cfg = dataclasses.replace(get_reduced("qwen2_moe_a2_7b"), dtype="float32",
+                              n_dense_layers=1)
+    model = build_model(cfg)
+    params = model.init(KEY)
+    B, S = 1, 8
+    rng = np.random.default_rng(5)
+    toks = jnp.asarray(rng.integers(0, cfg.vocab, (B, S)), jnp.int32)
+    lg_pre, pre_cache = model.prefill(params, toks)
+    assert pre_cache["k"].shape[0] == pre_cache["v"].shape[0] == cfg.n_layers
+    cache = model.init_cache(B, S + 4)
+    for t in range(S):
+        lg_dec, cache = model.decode_step(params, cache, toks[:, t:t + 1])
+    np.testing.assert_allclose(np.asarray(lg_pre), np.asarray(lg_dec),
+                               rtol=2e-4, atol=2e-4)
+    bumped = dict(params, dense_layers=jax.tree.map(
+        lambda a: a * 1.5, params["dense_layers"]))
+    lg_bumped, _ = model.prefill(bumped, toks)
+    assert not np.allclose(np.asarray(lg_bumped), np.asarray(lg_pre),
+                           rtol=1e-3, atol=1e-3)
+
+
 @pytest.mark.parametrize("arch", ["qwen3_1_7b", "granite_moe_1b_a400m",
                                   "falcon_mamba_7b", "recurrentgemma_9b"])
 def test_pallas_mode_matches_ref_mode(arch):

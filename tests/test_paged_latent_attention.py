@@ -20,8 +20,7 @@ from repro.kernels.latent_attention import (latent_pages_per_block,
                                             paged_latent_fits)
 from repro.models import build_model
 from repro.models.config import MLAConfig
-from repro.models.layers import latent_gather_attention
-from repro.models.lm import rope_pack
+from repro.models.layers import latent_gather_attention, rope_pack
 from repro.serve.kv import PagedKV
 
 LAYERS = 2
