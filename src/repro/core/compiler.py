@@ -144,14 +144,19 @@ class _Group:
 class CompiledGraph:
     """Executable produced by :class:`StitchCompiler`.
 
-    Calling it runs the graph group-by-group (each group = one kernel):
+    Calling it walks the graph group-by-group (each group = one kernel):
     stitched groups through their Pallas callable, the rest through jnp.
+    This call is the body that :mod:`repro.exec` traces under ``jax.jit``
+    (inside ``shard_map`` on a mesh), so that a served call is one program
+    in which the fused-jnp groups become XLA fusions; called eagerly (as
+    tests do) it dispatches every group on its own.
 
-    ``launches`` and ``uploads`` count what the last call dispatched: each
+    ``launches`` and ``uploads`` count what the last walk issued: each
     Pallas callable call and each ``eval_node`` call is one launch; each
-    constant, and each input that is not already a device array, is one
-    ``jnp.asarray`` upload.  Called under a trace (a ``shard_map`` body),
-    they count the walk that was traced.
+    constant, and each input that is not already a device array (a tracer
+    counts as one), is one ``jnp.asarray`` upload.  Called under a trace,
+    they count the walk that was traced into the program, which
+    ``StitchedFunction.report()`` gives as ``walk_launches``.
     """
 
     def __init__(self, g: Graph, groups: list[_Group], stats: FusionStats):

@@ -240,11 +240,14 @@ class Graph:
 
     # -- subgraph / pattern helpers ------------------------------------------
     def external_inputs(self, members: Iterable[str]) -> list[str]:
-        """Tensors read by `members` but produced outside the set."""
+        """Tensors read by `members` but produced outside the set, in an
+        order that does not depend on the process's string hashing: a
+        stitched kernel takes its arguments in this order, and the program
+        that holds it is cached across processes by its text."""
         mset = set(members)
         ext: list[str] = []
         seen = set()
-        for m in mset:
+        for m in sorted(mset):
             for o in self.nodes[m].operands:
                 if o not in mset and o not in seen:
                     ext.append(o)

@@ -19,15 +19,20 @@ engine, and the packed optimizer.  ``stitch()`` owns all of it:
   *fails* is surfaced once as a :class:`RuntimeWarning` and in
   :meth:`StitchedFunction.report` — the fallback keeps serving, and the
   doomed compile is not re-kicked.
-* **Dispatch** is single-device or ``shard_map``, derived from the
-  partition specs: with ``mesh=`` the function is traced at *shard-local*
-  shapes (collectives inside ``fn`` trace via ``axis_env`` into executable
-  CUSTOM fusion partitions), compiled under a mesh+spec placement cache
-  key, and executed inside a jit-memoized ``shard_map`` wrapper rebuilt
-  only when an upgrade swaps the artifact.
-* **Fallback**: trace failure or a per-call shape/structure drift serves
-  that call through ``jax.jit(fn)`` (sharded when specs resolve), counted
-  in :meth:`StitchedFunction.report`.
+* **Dispatch** is always one jitted program per specialization: the
+  artifact's group walk (``CompiledGraph.__call__``) traced under
+  ``jax.jit``, so one stitched call is one launch.  Pallas groups stay the
+  plan's Pallas kernels and fused-jnp groups become XLA fusions inside the
+  program.  The program is built when the artifact is first served,
+  memoized per artifact, and rebuilt once when an upgrade swaps it.  With
+  ``mesh=`` the only difference is that the body is wrapped in
+  ``shard_map``: the function is traced at *shard-local* shapes
+  (collectives inside ``fn`` trace via ``axis_env`` into executable CUSTOM
+  fusion partitions) and compiled under a mesh+spec placement cache key.
+* **Fallback**: trace failure (of ``fn``, or of the walk under ``jax.jit``)
+  or a per-call shape/structure drift serves that call through
+  ``jax.jit(fn)`` (sharded when specs resolve), counted in
+  :meth:`StitchedFunction.report`.
 
 Modes: ``"stitch"`` executes through the artifact; ``"shadow"`` compiles
 and reports but serves jit (the serving engine's ``stitch_execute=False``);
@@ -132,7 +137,8 @@ class _Specialization:
     __slots__ = ("status", "graph", "names", "compiled", "out_tree",
                  "in_sig", "placement", "sig", "lookup_compiler",
                  "executable", "error", "warned", "sharded",
-                 "sm_in_specs", "sm_out_specs", "sm_fn", "sm_for")
+                 "sm_in_specs", "sm_out_specs", "program", "program_for",
+                 "program_builds")
 
     def __init__(self):
         self.status: str | None = None
@@ -150,8 +156,9 @@ class _Specialization:
         self.sharded = False
         self.sm_in_specs = None
         self.sm_out_specs = None
-        self.sm_fn = None
-        self.sm_for = None
+        self.program = None          # the jitted walk of ``program_for``
+        self.program_for = None
+        self.program_builds = 0
 
     @property
     def ok(self) -> bool:
@@ -392,35 +399,47 @@ class StitchedFunction:
             self._poll(self._active)
 
     # -- execution -------------------------------------------------------------
-    def _run(self, sp: _Specialization, dyn, kwargs, wsp=obs.NULL_SPAN):
-        """Execute the compiled artifact; ``wsp`` (the ``exec.walk`` span)
-        gets the dispatches the walk issued."""
+    def _build(self, sp: _Specialization, dyn, kwargs) -> bool:
+        """Make ``sp.program`` the one jitted program of the current
+        artifact: its group walk traced under ``jax.jit`` (inside
+        ``shard_map`` when sharded).  Memoized per artifact, so an upgrade
+        swap rebuilds it once.  A walk that fails to trace marks the
+        specialization an error, served by jit from then on; returns False
+        then."""
+        if sp.program_for is sp.compiled:
+            return True
+        compiled, graph = sp.compiled, sp.graph
+        names, out_tree = sp.names, sp.out_tree
+
+        def body(*args, **kw):
+            outs = compiled(dict(zip(
+                names, jax.tree_util.tree_leaves((args, kw)))))
+            flat = [outs[o] for o in graph.outputs]
+            return jax.tree_util.tree_unflatten(out_tree, flat)
+
         if sp.sharded:
-            if sp.sm_for is not sp.compiled:
-                compiled, graph = sp.compiled, sp.graph
-                names, out_tree = sp.names, sp.out_tree
+            body = jax.shard_map(body, mesh=self.mesh,
+                                 in_specs=sp.sm_in_specs,
+                                 out_specs=sp.sm_out_specs, check_vma=False)
+        program = jax.jit(body)
+        try:
+            # traces the walk now (the call reuses this trace), so that a
+            # walk jit cannot trace degrades like a failed trace of ``fn``
+            program.trace(*dyn, **kwargs)
+        except Exception as e:              # noqa: BLE001 — degrade to jit
+            sp.status = "error"
+            sp.error = f"{type(e).__name__}: {e}"
+            sp.executable = False
+            return False
+        sp.program, sp.program_for = program, compiled
+        sp.program_builds += 1
+        return True
 
-                def body(*local_args):
-                    env = dict(zip(names,
-                                   jax.tree_util.tree_leaves(local_args)))
-                    outs = compiled(env)
-                    flat = [outs[o] for o in graph.outputs]
-                    return jax.tree_util.tree_unflatten(out_tree, flat)
-
-                # memoized per artifact: steady state is a jit-cache hit,
-                # an upgrade swap rebuilds once
-                sp.sm_fn = jax.jit(jax.shard_map(
-                    body, mesh=self.mesh, in_specs=sp.sm_in_specs,
-                    out_specs=sp.sm_out_specs, check_vma=False))
-                sp.sm_for = sp.compiled
-            # the walk was traced into this one jitted program
-            wsp.set(launches=1, uploads=0)
-            return sp.sm_fn(*dyn)
-        env = dict(zip(sp.names, jax.tree_util.tree_leaves((dyn, kwargs))))
-        outs = sp.compiled(env)
-        wsp.set(launches=sp.compiled.launches, uploads=sp.compiled.uploads)
-        flat = [outs[o] for o in sp.graph.outputs]
-        return jax.tree_util.tree_unflatten(sp.out_tree, flat)
+    def _run(self, sp: _Specialization, dyn, kwargs, wsp=obs.NULL_SPAN):
+        """Dispatch the specialization's program: the whole walk is one
+        launch, and its constants are part of the program."""
+        wsp.set(launches=1, uploads=0)
+        return sp.program(*dyn, **kwargs)
 
     def _jit_call(self, args, dyn, kwargs):
         if self.respecialize and self.mesh is None:
@@ -511,11 +530,15 @@ class StitchedFunction:
         if self.mode == "shadow":
             self.jit_calls += 1
             return "jit", None, dyn
+        if not self._build(sp, dyn, kwargs):
+            self.fallback_calls += 1
+            return "fallback", None, dyn
         return "stitched", sp, dyn
 
     def __call__(self, *args, **kwargs):
-        # spans: exec.<name> holds exec.check (routing) and, on the
-        # stitched path, exec.walk (the artifact's dispatches)
+        # spans: exec.<name> holds exec.check (routing, and the program's
+        # build when the artifact is new) and, on the stitched path,
+        # exec.walk (the program's one dispatch)
         with obs.span(self._span_name, cat="exec") as osp:
             with obs.span("exec.check", cat="exec"):
                 path, sp, dyn = self._route(args, kwargs)
@@ -584,8 +607,12 @@ class StitchedFunction:
                 "modeled_time": s.modeled_time,
                 "cache_status": s.cache_status,
                 "verify": getattr(s, "verify", None),
-                "launches": sp.compiled.launches,
-                "uploads": sp.compiled.uploads}
+                # one stitched call is one program: one launch, no upload;
+                # the walk traced into it issued ``walk_launches``
+                "launches": 1,
+                "uploads": 0,
+                "walk_launches": sp.compiled.launches,
+                "program_builds": sp.program_builds}
 
     def land_plans(self, timeout: float | None = None) -> int:
         """Join background compiles and poll EVERY specialization's upgrade
@@ -637,7 +664,9 @@ class StitchedFunction:
                       for sp in self._specs.values()},
             "error": (self._active.error
                       if self._active is not None else None),
-            "errors": {},
+            "errors": {f"exec/{self.name}/{sp.placement}": sp.error
+                       for sp in self._specs.values()
+                       if sp.status == "error"},
             # structured StitchInfeasible records from tuning: why chosen
             # patterns degraded to fused-jnp (see core.tuner._diagnostic)
             "diagnostics": (list(self._active.compiled.stats.diagnostics)
@@ -652,7 +681,7 @@ class StitchedFunction:
         if self.service is not None:
             out["cache"] = self.service.cache.report()
             out["service_error"] = self.service.last_error
-            out["errors"] = self.service.error_report()
+            out["errors"].update(self.service.error_report())
         return out
 
     def wait(self, timeout: float | None = None) -> None:

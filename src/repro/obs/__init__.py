@@ -26,7 +26,7 @@ first; each per-request span carries the request's ``rid``:
   ``serve.decode.prep`` (page growth, page-table/length/token uploads),
   ``exec.decode_step`` (``path``: stitched / fallback / jit) with its
   ``exec.check`` (signature drift check, upgrade poll) and ``exec.walk``
-  (the eager group walk; ``launches`` and ``uploads`` it issued),
+  (the stitched program's dispatch: ``launches=1, uploads=0``),
   ``serve.decode.sample`` (argmax, cast, concat), ``serve.decode.readback``
   (the blocking token copy) and ``serve.decode.commit`` (pool, lengths,
   last tokens);

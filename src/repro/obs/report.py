@@ -25,17 +25,23 @@ key                 meaning
 ``placement``       active mesh+PartitionSpec cache key ("" = single-device)
 ``plan``            active plan stats (mode, n_ops, n_kernels,
                     pallas_groups, modeled_time, cache_status, launches,
-                    uploads) or None.  ``launches``: dispatches the last
-                    walk of the artifact issued (Pallas callable calls plus
-                    ``eval_node`` calls); ``uploads``: constants and host
-                    values it put on the device with ``jnp.asarray``.
-                    Both are counted as the walk runs
-                    (:class:`repro.core.compiler.CompiledGraph`); 0 before
-                    the first call
+                    uploads, walk_launches, program_builds) or None.
+                    ``launches`` / ``uploads``: what one stitched call
+                    dispatches, 1 and 0 (the walk is one jitted program
+                    with its constants inside); ``walk_launches``: the
+                    Pallas callable calls plus ``eval_node`` calls of the
+                    walk traced into that program
+                    (:class:`repro.core.compiler.CompiledGraph`), 0 before
+                    the first call; ``program_builds``: programs built for
+                    this specialization, 1 per artifact served (an upgrade
+                    swap adds 1)
 ``error``           this function's trace/compile failure message or None
 ``errors``          *all* per-key background-compile failures recorded by
-                    the :class:`repro.cache.CompilationService`
-                    (stringified key -> message; {} when none / no service)
+                    the :class:`repro.cache.CompilationService`, and each
+                    specialization whose trace (of the function, or of its
+                    walk under ``jax.jit``) failed, keyed
+                    ``exec/<name>/<placement>`` (stringified key ->
+                    message; {} when none)
 ``diagnostics``     structured ``StitchInfeasible`` records from the active
                     plan's tuning run (stage / pattern_class / members /
                     reason dicts) — why chosen patterns degraded to
@@ -85,7 +91,8 @@ def validate_exec_report(rep: dict) -> list[str]:
         problems.append("errors must be a dict (possibly empty)")
     plan = rep.get("plan")
     if plan is not None and not {"n_kernels", "n_ops", "modeled_time",
-                                 "launches", "uploads"} <= set(plan):
+                                 "launches", "uploads", "walk_launches",
+                                 "program_builds"} <= set(plan):
         problems.append(f"plan missing kernel/op/time/walk stats: {plan!r}")
     cache = rep.get("cache")
     if cache is not None and not {"total_hits", "total_misses",

@@ -223,6 +223,34 @@ def test_latent_decode_step_compiles(compiled):
     assert not re.search(page + r" gather\(", text), "pages still gathered"
 
 
+@pytest.mark.parametrize("step", ["qwen3", "deepseek"])
+def test_stitched_decode_program_compiles(step, monkeypatch, one_chip):
+    """The decode step as ``stitch()`` serves it: its plan's group walk
+    traced into one jitted program, which compiles for the chip with the
+    Mosaic kernels inside.  In one program the latent row write lands in
+    the program's own output, so no temporary holds a copy of the pool."""
+    from repro.cache import CompilationService
+    from repro.exec import stitch
+
+    monkeypatch.setattr(kernels, "interpret_mode", lambda: False)
+    fn, avals = {"qwen3": _decode_step,
+                 "deepseek": _deepseek_decode_step}[step]()
+    args = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip), avals)
+    sf = stitch(fn, mode="offline", service=CompilationService(),
+                name="decode_step")
+    assert sf.warmup(*args) == "compiled"
+    sp = sf._active
+    assert sf._build(sp, args, {})
+    assert sf.report()["plan"]["walk_launches"] > 1
+    program = sp.program.lower(*args).compile()
+    assert "tpu_custom_call" in program.as_text()
+    if step == "deepseek":
+        pool = sum(l.size * l.dtype.itemsize for n, l in avals[1].items()
+                   if n in ("lat", "rope"))
+        assert program.memory_analysis().temp_size_in_bytes < pool / 4
+
+
 def test_paged_latent_decode_attention_compiles(compiled):
     """The kernel at the DeepSeek cell's shapes: 48 slots of 2048
     positions, page 16, 16 heads, rank 512 + rope 64, 27 layers."""

@@ -55,6 +55,34 @@ def test_external_io_and_saved_bytes():
     assert p.saved_bytes == expected
 
 
+_EXT_ORDER = """
+from repro.core import GraphBuilder
+b = GraphBuilder("chain")
+ps = [b.param(f"p{i}", (4,)) for i in range(12)]
+y = ps[0]
+for p in ps[1:]:
+    y = b.ew("add", y, p)
+g = b.build(outputs=[y])
+print(g.external_inputs([n for n in g.nodes if n not in ps]))
+"""
+
+
+def test_external_inputs_order_does_not_depend_on_string_hashing():
+    """A stitched kernel takes its arguments in ``external_inputs`` order,
+    and the jitted program holding it is found again in the persistent
+    compile cache only if its text is the same in every process."""
+    import os
+    import subprocess
+    import sys
+
+    outs = {subprocess.run(
+        [sys.executable, "-c", _EXT_ORDER], capture_output=True, text=True,
+        check=True, env=dict(os.environ, PYTHONHASHSEED=str(seed),
+                             JAX_PLATFORMS="cpu")).stdout
+        for seed in (1, 2, 3)}
+    assert len(outs) == 1, outs
+
+
 # ------------------------------------------------------- pattern gen --------
 
 def test_substitution_collapses_between_partitions():

@@ -3,8 +3,8 @@
 Contract under test — the jit-like transform every subsystem now dispatches
 through: pytree-aware tracing (nested containers, kwargs), static-argnum
 specialization, shape-drift fallback, miss-then-upgrade, donation, sharded
-dispatch equality, background-failure surfacing, and the anytime ILP budget
-feeding the same pipeline.
+dispatch equality, one jitted program per served artifact, background-failure
+surfacing, and the anytime ILP budget feeding the same pipeline.
 """
 
 import warnings
@@ -15,9 +15,12 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
+from repro import obs
 from repro.cache import CompilationService
+from repro.configs import get_reduced
 from repro.exec import StitchedFunction, stitch
 from repro.launch.mesh import make_host_mesh
+from repro.models import build_model
 
 
 def ck(a, b, rtol=1e-6, atol=1e-6):
@@ -208,6 +211,192 @@ def test_stitch_mesh_requires_specs():
         pytest.skip("needs a multi-device host")
     with pytest.raises(ValueError, match="in_specs"):
         stitch(lambda x: x, mode="jit", mesh=mesh)
+
+
+# ---------------------------------------------------------------------------
+# one jitted program per served artifact
+# ---------------------------------------------------------------------------
+
+def _small_fn(x):
+    h = x * jax.nn.sigmoid(x)
+    return h / (1.0 + jnp.sum(h * h, axis=-1, keepdims=True))
+
+
+def _landed(fn, x, svc):
+    """``stitch(fn)`` whose stitched plan has landed before its first call."""
+    sf = stitch(fn, service=svc, name="one_program")
+    sf.warmup(x)
+    svc.compiler("stitch").compile(sf.graph, bypass_cache_lookup=True)
+    sf.poll_upgrade()
+    assert sf.status == "hit" and sf.compiled.stats.mode == "stitch"
+    return sf
+
+
+def _walk_spans(tracer):
+    return [e for e in tracer.events() if e["name"] == "exec.walk"]
+
+
+@pytest.fixture
+def traced():
+    obs.clear_trace()
+    obs.enable_tracing()
+    yield obs.tracer
+    obs.disable_tracing()
+    obs.clear_trace()
+
+
+def test_off_mesh_stitched_call_dispatches_one_program(svc, traced):
+    """Every off-mesh stitched call is one launch with no upload, while the
+    walk traced into the program issued the plan's ``walk_launches``; the
+    program is built once for the artifact."""
+    x = jnp.asarray(np.random.default_rng(5).standard_normal((8, 64)),
+                    jnp.float32)
+    sf = _landed(_small_fn, x, svc)
+    for _ in range(3):
+        ck(sf(x), jax.jit(_small_fn)(x), rtol=2e-5, atol=2e-5)
+    walks = _walk_spans(traced)
+    assert [w["args"] for w in walks] == [{"launches": 1, "uploads": 0}] * 3
+    plan = sf.report()["plan"]
+    assert (plan["launches"], plan["uploads"]) == (1, 0)
+    assert plan["walk_launches"] == sf.compiled.launches > 0
+    assert plan["program_builds"] == 1
+    assert sf.stitched_calls == 3 and sf.fallback_calls == 0
+
+
+def test_upgrade_swap_rebuilds_the_program_once(svc, traced):
+    """The fallback artifact's program serves until the upgrade lands; the
+    swap builds the stitched artifact's program once, and later calls reuse
+    it."""
+    x = jnp.asarray(np.random.default_rng(6).standard_normal((16, 64)),
+                    jnp.float32)
+    sf = stitch(_small_fn, service=svc)
+    sf(x)
+    assert sf.status in ("miss", "pending")
+    assert sf.report()["plan"]["program_builds"] == 1
+    fallback_program = sf._active.program
+    svc.compiler("stitch").compile(sf.graph, bypass_cache_lookup=True)
+    for _ in range(3):
+        ck(sf(x), jax.jit(_small_fn)(x), rtol=2e-5, atol=2e-5)
+    assert sf.status == "hit"
+    assert sf.report()["plan"]["program_builds"] == 2
+    assert sf._active.program is not fallback_program
+    assert [w["args"]["launches"] for w in _walk_spans(traced)] == [1] * 4
+
+
+def test_signature_drift_falls_back_to_jit_with_no_walk(svc, traced):
+    """A drifted call is served by jit: no walk span, no program built for
+    the drifted shapes."""
+    x = jnp.ones((8, 64), jnp.float32)
+    sf = _landed(_small_fn, x, svc)
+    sf(x)
+    obs.clear_trace()
+    drifted = jnp.ones((4, 64), jnp.float32)
+    ck(sf(drifted), jax.jit(_small_fn)(drifted), rtol=2e-5, atol=2e-5)
+    assert _walk_spans(traced) == []
+    assert sf.fallback_calls == 1 and sf.stitched_calls == 1
+    assert sf.report()["plan"]["program_builds"] == 1
+
+
+def test_walk_that_fails_to_trace_is_served_by_jit(svc, monkeypatch):
+    """A walk that ``jax.jit`` cannot trace degrades like a failed trace of
+    the function: jit serves the call and every later one, and the error is
+    in ``report()["errors"]``."""
+    from repro.core.compiler import CompiledGraph
+
+    def untraceable(self, inputs):
+        raise TypeError("walk cannot be traced")
+
+    x = jnp.ones((8, 64), jnp.float32)
+    sf = stitch(_small_fn, service=svc, name="untraceable")
+    sf.warmup(x)
+    monkeypatch.setattr(CompiledGraph, "__call__", untraceable)
+    for _ in range(2):
+        ck(sf(x), jax.jit(_small_fn)(x), rtol=2e-5, atol=2e-5)
+    assert sf.status == "error" and sf.fallback_calls == 2
+    rep = sf.report()
+    assert obs.validate_exec_report(rep) == []
+    assert list(rep["errors"].values()) == ["TypeError: walk cannot be traced"]
+    assert list(rep["errors"]) == ["exec/untraceable/"]
+
+
+class _Tap:
+    """Stands in for an engine's stitched function and keeps the arguments
+    of each call."""
+
+    def __init__(self, fn):
+        self.fn = fn
+        self.args = []
+
+    def __call__(self, *args):
+        self.args.append(args)
+        return self.fn(*args)
+
+    def __getattr__(self, name):
+        return getattr(self.fn, name)
+
+
+def _eager_walk(sf, args):
+    """The active specialization's artifact walked eagerly, group by group,
+    at ``args``."""
+    sp = sf._active
+    outs = sp.compiled(dict(zip(sp.names, jax.tree_util.tree_leaves(
+        (tuple(args), {})))))
+    return jax.tree_util.tree_unflatten(
+        sp.out_tree, [outs[o] for o in sp.graph.outputs])
+
+
+def _tapped_engine(name, tmp_path):
+    """A paged engine whose stitched plans have landed (reduced qwen3, or
+    the small DeepSeek-V2 of ``test_deepseek_v2``), with one request
+    prefilled and one decode step served through taps."""
+    from test_deepseek_v2 import TINY, _program, _stitched_engine, weights
+
+    if name == "qwen3":
+        model = build_model(get_reduced("qwen3_1_7b"))
+        params = model.init(jax.random.PRNGKey(0))
+    else:
+        model, params = _program(TINY), weights.make(TINY, 3)
+    eng = _stitched_engine(model, params, tmp_path)
+    eng._exec, eng._prefill_exec = _Tap(eng._exec), _Tap(eng._prefill_exec)
+    eng.insert(eng.prefill(np.arange(1, 12, dtype=np.int32)), 1)
+    eng.generate_step(1)
+    return eng
+
+
+@pytest.mark.parametrize("case", ["small", "qwen3.decode", "qwen3.prefill",
+                                  "deepseek.decode"])
+def test_stitched_program_matches_eager_walk(case, svc, tmp_path):
+    """The one jitted program computes what the artifact's eager group walk
+    computes at the same inputs: a small function, and the reduced qwen3
+    and DeepSeek paged decode steps (gather path) and a prefill bucket.
+    Inside one program XLA fuses the jnp groups and may reorder a float32
+    sum, so the two agree to float32 rounding."""
+    if case == "small":
+        x = jnp.asarray(np.random.default_rng(7).standard_normal((8, 64)),
+                        jnp.float32)
+        sf, args = _landed(_small_fn, x, svc), (x,)
+        got = sf(*args)
+        builds = {1}                 # the plan landed before the first call
+    else:
+        name, step = case.split(".")
+        eng = _tapped_engine(name, tmp_path)
+        tap = eng._exec if step == "decode" else eng._prefill_exec
+        sf, args = tap.fn, tap.args[-1]
+        assert sf.status == "hit" and sf.compiled.stats.mode == "stitch"
+        got = sf(*args)
+        # the plan's program, after the fallback artifact's unless the
+        # background compile landed before the first call's poll
+        builds = {1, 2}
+        if step == "decode":
+            path = eng.stitch_report()["paged_attention"]
+            assert path == ("gather" if name == "qwen3" else "mla-gather")
+    assert sf.report()["calls"]["fallback"] == 0
+    assert sf.report()["plan"]["program_builds"] in builds
+    assert sf._active.program_for is sf.compiled
+    want = _eager_walk(sf, args)
+    assert (jax.tree_util.tree_structure(got)
+            == jax.tree_util.tree_structure(want))
+    ck(got, want, rtol=1e-5, atol=1e-5)
 
 
 # ---------------------------------------------------------------------------

@@ -453,9 +453,12 @@ def test_engine_spans_land_on_the_profiler_host_plane(tmp_path):
     check, walk = [s for s in spans if s[0] in ("exec.check", "exec.walk")
                    and _inside(step, s)]
     assert (check[0], walk[0]) == ("exec.check", "exec.walk")
-    compiled = eng._exec.compiled
-    assert walk[3]["launches"] == compiled.launches > 0
-    assert walk[3]["uploads"] == compiled.uploads
+    # the walk is one jitted program: one launch, no upload, while the walk
+    # traced into it issued the plan's ``walk_launches``
+    plan = eng._exec.report()["plan"]
+    assert walk[3]["launches"] == plan["launches"] == 1
+    assert walk[3]["uploads"] == plan["uploads"] == 0
+    assert plan["walk_launches"] == eng._exec.compiled.launches > 0
     (prefill,) = by["exec.prefill"]
     assert _inside(by["serve.prefill"][0], prefill)
     assert any(s[0] == "exec.walk" and _inside(prefill, s) for s in spans)
@@ -490,7 +493,8 @@ def test_exec_span_covers_check_and_walk(svc, traced):
         walk["ts"] + walk["dur"] <= call["ts"] + call["dur"]
     plan = sf.report()["plan"]
     assert walk["args"] == {"launches": plan["launches"],
-                            "uploads": plan["uploads"]}
+                            "uploads": plan["uploads"]} == {"launches": 1,
+                                                            "uploads": 0}
     obs.clear_trace()
     sf(jnp.ones((4, 16), jnp.float32))                 # signature drift
     assert [e["name"] for e in obs.tracer.events()] == [
